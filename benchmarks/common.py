@@ -24,6 +24,7 @@ execution state (the old ``EXEC`` module global is gone).
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
 import numpy as np
 
@@ -35,6 +36,26 @@ from repro.workloads import Workload
 # Paper-scale default; REPRO_BENCH_EVENTS=2000 gives a fast smoke pass with
 # identical bucketing/compile behavior (n_events is part of the shape key).
 EVENTS = int(os.environ.get("REPRO_BENCH_EVENTS", 150_000))
+
+#: the persistent compilation cache when JAX_COMPILATION_CACHE_DIR is unset
+#: (a fixed path: the cache key includes it, so it must never move)
+REPO_COMPILE_CACHE = (Path(__file__).resolve().parent.parent
+                      / ".jax_compile_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry-point script.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as is (JAX reads it
+    itself); otherwise the cache lives at :data:`REPO_COMPILE_CACHE`.
+    Entry points call this once at start-up; library code and tests never
+    do. Returns the directory in use.
+    """
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_COMPILE_CACHE))
+    return jax.config.jax_compilation_cache_dir
 
 
 def wl(alg, nodes, tpn, locks, loc, b=(5, 20), seed=0,

@@ -65,7 +65,7 @@ import time
 import numpy as np
 
 from benchmarks import roofline
-from benchmarks.common import EVENTS
+from benchmarks.common import EVENTS, enable_compile_cache
 from repro.core import batch
 from repro.experiments import fig5_workloads
 from repro.workloads import Arrivals, Workload, racks_of
@@ -214,6 +214,7 @@ def main() -> None:
                     help="fail if sharded ev/s falls below FRAC x the "
                          "unsharded XLA leg (default 0.0 = report only)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.baseline and not 0.0 < args.regression_tolerance < 1.0:
         ap.error(f"--regression-tolerance must be in (0, 1), got "
                  f"{args.regression_tolerance}")

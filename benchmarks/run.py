@@ -163,6 +163,7 @@ def main() -> None:
                     help="override the wall-clock events/sec floor for "
                          "every checked scenario")
     args = ap.parse_args()
+    common.enable_compile_cache()
     if args.list_scenarios:
         for name in scenario_names():
             print(name)

@@ -62,6 +62,9 @@ __all__ = ["Entrypoint", "trace_entrypoints", "collect_buckets"]
 #: small keeps the operand avals cheap while every phase program stays
 #: strictly increasing
 DEFAULT_TRACE_EVENTS = 2048
+#: the chip the native rows are traced for: tracing needs no device, so
+#: the VMEM budget comes from this kind's table entry, not from the host
+LINT_DEVICE_KIND = "TPU v5 lite"
 
 
 @dataclass(frozen=True)
@@ -126,7 +129,6 @@ def trace_entrypoints(scenarios: Iterable[str] | None = None,
     no kernel runs, and the process-wide x64 flag is saved/restored.
     """
     import jax
-    from jax.experimental import disable_x64, enable_x64
     from repro.core.batch import _run_events_batch
     from repro.core.sim import LAT_SAMPLES, topology
     from repro.kernels.event_loop import ops as el_ops
@@ -153,13 +155,13 @@ def trace_entrypoints(scenarios: Iterable[str] | None = None,
         tn, ln = j(thread_node), j(lock_node)
 
         if "xla-batch" in want:
-            with enable_x64():
+            with jax.enable_x64(True):
                 jx = _trace(functools.partial(
                     _run_events_batch, alg, T, N, K, ne), wlj, tn, ln)
             eps.append(Entrypoint(f"xla-batch:{key}", "xla-batch", jx,
                                   repr32=False, x64_off=False, meta=meta))
         if "pallas-i64" in want:
-            with enable_x64():
+            with jax.enable_x64(True):
                 jx = _trace(functools.partial(
                     el_ops.run_events, alg, T, N, K, ne, interpret=True,
                     representation="i64"), wlj, tn, ln)
@@ -172,11 +174,14 @@ def trace_entrypoints(scenarios: Iterable[str] | None = None,
             plan = el_ops.plan_for_run(B, P, ne, T, N, K, R=R,
                                        hl=dims["hl"], rw=dims["rw"],
                                        interpret=False,
-                                       representation="i32pair")
-            with enable_x64():
+                                       representation="i32pair",
+                                       device_kind=LINT_DEVICE_KIND)
+            with jax.enable_x64(True):
                 jx = _trace(functools.partial(
                     el_ops.run_events, alg, T, N, K, ne, interpret=False,
-                    representation="i32pair"), wlj, tn, ln)
+                    representation="i32pair",
+                    device_kind=LINT_DEVICE_KIND),
+                    wlj, tn, ln)
             eps.append(Entrypoint(f"pallas-native:{key}", "pallas-native",
                                   jx, repr32=True, x64_off=False,
                                   meta=dict(meta, plan=plan)))
@@ -184,11 +189,13 @@ def trace_entrypoints(scenarios: Iterable[str] | None = None,
             plan = el_ops.plan_for_run(B, P, ne, T, N, K, R=R,
                                        hl=dims["hl"], rw=dims["rw"],
                                        interpret=False,
-                                       representation="i32pair")
-            with disable_x64():
+                                       representation="i32pair",
+                                       device_kind=LINT_DEVICE_KIND)
+            with jax.enable_x64(False):
                 jx = _trace(functools.partial(
                     el_ops.run_events_pairs, alg, T, N, K, ne,
-                    interpret=False), wlj, tn, ln)
+                    interpret=False, device_kind=LINT_DEVICE_KIND),
+                    wlj, tn, ln)
             eps.append(Entrypoint(f"pallas-pairs:{key}", "pallas-pairs",
                                   jx, repr32=True, x64_off=True,
                                   meta=dict(meta, plan=plan,

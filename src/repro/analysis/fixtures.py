@@ -65,7 +65,6 @@ def mosaic_offender() -> Entrypoint:
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental import enable_x64
     from jax.experimental import pallas as pl
 
     def bad_kernel(x_ref, o_ref):
@@ -82,7 +81,7 @@ def mosaic_offender() -> Entrypoint:
             out_shape=jax.ShapeDtypeStruct((8, 8), jnp.int32),
             interpret=True)(x)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(call)(np.zeros((8, 8), np.int32))
     return Entrypoint("corpus:mosaic-offender", "pallas-native", jx,
                       repr32=True, x64_off=False)
@@ -98,7 +97,6 @@ def rack_offender() -> Entrypoint:
     lower (no 64-bit vector registers → M001)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     from jax.experimental import pallas as pl
 
     def bad_kernel(rack_ref, o_ref):
@@ -112,7 +110,7 @@ def rack_offender() -> Entrypoint:
             out_shape=jax.ShapeDtypeStruct((1, 8), jnp.int32),
             interpret=True)(r)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(call)(np.zeros((1, 8), np.int32))
     return Entrypoint("corpus:rack-offender", "pallas-native", jx,
                       repr32=True, x64_off=False)
@@ -122,13 +120,12 @@ def x64_offender() -> Entrypoint:
     """An int64 manufactured on a path that promised zero 64-bit avals."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     def leaky(x):
         # the classic leak: an unpinned sum widens under x64
         return jnp.sum(x.astype(jnp.int64))
 
-    with enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(leaky)(np.zeros(4, np.int32))
     return Entrypoint("corpus:x64-offender", "pallas-pairs", jx,
                       repr32=False, x64_off=True)

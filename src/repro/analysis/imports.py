@@ -57,9 +57,7 @@ ROOT_PACKAGES = ("repro.core", "repro.kernels", "repro.workloads",
 #: wired in or removed).
 QUARANTINED: dict[str, str] = {
     # the dead seed stack (repro.models / repro.configs / repro.serve,
-    # plus the empty repro.train / repro.launch dirs) was deleted
-    # outright — repro.parallel.sharding survives, slimmed to the
-    # shard_map wrapper batch.sweep's chunked dispatch imports
+    # plus the empty repro.train / repro.launch dirs) was deleted outright
     "repro.core.tla": "TLA+ spec emitter — developer tooling invoked by "
                       "hand, deliberately outside the engine's import "
                       "surface",

@@ -36,20 +36,19 @@ bucket's flattened (workload x seed) axis is measured in dispatch *units*
 of ``chunk`` rows per device (``chunk * n_devices`` rows each), and the
 unit count is greedily decomposed into power-of-two **superchunks** —
 each superchunk is ONE dispatch of ``2**k * chunk * n_devices`` rows
-through a cached ``shard_map`` runner (``parallel/sharding.py``'s compat
-wrapper, mesh axis ``"data"``). A bucket of ``u`` units therefore costs
-``popcount(u)`` dispatches against an executable family of at most
-``log2(u) + 1`` shapes, instead of ``u`` serialized unit dispatches: on
-hosts where every dispatch pays a full serial event loop regardless of
-its replica-row count (vmap rows are nearly free), this is what keeps
-the sharded layout's events/sec at parity with the unsharded
-single-dispatch layout. The dispatch loop never blocks — every
-superchunk is issued before the first result is touched, chunk operand
-buffers are donated, and the host-side aggregation of finished
-superchunks overlaps the still-in-flight ones. Edge padding is bounded
-below the mesh width (rows are only rounded up to a device-count
-multiple); the final superchunk is trimmed to the true remaining rows
-rather than padded to a full unit.
+through a cached ``jax.shard_map`` runner (mesh axis ``"data"``). A
+bucket of ``u`` units therefore costs ``popcount(u)`` dispatches against
+an executable family of at most ``log2(u) + 1`` shapes, instead of ``u``
+serialized unit dispatches: on hosts where every dispatch pays a full
+serial event loop regardless of its replica-row count (vmap rows are
+nearly free), this is what keeps the sharded layout's events/sec at
+parity with the unsharded single-dispatch layout. The dispatch loop
+never blocks — every superchunk is issued before the first result is
+touched, chunk operand buffers are donated, and the host-side
+aggregation of finished superchunks overlaps the still-in-flight ones.
+Edge padding is bounded below the mesh width (rows are only rounded up
+to a device-count multiple); the final superchunk is trimmed to the true
+remaining rows rather than padded to a full unit.
 ``exec_stats()`` exposes the dispatch/compile counters so benchmarks
 (``benchmarks/perfcheck.py``) can record the dispatch-count reduction.
 ``repro.experiments.ExecOptions`` carries (backend, devices, chunk) as
@@ -66,13 +65,11 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.cost_model import CostModel, N_COST_ROWS
 from repro.core.sim import (LAT_SAMPLES, SimConfig, SimResult, _run_events,
                             resolve_backend, topology)
-from repro.parallel.sharding import shard_map
 from repro.workloads import (Workload, WorkloadOperands, as_workload, lower,
                              pad_phases)
 
@@ -81,18 +78,21 @@ from repro.workloads import (Workload, WorkloadOperands, as_workload, lower,
 # every device in its mesh); a "compile" is one new (runner, input shape)
 # pair. perfcheck.py records these next to events/sec. "vmem_plan" is the
 # most recent event-loop kernel VMEM plan (repro.kernels.event_loop.vmem) —
-# tile auto-shrinks and byte totals ride into the benchmark reports with it.
+# tile auto-shrinks and byte totals ride into the benchmark reports with it
+# — and "vmem_plans" every distinct plan since the reset, one per bucket.
 _STATS = {"dispatches": 0, "compiles": 0}
 _COMPILED: set = set()
 
 
 def exec_stats() -> dict:
-    """Snapshot of {dispatches, compiles, vmem_plan} since the last reset
-    (``vmem_plan`` is None unless the Pallas backend planned a kernel)."""
+    """Snapshot of {dispatches, compiles, vmem_plan, vmem_plans} since the
+    last reset (``vmem_plan`` is None and ``vmem_plans`` empty unless the
+    Pallas backend planned a kernel)."""
     from repro.kernels.event_loop import vmem
     st = dict(_STATS)
     plan = vmem.last_plan()
     st["vmem_plan"] = plan.as_dict() if plan is not None else None
+    st["vmem_plans"] = [p.as_dict() for p in vmem.plans()]
     return st
 
 
@@ -180,10 +180,10 @@ def _bucket_runner(key, n_phases: int, backend: str, mesh: Mesh):
         from repro.kernels.event_loop.ref import run_events_ref
         return run_events_ref(alg, T, N, K, n_events, wl, tn, ln)
 
-    fn = jax.jit(shard_map(
-        local_block, mesh,
+    fn = jax.jit(jax.shard_map(
+        local_block, mesh=mesh,
         in_specs=(P("data"),) * n_fields + (P(), P()),
-        out_specs=(P("data"),) * n_out, axis_names={"data"}),
+        out_specs=(P("data"),) * n_out, check_vma=False),
         donate_argnums=tuple(range(n_fields)))
     _RUNNER_CACHE[ck] = fn
     return fn, ck
@@ -332,7 +332,7 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
     B = wl.seed.shape[0]
     n_phases = wl.edges.shape[1]
     if devices is None and chunk is None:
-        with enable_x64():
+        with jax.enable_x64(True):
             wj = WorkloadOperands(*(jnp.asarray(a) for a in wl))
             if backend == "pallas":
                 from repro.kernels.event_loop.ops import (plan_for_run,
@@ -377,7 +377,7 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
     ln = np.asarray(lock_node)
     runner, ck = _bucket_runner(key, n_phases, backend, mesh)
     outs = []
-    with enable_x64(), warnings.catch_warnings():
+    with jax.enable_x64(True), warnings.catch_warnings():
         # donated operand buffers only help when an output can reuse one;
         # most of this engine's outputs are clock-typed rings with no
         # matching input shape, so XLA declines those donations with a

@@ -68,7 +68,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 from repro.core import machine as mc
 from repro.core.cost_model import CostModel
@@ -457,8 +456,8 @@ def _run_events(alg, T, N, K, n_events, wl: WorkloadOperands, thread_node,
 
     Plain (unjitted) so callers can compose it: ``simulate`` jits it directly
     (``_run_events_jit``), ``batch.sweep`` vmaps it over a flattened
-    (config x seed) axis. Must run under ``enable_x64()`` so the clock
-    arrays below really are int64. ``wl`` is the lowered
+    (config x seed) axis. Must run under ``jax.enable_x64(True)`` so the
+    clock arrays below really are int64. ``wl`` is the lowered
     ``WorkloadOperands`` struct (see ``repro.workloads.lower``) — every
     leaf is a traced operand and may vary per replica in the batched path,
     including the per-phase cost rows ``wl.cost_rows (P, 8)`` and the
@@ -759,7 +758,7 @@ def simulate(cfg: SimConfig | Workload, n_events: int = 400_000,
     thread_node, lock_node, _ = topology(
         w.alg, N, w.threads_per_node, K, cm)
     backend = resolve_backend(backend)
-    with enable_x64():
+    with jax.enable_x64(True):
         if backend == "pallas":
             from repro.kernels.event_loop.ops import run_events_jit
             batched = WorkloadOperands(

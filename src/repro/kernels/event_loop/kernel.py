@@ -29,7 +29,7 @@ Clock representation
   flag (``ops.py`` resolves it):
 
   * ``_I64Clocks`` — plain int64 arrays. The fast path for interpret mode
-    and XLA-adjacent hosts; callers hold ``enable_x64()``.
+    and XLA-adjacent hosts; callers hold ``jax.enable_x64(True)``.
   * ``_PairClocks`` — hi/lo int32 pairs with carry-correct add/sub and
     lexicographic compare/argmin (``i32pair.py``). Mosaic has no 64-bit
     vector registers, so this is the *native-TPU* representation; it also
@@ -93,6 +93,12 @@ def _iota(shape, dim):
 #: x64 they widen `jnp.where` branches to int64, which Mosaic cannot lower.
 #: Every such constant is pinned at its use site (repro.analysis rule M001).
 _I = np.int32
+
+
+def _any(mask):
+    """Row-wise ``jnp.any`` over axis 1 as an i32 max: Mosaic lowers a
+    boolean reduction through a float max, which is f64 under x64."""
+    return jnp.max(mask.astype(I32), axis=1) > _I(0)
 
 
 def _select(conds, vals, default):
@@ -316,22 +322,20 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             C.write(wq_refs, C.full_m1((tile, R)))
             C.write(soj_refs, C.full_m1((tile, R)))
 
-    u1s = u1_ref[...]                               # (tile, ev_chunk) f32
-    r2s = r2_ref[...].astype(I32)
-    r3s = r3_ref[...].astype(I32)
     edges = edges_ref[...].astype(I32)              # (tile, P)
     think = think_ref[...].astype(I32)              # (tile, P)
-    # per-phase payloads arrive flattened (tile, P*…); P and T are static
-    locp = locp_ref[...].reshape(tile, P, T)        # f32
-    actp = actp_ref[...].astype(I32).reshape(tile, P, T)
-    binitp = binit_ref[...].astype(I32).reshape(tile, P, 2)
-    cstp = costs_ref[...].astype(I32).reshape(tile, P, N_COST_ROWS)
-    nmp = nmult_ref[...].reshape(tile, P, N)        # f32 fail-slow mults
+    # per-phase payloads arrive flattened (tile, P*…) and stay flat:
+    # Mosaic cannot reshape them to (tile, P, …), so phase rows are
+    # selected from static lane slices (``phase_row``)
+    locp = locp_ref[...]                            # (tile, P*T) f32
+    actp = actp_ref[...].astype(I32)                # (tile, P*T)
+    binitp = binit_ref[...].astype(I32)             # (tile, P*2)
+    cstp = costs_ref[...].astype(I32)               # (tile, P*8)
+    nmp = nmult_ref[...]                            # (tile, P*N) f32
     tn = jnp.broadcast_to(tn_ref[...].astype(I32), (tile, T))
     ln = jnp.broadcast_to(ln_ref[...].astype(I32), (tile, K))
     if is_rw:
-        u4s = u4_ref[...]                           # (tile, ev_chunk) f32
-        readfp = readf_ref[...].reshape(tile, P, T)  # f32 read probs
+        readfp = readf_ref[...]                     # (tile, P*T) f32
     if is_hl:
         rk = rack_ref[...].astype(I32)              # (tile, N) rack ids
     if R > 0:
@@ -347,6 +351,12 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
     kio = _iota((tile, K), 1)
     nio = _iota((tile, N), 1)
     pio = _iota((tile, P), 1)
+    # event e's draw is read from an aligned `win`-lane window of the
+    # streamed (tile, ev_chunk) block, then picked by a one-hot lane
+    # select: Mosaic has no dynamic_slice of a loaded vector, and a ref
+    # slice at a dynamic lane offset must be provably 128-aligned
+    win = 128 if ev_chunk % 128 == 0 else ev_chunk
+    wio = _iota((tile, win), 1)
     if repr32:
         sio = _iota((tile, lat_samples), 1)   # ring one-hot (Mosaic path)
     else:
@@ -364,6 +374,24 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         return jnp.sum(jnp.where(kio == idx[:, None], arr,
                                  arr.dtype.type(0)), axis=1,
                        dtype=arr.dtype)
+
+    def draw(ref, e):
+        """Event ``e``'s (tile,) column of a streamed draw block."""
+        base = pl.multiple_of(lax.div(e, _I(win)) * _I(win), win)
+        blk = ref[:, pl.ds(base, win)]
+        return jnp.sum(jnp.where(wio == lax.rem(e, _I(win)), blk,
+                                 blk.dtype.type(0)), axis=1,
+                       dtype=blk.dtype)
+
+    def phase_row(flat, ph, w):
+        """Phase ``ph``'s (tile, w) row of a flat (tile, P*w) block: a
+        one-hot select over static lane slices (exactly the one-hot sum
+        it replaces — one phase matches, the rest contribute zero)."""
+        row = jnp.zeros((tile, w), flat.dtype)
+        for q in range(P):
+            row = jnp.where((ph == _I(q))[:, None],
+                            flat[:, q * w:(q + 1) * w], row)
+        return row
 
     state = (s_t0[...], s_t1[...], s_vic[...], s_pc[...], s_bud[...],
              s_nxt[...], s_prev[...], s_tgt[...], s_coh[...],
@@ -404,32 +432,21 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             ph = jnp.sum((gi >= edges).astype(I32), axis=1,
                          dtype=I32) - 1              # (tile,)
             ohP = pio == ph[:, None]
-            act_row = jnp.sum(jnp.where(ohP[:, :, None], actp, _I(0)),
-                              axis=1, dtype=I32)
-            loc_row = jnp.sum(jnp.where(ohP[:, :, None], locp,
-                                        np.float32(0)),
-                              axis=1, dtype=jnp.float32)
+            act_row = phase_row(actp, ph, T)
+            loc_row = phase_row(locp, ph, T)
             think_e = jnp.sum(jnp.where(ohP, think, _I(0)), axis=1,
                               dtype=I32)
-            # phase-indexed cost rows + ALock budgets (sum dtypes pinned,
-            # same x64 caveat as gat_t)
-            binit = jnp.sum(jnp.where(ohP[:, :, None], binitp, _I(0)),
-                            axis=1, dtype=I32)       # (tile, 2)
-            cst = jnp.sum(jnp.where(ohP[:, :, None], cstp, _I(0)), axis=1,
-                          dtype=I32)                 # (tile, 8)
-            nm_row = jnp.sum(jnp.where(ohP[:, :, None], nmp, np.float32(0)),
-                             axis=1, dtype=jnp.float32)   # (tile, N)
+            # phase-indexed cost rows + ALock budgets
+            binit = phase_row(binitp, ph, 2)             # (tile, 2)
+            cst = phase_row(cstp, ph, N_COST_ROWS)       # (tile, 8)
+            nm_row = phase_row(nmp, ph, N)               # (tile, N)
             if is_rw:
-                rf_row = jnp.sum(jnp.where(ohP[:, :, None], readfp,
-                                           np.float32(0)),
-                                 axis=1, dtype=jnp.float32)   # (tile, T)
+                rf_row = phase_row(readfp, ph, T)        # (tile, T)
 
             # phase boundary: rejoining threads resume from the cluster's
             # current clock (mirror of the XLA loop's rejoin bump)
-            ohPp = pio == jnp.maximum(ph - _I(1), _I(0))[:, None]
-            was_act = jnp.sum(jnp.where(ohPp[:, :, None], actp, _I(0)),
-                              axis=1, dtype=I32)
-            rejoin = (jnp.any(gi == edges, axis=1)[:, None]
+            was_act = phase_row(actp, jnp.maximum(ph - _I(1), _I(0)), T)
+            rejoin = (_any(gi == edges)[:, None]
                       & (act_row != 0) & (was_act == 0))
             cont_min = C.reduce_min_masked(ready,
                                            (act_row != 0) & (was_act != 0))
@@ -441,13 +458,13 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         else:
             # single phase: the flat PR-2 hot path, no phase machinery
             # (lowering guarantees P == 1 operands are all-active)
-            loc_row = locp[:, 0, :]
+            loc_row = locp
             think_e = think[:, 0]
-            binit = binitp[:, 0]
-            cst = cstp[:, 0]
-            nm_row = nmp[:, 0, :]
+            binit = binitp
+            cst = cstp
+            nm_row = nmp
             if is_rw:
-                rf_row = readfp[:, 0, :]
+                rf_row = readfp
             actm = None
         if R > 0:
             # idle threads (NCS, no request bound) wake at the earliest
@@ -476,9 +493,9 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         mynode = gat_t(tn, tid)
 
         # -- workload draw (precomputed stream; NCS branch consumes it) ----
-        u1e = lax.dynamic_index_in_dim(u1s, e, 1, keepdims=False)
-        r2e = lax.dynamic_index_in_dim(r2s, e, 1, keepdims=False)
-        r3e = lax.dynamic_index_in_dim(r3s, e, 1, keepdims=False)
+        u1e = draw(u1_ref, e)
+        r2e = draw(r2_ref, e)
+        r3e = draw(r3_ref, e)
         # thread-dependent half of the locality draw: same f32 compare as
         # the XLA loop's uniform(k1) < locality[ph, tid]
         loc_t = jnp.sum(jnp.where(ohT, loc_row, np.float32(0)), axis=1,
@@ -500,7 +517,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         if is_rw:
             # reader/writer coin: same f32 compare as the XLA loop's
             # uniform(k4) < read_frac[ph, tid]
-            u4e = lax.dynamic_index_in_dim(u4s, e, 1, keepdims=False)
+            u4e = draw(u4_ref, e)
             rf_t = jnp.sum(jnp.where(ohT, rf_row, np.float32(0)), axis=1,
                            dtype=jnp.float32)
             new_r = u4e < rf_t
@@ -530,7 +547,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             queued = (rstat == _I(0)) & (rio < arrptr[:, None])
             head = jnp.min(jnp.where(queued, rio,
                                      _I(np.iinfo(np.int32).max)), axis=1)
-            do_disp = live & pend_tid & jnp.any(queued, axis=1)
+            do_disp = live & pend_tid & _any(queued)
             hd = jnp.minimum(head, _I(R - 1))
             ohR = rio == hd[:, None]
             dm = do_disp[:, None]

@@ -11,9 +11,10 @@ scratch.
 Clock representation
   ``representation="auto" | "i64" | "i32pair"`` picks how the kernel holds
   its 64-bit clock state (see ``kernel.py``): plain int64 (the interpret /
-  XLA-adjacent fast path, callers hold ``enable_x64()``) or carry-correct
-  hi/lo int32 pairs (``i32pair.py`` — what Mosaic can actually lower on
-  TPU, and the only mode that works with x64 entirely off). ``"auto"``
+  XLA-adjacent fast path, callers hold ``jax.enable_x64(True)``) or
+  carry-correct hi/lo int32 pairs (``i32pair.py`` — what Mosaic can
+  actually lower on TPU, and the only mode that works with x64 entirely
+  off). ``"auto"``
   resolves to ``i32pair`` for native lowering and ``i64`` under interpret;
   the ``REPRO_EVENT_CLOCKS`` environment variable overrides either way.
   Both representations are bitwise-equal to the XLA engine
@@ -25,11 +26,15 @@ Clock representation
 VMEM budget
   Before tracing, the ``vmem.py`` planner prices every VMEM buffer of one
   grid step and deterministically shrinks the replica tile to fit
-  ``vmem_budget`` bytes (default: ``vmem.DEFAULT_VMEM_BUDGET`` for native
-  lowering, unconstrained under interpret). An impossible budget raises an
-  actionable ``ValueError`` instead of a Mosaic OOM; the chosen plan is
-  recorded and surfaced through ``repro.core.batch.exec_stats()`` and the
-  benchmark reports.
+  ``vmem_budget`` bytes (default: ``vmem.budget_for`` the device's
+  ``device_kind`` for native lowering — an unknown device is an error —
+  unconstrained under interpret); the native ``pallas_call`` gets the
+  device's ``vmem.limit_for`` as ``vmem_limit_bytes``, whatever the
+  budget. ``device_kind`` names the target when compiling for a device
+  that is not attached (default: ``jax.devices()[0]``). An impossible budget raises an actionable
+  ``ValueError`` instead of a Mosaic OOM; the chosen plan is recorded and
+  surfaced through ``repro.core.batch.exec_stats()`` and the benchmark
+  reports.
 
 The state-independent half of the workload draw stream is precomputed here
 (``precompute_draws``) from the identical ``jax.random.fold_in`` counter
@@ -71,6 +76,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -84,6 +90,12 @@ from repro.traffic.stream import (arrival_plan, arrival_times_i64,
 
 DEFAULT_TILE = 8
 DEFAULT_EV_CHUNK = 4096
+#: the (8, 128) vreg tiling Mosaic's block shapes must respect
+SUBLANES = 8
+LANES = 128
+#: index-map constant: a python 0 would trace as int64 under x64, which
+#: Mosaic refuses in the grid's index functions
+_ZERO = np.int32(0)
 
 _REPRESENTATIONS = ("auto", "i64", "i32pair")
 
@@ -91,6 +103,11 @@ _REPRESENTATIONS = ("auto", "i64", "i32pair")
 def default_interpret() -> bool:
     """Native Mosaic lowering on TPU; interpreter everywhere else."""
     return jax.default_backend() != "tpu"
+
+
+def _device_kind() -> str:
+    """The kind of the device native lowering targets by default."""
+    return jax.devices()[0].device_kind
 
 
 def resolve_representation(representation: str, interpret: bool) -> str:
@@ -154,6 +171,7 @@ def plan_for_run(B, P, n_events, T, N, K, *, R: int = 0,
                  representation: str = "auto",
                  lat_samples: int = LAT_SAMPLES,
                  vmem_budget: int | None = None,
+                 device_kind: str | None = None,
                  hl: bool = False, rw: bool = False) -> vmem.VmemPlan:
     """Resolve representation/budget, clamp (tile, ev_chunk) exactly like
     ``run_events`` will, and record the resulting VMEM plan.
@@ -168,24 +186,34 @@ def plan_for_run(B, P, n_events, T, N, K, *, R: int = 0,
         interpret = default_interpret()
     repr32 = resolve_representation(representation, interpret) == "i32pair"
     if vmem_budget is None and not interpret:
-        vmem_budget = vmem.DEFAULT_VMEM_BUDGET
+        vmem_budget = vmem.budget_for(device_kind or _device_kind())
     tile = max(1, min(tile, B))
     # same grid-dim count, minimal edge padding: B=9, tile=8 pads 7 rows
     # of dead kernel work; tile=5 runs the same two tiles padding 1
     tile = -(-B // -(-B // tile))
     ev_chunk = max(1, min(ev_chunk, max(n_events, 1)))
+    align = 1
+    if not interpret:
+        # Mosaic blocks: the replica (sublane) dim is a multiple of 8 or
+        # the whole batch, the event (lane) dim a multiple of 128 or the
+        # whole padded run; rows and events are padded to match
+        align = SUBLANES
+        if tile < B:
+            tile = min(-(-tile // SUBLANES) * SUBLANES, B)
+        if ev_chunk < n_events:
+            ev_chunk = max(LANES, ev_chunk // LANES * LANES)
     # price the VMEM footprint up front: shrink the replica tile to fit
     # the budget (or raise actionably) instead of dying inside Mosaic
     plan = vmem.plan_vmem(tile=tile, ev_chunk=ev_chunk, T=T, N=N, K=K, P=P,
                           lat_samples=lat_samples, repr32=repr32, R=R,
-                          hl=hl, rw=rw, budget=vmem_budget)
+                          hl=hl, rw=rw, budget=vmem_budget, align=align)
     vmem.note_plan(plan)
     return plan
 
 
 def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
                    tile, ev_chunk, interpret, repr32, lat_samples,
-                   vmem_budget):
+                   vmem_budget, device_kind):
     """Shared pallas_call builder: returns the raw kernel outputs
     (clock outputs as (hi, lo) pairs when ``repr32``)."""
     B = wl.seed.shape[0]
@@ -201,7 +229,7 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
                         ev_chunk=ev_chunk, interpret=interpret,
                         representation="i32pair" if repr32 else "i64",
                         lat_samples=lat_samples, vmem_budget=vmem_budget,
-                        hl=is_hl, rw=is_rw)
+                        device_kind=device_kind, hl=is_hl, rw=is_rw)
     tile, ev_chunk = plan.tile, plan.ev_chunk
     pad_b = -B % tile
     pad_e = -n_events % ev_chunk
@@ -246,7 +274,7 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     grid = (Bp // tile, n_chunks)
 
     def row(w):
-        return pl.BlockSpec((tile, w), lambda i, j: (i, 0))
+        return pl.BlockSpec((tile, w), lambda i, j: (i, _ZERO))
 
     def clock_out(w):
         """One (Bp, w) i64 output, or an (hi, lo) pair of i32 outputs."""
@@ -296,8 +324,8 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
         + [row(P), row(P), row(P * T)]
         + ([row(P * T)] if is_rw else [])          # read_frac rows
         + [row(P * T), row(P * 2), row(P * N_COST_ROWS), row(P * N),
-           pl.BlockSpec((1, T), lambda i, j: (0, 0)),
-           pl.BlockSpec((1, K), lambda i, j: (0, 0))]
+           pl.BlockSpec((1, T), lambda i, j: (_ZERO, _ZERO)),
+           pl.BlockSpec((1, K), lambda i, j: (_ZERO, _ZERO))]
         + ([row(N)] if is_hl else []))             # rack row
     operands = [*streams,
                 jnp.asarray(edges, I32), jnp.asarray(think, I32),
@@ -328,6 +356,8 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
         interpret=interpret,
+        compiler_params=(None if interpret else pltpu.CompilerParams(
+            vmem_limit_bytes=vmem.limit_for(device_kind or _device_kind()))),
     )(*operands)
 
     out = [o[:B] for o in out]
@@ -353,10 +383,11 @@ def run_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
                tile: int = DEFAULT_TILE, ev_chunk: int = DEFAULT_EV_CHUNK,
                interpret=None, representation: str = "auto",
                lat_samples: int = LAT_SAMPLES,
-               vmem_budget: int | None = None):
-    """Batched Pallas event loop; must run under ``enable_x64()`` (the
-    int64 output contract — use :func:`run_events_pairs` to stay in pure
-    int32 with x64 off).
+               vmem_budget: int | None = None,
+               device_kind: str | None = None):
+    """Batched Pallas event loop; must run under ``jax.enable_x64(True)``
+    (the int64 output contract — use :func:`run_events_pairs` to stay in
+    pure int32 with x64 off).
 
     ``wl`` is a ``WorkloadOperands`` with a leading replica axis B on
     every leaf: locality (B,P,T) f32, zcdf (B,P,K//N) f32, edges (B,P)
@@ -400,7 +431,8 @@ def run_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     out = _pallas_events(alg, T, N, K, n_events, wl, thread_node,
                          lock_node, tile=tile, ev_chunk=ev_chunk,
                          interpret=interpret, repr32=repr32,
-                         lat_samples=lat_samples, vmem_budget=vmem_budget)
+                         lat_samples=lat_samples, vmem_budget=vmem_budget,
+                         device_kind=device_kind)
     if repr32:
         done, lat, lat_n, t_end, nreacq, npass = out[:6]
         base = (done, p32.pack(lat), lat_n, p32.pack(t_end), nreacq, npass)
@@ -416,7 +448,8 @@ def run_events_pairs(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
                      tile: int = DEFAULT_TILE,
                      ev_chunk: int = DEFAULT_EV_CHUNK, interpret=None,
                      lat_samples: int = LAT_SAMPLES,
-                     vmem_budget: int | None = None):
+                     vmem_budget: int | None = None,
+                     device_kind: str | None = None):
     """The hi/lo representation end to end — no int64 anywhere, so it runs
     with x64 off (the TPU vector constraint the x64-off CI leg emulates).
 
@@ -445,7 +478,8 @@ def run_events_pairs(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     return _pallas_events(alg, T, N, K, n_events, wl, thread_node,
                           lock_node, tile=tile, ev_chunk=ev_chunk,
                           interpret=interpret, repr32=True,
-                          lat_samples=lat_samples, vmem_budget=vmem_budget)
+                          lat_samples=lat_samples, vmem_budget=vmem_budget,
+                          device_kind=device_kind)
 
 
 _jit_run_events = functools.partial(

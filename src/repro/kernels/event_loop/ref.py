@@ -26,7 +26,7 @@ def run_events_ref(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     (B,P,8), node_mult (B,P,N), seed (B,)); thread_node (T,) and
     lock_node (K,) broadcast.
     Returns (done (B,T), lat (B,lat_samples), lat_n (B,), t_end (B,),
-    nreacq (B,), npass (B,)) — must run under ``enable_x64()``.
+    nreacq (B,), npass (B,)) — must run under ``jax.enable_x64(True)``.
     """
     point = functools.partial(_run_events, alg, T, N, K, n_events)
 

@@ -7,7 +7,7 @@ TPU an oversized ``(tile, lat_samples)`` request dies inside Mosaic as an
 opaque VMEM-exhaustion error; this planner computes the byte table up
 front, **deterministically shrinks the replica tile** (halving) until the
 configured budget fits, and raises an actionable ``ValueError`` when even
-``tile=1`` cannot fit — never a silent wrong answer.
+the smallest legal tile cannot fit — never a silent wrong answer.
 
 Byte formula (one grid step = one replica tile; int32/float32 = 4 bytes,
 clocks = 8 bytes, as one i64 buffer or an (hi, lo) i32 pair):
@@ -54,9 +54,36 @@ from typing import Mapping
 
 from repro.core.cost_model import N_COST_ROWS
 
-#: Per-core VMEM on current TPUs is ~16 MiB; leave headroom for Mosaic's
-#: own spills/temporaries. Overridable per call (``run_events(vmem_budget=)``).
-DEFAULT_VMEM_BUDGET = 12 * 2**20
+#: Per-TensorCore VMEM by ``jax.Device.device_kind``. Source: JAX's Pallas
+#: TPU hardware table (``jax/_src/pallas/mosaic/tpu_info.py``, jax 0.9.0:
+#: "TPU v5 lite" -> 128 MiB per core). A device missing here is an error
+#: on the native path, never a guessed default.
+VMEM_CAPACITY = {"TPU v5 lite": 128 * 2**20}
+#: VMEM the compiled kernel needs per priced byte: Mosaic also keeps the
+#: loop-carried state (a value copy of the scratch and outputs) and the
+#: step's temporaries in VMEM. Compiled for a described v5e at the
+#: paper-fig5, hot-key-storm and open-loop-ramp shapes (tile 8), the
+#: kernel needed between 2.5x and 2.75x the priced bytes
+MOSAIC_FACTOR = 3
+
+
+def limit_for(device_kind: str) -> int:
+    """The ``vmem_limit_bytes`` the compiler gets on ``device_kind``: 3/4
+    of the core's VMEM, whatever budget the planner was given."""
+    try:
+        cap = VMEM_CAPACITY[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no VMEM capacity known for device kind {device_kind!r} "
+            f"(known: {sorted(VMEM_CAPACITY)}); add it to "
+            f"vmem.VMEM_CAPACITY with its source") from None
+    return cap * 3 // 4
+
+
+def budget_for(device_kind: str) -> int:
+    """The planner's default byte budget on ``device_kind``: the priced
+    buffers get ``1 / MOSAIC_FACTOR`` of the compiler's limit."""
+    return limit_for(device_kind) // MOSAIC_FACTOR
 
 _I32 = 4
 _F32 = 4
@@ -192,14 +219,16 @@ class VmemPlan:
 def plan_vmem(*, tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
               lat_samples: int, repr32: bool, R: int = 0,
               hl: bool = False, rw: bool = False,
-              budget: int | None = None) -> VmemPlan:
+              budget: int | None = None, align: int = 1) -> VmemPlan:
     """Compute the byte table; halve ``tile`` until ``budget`` fits.
 
     Deterministic: the same arguments always yield the same plan. With
     ``budget=None`` the table is computed but never shrunk (interpret
-    mode / host runs have no VMEM ceiling). Raises ``ValueError`` when
-    even ``tile=1`` exceeds the budget, naming the dominant buffers and
-    the knobs that actually help.
+    mode / host runs have no VMEM ceiling). Halving keeps the tile a
+    multiple of ``align`` (8 for Mosaic's sublane tiling) and stops at
+    ``min(tile, align)``. Raises ``ValueError`` when even that smallest
+    tile exceeds the budget, naming the dominant buffers and the knobs
+    that actually help.
     """
     if tile < 1 or ev_chunk < 1:
         raise ValueError(f"tile and ev_chunk must be >= 1, got "
@@ -212,16 +241,16 @@ def plan_vmem(*, tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
         table = buffer_table(t, ev_chunk, T, N, K, P, lat_samples, repr32,
                              R, hl, rw)
         total = sum(b for _, b in table.values())
-        if budget is None or total <= budget or t == 1:
+        if budget is None or total <= budget or t <= align:
             break
-        t = max(1, t // 2)
+        t = max(align, t // 2 // align * align)
     if budget is not None and total > budget:
         top = sorted(table.items(), key=lambda kv: -kv[1][1])[:3]
         detail = ", ".join(f"{name}{shape}={b:,}B"
                            for name, (shape, b) in top)
         raise ValueError(
             f"event-loop kernel cannot fit VMEM budget {budget:,}B even at "
-            f"tile=1 (needs {total:,}B; largest buffers: {detail}). Lower "
+            f"tile={t} (needs {total:,}B; largest buffers: {detail}). Lower "
             f"lat_samples ({lat_samples}) or ev_chunk ({ev_chunk}), or "
             f"raise the budget (run_events(vmem_budget=...)).")
     return VmemPlan(requested_tile=requested, tile=t, ev_chunk=ev_chunk,
@@ -230,20 +259,26 @@ def plan_vmem(*, tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
                     budget=budget, total_bytes=total, breakdown=table)
 
 
-# -- last-plan registry (read by batch.exec_stats / benchmarks) -------------
+# -- plan registry (read by batch.exec_stats / benchmarks) ------------------
+# Every distinct plan since the last clear, most recently noted last: a
+# sweep plans one kernel per shape bucket, and each must be checked.
 
-_LAST_PLAN: VmemPlan | None = None
+_PLANS: list[VmemPlan] = []
 
 
 def note_plan(plan: VmemPlan) -> None:
-    global _LAST_PLAN
-    _LAST_PLAN = plan
+    if plan in _PLANS:
+        _PLANS.remove(plan)
+    _PLANS.append(plan)
 
 
 def last_plan() -> VmemPlan | None:
-    return _LAST_PLAN
+    return _PLANS[-1] if _PLANS else None
+
+
+def plans() -> tuple[VmemPlan, ...]:
+    return tuple(_PLANS)
 
 
 def clear_plan() -> None:
-    global _LAST_PLAN
-    _LAST_PLAN = None
+    _PLANS.clear()

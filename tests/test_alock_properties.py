@@ -2,7 +2,7 @@
 import itertools
 
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import machine as mc
 from repro.core.tla import bounded_overtaking, explore

@@ -200,12 +200,12 @@ def test_pad_phases_inert_for_cost_and_budget_rows():
     padded = pad_phases(lw.operands, 5)
     assert padded.cost_rows.shape == (5, 8)
     assert padded.b_init.shape == (5, 2)
-    from jax.experimental import enable_x64
+    import jax
     import jax.numpy as jnp
     from repro.kernels.event_loop.ref import run_events_ref
     from repro.workloads import WorkloadOperands
     tn, ln, _ = topology("alock", 2, 2, 8)
-    with enable_x64():
+    with jax.enable_x64(True):
         outs = []
         for ops in (lw.operands, padded):
             wl = WorkloadOperands(*(jnp.asarray(a)[None] for a in ops))

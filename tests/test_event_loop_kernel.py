@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import batch
 from repro.core.sim import SimConfig, simulate, topology, zipf_cdf
@@ -91,7 +90,7 @@ def test_kernel_ragged_tile_and_chunk_bitwise():
     from repro.kernels.event_loop.ref import run_events_ref
     ev = 1100
     alg, T, N, K, wl, tn, ln = _ragged_operands(5, ev)
-    with enable_x64():
+    with jax.enable_x64(True):
         ref = run_events_ref(alg, T, N, K, ev, wl, tn, ln)
         out = run_events(alg, T, N, K, ev, wl, tn, ln,
                          tile=2, ev_chunk=256, interpret=True)
@@ -108,7 +107,7 @@ def test_native_grid_matches_per_replica_runs():
     from repro.kernels.event_loop.ops import run_events
     ev, B = 700, 5           # ev crosses the 600 phase edge, ragged chunk
     alg, T, N, K, wl, tn, ln = _ragged_operands(B, ev)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = run_events(alg, T, N, K, ev, wl, tn, ln,
                          tile=2, ev_chunk=256, interpret=True)
         singles = [run_events(

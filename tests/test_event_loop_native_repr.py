@@ -19,14 +19,14 @@ engine:
   * latency-ring overflow (``latn`` wrapping past ``lat_samples``) across
     all three engines: XLA, i64-pallas, i32-pair-pallas.
 
-The XLA oracle still runs under a local ``enable_x64()`` (its clocks are
-real int64); pair outputs are packed host-side with ``i32pair.pack_np`` so
-the comparison itself never needs x64.
+The XLA oracle still runs under a local ``jax.enable_x64(True)`` (its
+clocks are real int64); pair outputs are packed host-side with
+``i32pair.pack_np`` so the comparison itself never needs x64.
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.sim import topology, zipf_cdf
 from repro.experiments import scenario_names, scenario_workloads
@@ -141,7 +141,7 @@ def test_native_repr_bitwise_phased_zipf_churn(alg):
         arr_fix=jnp.zeros((B, 0), jnp.int32),
         rack=jnp.asarray(np.tile(rack, (B, 1))),
         read_frac=jnp.asarray(rf))
-    with enable_x64():
+    with jax.enable_x64(True):
         ref = [np.asarray(r) for r in
                run_events_ref(alg, T, N, K, EV, wl, tn, ln)]
     # the phase edge at 600 falls mid-chunk (600 % 256 != 0)
@@ -164,7 +164,7 @@ def test_node_mult_phase_edge_mid_chunk_bitwise():
     alg, T, N, K, _, _ = lw.shape_key
     tn, ln, _ = topology(alg, N, T // N, K)
     wl = WorkloadOperands(*(jnp.asarray(a)[None] for a in lw.operands))
-    with enable_x64():
+    with jax.enable_x64(True):
         ref = [np.asarray(r) for r in
                run_events_ref(alg, T, N, K, EV, wl, tn, ln)]
     out = run_events_pairs(alg, T, N, K, EV, wl, tn, ln,
@@ -175,7 +175,7 @@ def test_node_mult_phase_edge_mid_chunk_bitwise():
     healthy = lower(w.replace(phases=(Phase(frac=0.55), Phase(frac=0.45))),
                     EV)
     wl_h = WorkloadOperands(*(jnp.asarray(a)[None] for a in healthy.operands))
-    with enable_x64():
+    with jax.enable_x64(True):
         ref_h = [np.asarray(r) for r in
                  run_events_ref(alg, T, N, K, EV, wl_h, tn, ln)]
     assert ref[3][0] > ref_h[3][0]      # t_end grows under the limp
@@ -201,7 +201,7 @@ def test_read_frac_phase_edge_mid_chunk_bitwise():
     rf = np.asarray(lw.operands.read_frac)
     assert rf.shape == (2, T)
     assert np.all(rf[0] == np.float32(0.15)) and len(set(rf[1])) == T
-    with enable_x64():
+    with jax.enable_x64(True):
         ref = [np.asarray(r) for r in
                run_events_ref(alg, T, N, K, EV, wl, tn, ln)]
     out = run_events_pairs(alg, T, N, K, EV, wl, tn, ln,
@@ -215,7 +215,7 @@ def test_read_frac_phase_edge_mid_chunk_bitwise():
         lc = lower(w.replace(phases=(Phase(frac=0.55, read_frac=mix),
                                      Phase(frac=0.45, read_frac=mix))), EV)
         wl_c = WorkloadOperands(*(jnp.asarray(a)[None] for a in lc.operands))
-        with enable_x64():
+        with jax.enable_x64(True):
             ref_c = [np.asarray(r) for r in
                      run_events_ref(alg, T, N, K, EV, wl_c, tn, ln)]
         rates[tag] = ref_c[0].sum() / float(ref_c[3][0])
@@ -262,7 +262,7 @@ def test_registry_scenarios_bitwise_i32pair():
         alg, T, N, K, _, R = key
         tn, ln, _ = topology(alg, N, T // N, K)
         _, wl = _stack_operands([w for _, w in items], ev)
-        with enable_x64():
+        with jax.enable_x64(True):
             ref = [np.asarray(r) for r in
                    run_events_ref(alg, T, N, K, ev, wl, tn, ln,
                                   lat_samples=lat_samples)]
@@ -290,7 +290,7 @@ def test_ring_overflow_identical_across_engines():
     tn, ln, _ = topology(alg, N, tpn, K)
     w = lower(Workload(alg, N, tpn, K, locality=0.9, seed=3), ev)
     wl = WorkloadOperands(*(jnp.asarray(a)[None] for a in w.operands))
-    with enable_x64():
+    with jax.enable_x64(True):
         ref = [np.asarray(r) for r in
                run_events_ref(alg, T, N, K, ev, wl, tn, ln,
                               lat_samples=lat_samples)]
@@ -319,7 +319,7 @@ def test_packed_run_events_i32pair_matches_i64():
     tn, ln, _ = topology(alg, N, tpn, K)
     w = lower(Workload(alg, N, tpn, K, locality=0.85, seed=5), ev)
     wl = WorkloadOperands(*(jnp.asarray(a)[None] for a in w.operands))
-    with enable_x64():
+    with jax.enable_x64(True):
         a = run_events(alg, T, N, K, ev, wl, tn, ln, interpret=True,
                        representation="i64")
         b = run_events(alg, T, N, K, ev, wl, tn, ln, interpret=True,
@@ -351,7 +351,7 @@ def test_env_override_keys_the_jit_cache(monkeypatch):
     tn, ln, _ = topology(alg, N, tpn, K)
     w = lower(Workload(alg, N, tpn, K, locality=0.9, seed=2), ev)
     wl = WorkloadOperands(*(jnp.asarray(a)[None] for a in w.operands))
-    with enable_x64():
+    with jax.enable_x64(True):
         a = run_events_jit(alg, T, N, K, ev, wl, tn, ln, interpret=True,
                            lat_samples=256)
         vmem.clear_plan()

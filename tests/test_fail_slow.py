@@ -3,10 +3,10 @@ and the monotone-degradation property (raising a node's multiplier never
 decreases that node's observed p50 latency)."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.sim import topology
 from repro.kernels.event_loop.ref import run_events_ref
 from repro.workloads import (NODE_MULT_PROFILES, Phase, Workload,
@@ -75,7 +75,7 @@ def _node_p50(node, mult, seed, ev=800, lat_samples=512):
     alg, T, N_, K_, _, _ = lw.shape_key
     tn, ln, _ = topology(alg, N_, tpn, K_)
     wl = WorkloadOperands(*(jnp.asarray(a)[None] for a in lw.operands))
-    with enable_x64():
+    with jax.enable_x64(True):
         done, lat, lat_n, *_ = run_events_ref(alg, T, N_, K_, ev, wl, tn,
                                               ln, lat_samples=lat_samples)
     n = int(min(int(lat_n[0]), lat_samples))
@@ -84,7 +84,7 @@ def _node_p50(node, mult, seed, ev=800, lat_samples=512):
 
 
 def test_monotone_degradation_chain():
-    """Deterministic spine of the property (runs without hypothesis):
+    """Deterministic spine of the property (no random search):
     1x -> 2x -> 4x never decreases the node's p50, on either node."""
     for node in (0, 1):
         p50s = [_node_p50(node, m, seed=3) for m in (1.0, 2.0, 4.0)]

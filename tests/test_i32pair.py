@@ -3,15 +3,14 @@ i32pair`): every operation round-trips against an int64 reference across
 carry boundaries, INT32_MAX±1, and the parked-thread ``never`` sentinel.
 
 Runs with x64 off (the whole point of the representation); int64
-references are computed host-side in numpy. Hypothesis legs degrade to
-skips when hypothesis is absent (``hypothesis_compat``); the deterministic
-edge-case legs below always run.
+references are computed host-side in numpy. Hypothesis drives the
+property legs; the deterministic edge-case legs below pin the boundaries.
 """
 import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.kernels.event_loop import i32pair as p32
 
 I64_MAX = np.iinfo(np.int64).max
@@ -119,7 +118,7 @@ def test_gather_one_hot():
                                   m[np.arange(8), idx])
 
 
-# -- hypothesis legs (skip cleanly when hypothesis is absent) ---------------
+# -- hypothesis legs --------------------------------------------------------
 
 BOUND = 2**62        # keep a+b inside int64 so the reference never wraps
 i64s = st.lists(st.integers(min_value=-BOUND, max_value=BOUND - 1),
@@ -165,8 +164,3 @@ def test_prop_mod_pow2(v, log2m):
     m = 1 << log2m
     assert int(np.asarray(p32.mod_pow2(to_pair(np.int64([v])), m))[0]) \
         == v % m
-
-
-def test_hypothesis_presence_marker():
-    """Document which mode this run exercised (both are valid)."""
-    assert HAVE_HYPOTHESIS in (True, False)

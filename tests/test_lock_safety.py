@@ -13,8 +13,7 @@ section, held from the successful rd_enter until the RD_REL decrement).
     intervals but never a write interval (alock-rw).
 
 The checker is exercised three ways: seeded adversarial schedules that
-always run (no external deps), hypothesis properties when hypothesis is
-installed (``hypothesis_compat`` degrades them to skips otherwise), and a
+always run, hypothesis properties, and a
 *seeded mutation* — an alock-rw writer whose reader-count drain check is
 disabled — which the checker must catch (a checker that cannot fail would
 gate nothing).
@@ -23,7 +22,7 @@ import itertools
 import random
 
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import machine as mc
 
@@ -200,7 +199,7 @@ def test_mutation_disabled_drain_is_caught():
 
 
 # ---------------------------------------------------------------------------
-# hypothesis properties (skip cleanly when hypothesis is absent)
+# hypothesis properties
 
 
 @given(st.lists(st.integers(0, 3), min_size=200, max_size=1500),

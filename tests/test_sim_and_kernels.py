@@ -4,7 +4,7 @@ import numpy.random as npr
 import pytest
 import jax
 import jax.numpy as jnp
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import machine as mc
 from repro.core.sim import SimConfig, run_schedule, simulate

@@ -6,8 +6,8 @@ ValueError (never a Mosaic crash), and the chosen plan is reported through
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import batch
 from repro.core.cost_model import N_COST_ROWS
@@ -199,7 +199,7 @@ def test_budgeted_run_shrinks_tile_and_stays_bitwise():
     # 6 replicas at lat_samples=1024 / ev_chunk=128 cost ~12 KiB per tile
     # row; 24 KiB forces the 6 -> 3 -> 1 halving path
     budget = 24 * 1024
-    with enable_x64():
+    with jax.enable_x64(True):
         ref = [np.asarray(r) for r in
                run_events_ref("alock", 4, 2, 8, ev, wl, tn, ln,
                               lat_samples=lat_samples)]
@@ -259,3 +259,41 @@ def test_plan_for_run_minimizes_edge_padding():
     p = plan_for_run(9, 2, 64, tile=8, interpret=True,
                      representation="i32pair", vmem_budget=fit2, **shape)
     assert (p.requested_tile, p.tile, p.shrunk) == (5, 2, True)
+
+
+def test_native_plan_respects_mosaic_block_rules():
+    """Native lowering: the replica tile is a multiple of 8 or the whole
+    batch, the event chunk a multiple of 128 or the whole run, and VMEM
+    halving never leaves those rules (interpret mode keeps any tile)."""
+    from repro.kernels.event_loop.ops import plan_for_run
+    shape = dict(T=12, N=3, K=6)
+    big = 1 << 30
+
+    def native(B, n_events, **kw):
+        kw.setdefault("vmem_budget", big)
+        return plan_for_run(B, 2, n_events, interpret=False, **shape, **kw)
+
+    assert native(9, 64, tile=8).tile == 8            # not 5: pads to 16
+    assert native(5, 64, tile=2).tile == 5            # whole batch
+    assert native(72, 64, tile=32).tile == 24         # 3 tiles, no padding
+    assert native(9, 5000, ev_chunk=4000).ev_chunk == 3968
+    assert native(9, 300, ev_chunk=4096).ev_chunk == 300   # whole run
+    assert native(9, 300, ev_chunk=100).ev_chunk == 128
+    # budget pressure halves 64 -> 32 -> 16 -> 8 and stops at 8
+    at8 = vmem.plan_vmem(tile=8, ev_chunk=64, P=2, repr32=True,
+                         lat_samples=LAT_SAMPLES, **shape).total_bytes
+    p = native(64, 64, tile=64, vmem_budget=at8)
+    assert (p.requested_tile, p.tile) == (64, 8)
+    with pytest.raises(ValueError, match="tile=8"):
+        native(64, 64, tile=64, vmem_budget=at8 - 1)
+
+
+def test_vmem_budget_is_keyed_by_device_kind():
+    """The native budget comes from one table keyed by device_kind: the
+    v5e entry, and an error (not a default) for an unknown device."""
+    limit = vmem.limit_for("TPU v5 lite")
+    assert limit == vmem.VMEM_CAPACITY["TPU v5 lite"] * 3 // 4
+    assert vmem.budget_for("TPU v5 lite") == 32 * 2**20
+    for fn in (vmem.limit_for, vmem.budget_for):
+        with pytest.raises(ValueError, match="cpu"):
+            fn("cpu")
