@@ -1,0 +1,5 @@
+"""The chip benchmark of the lock-table simulator: data, harness and yardstick.
+
+``python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>``
+runs one cell of ``BENCHMARK.json`` once; see ``bench/run.py``.
+"""
