@@ -13,8 +13,8 @@ writer path (full ALock protocol + reader drain) and the reader path
 
 Kernel wall time: one small bucket through the event-loop Pallas kernel
 (interpret mode — the CPU CI stand-in) vs the vmapped XLA oracle via
-``batch.sweep``. These are wall-us rows for eyeballing, not trajectory
-gates — ``benchmarks/perfcheck.py`` owns the gated trajectory.
+``batch.sweep``. These are wall-us rows for eyeballing, not gates: the
+chip's speed is measured by ``bench/run.py``.
 """
 import sys
 import time

@@ -9,7 +9,7 @@ Prints ``name,us_per_call,derived`` CSV rows.
 
 Sections reproduce the paper's figures; ``--scenario NAME`` runs a named
 workload program from the registry (``repro.experiments.registry``) — the
-same single entry point ``perfcheck.py`` and CI use. ``--scenario all``
+same single entry point CI uses. ``--scenario all``
 runs every registered scenario; ``--scenario-out FILE`` additionally
 writes the scenario rows as JSON with the scenario name recorded per row.
 Running more than one scenario appends a cross-algorithm leaderboard:
@@ -22,9 +22,9 @@ in one vmapped dispatch per shape bucket — no extra compiles) and turns
 the derived columns into mean±ci95. --backend/--devices/--chunk build the
 immutable ``ExecOptions`` value threaded explicitly into every section and
 scenario (no process-wide execution state). --zipf skews the within-node
-lock choice for sections that support it (fig5). Kernel/roofline sections
-ignore the simulator flags. ``benchmarks.perfcheck`` records events/sec
-per backend.
+lock choice for sections that support it (fig5). The kernel section
+ignores the simulator flags. These rows are CPU wall times and simulated
+statistics; the speed of the chip is measured by ``bench/run.py``.
 
 --check-slo evaluates each run scenario's registered SLO
 (``repro.experiments.Slo``: simulated p99 latency ceiling + wall-clock
@@ -42,7 +42,7 @@ import sys
 import time
 
 from benchmarks import (common, fig1_loopback, fig4_budget, fig5_throughput,
-                        fig6_latency, microbench, roofline, serving_curves)
+                        fig6_latency, microbench, serving_curves)
 from repro.core import batch
 from repro.experiments import (ExecOptions, Slo, check_slo, get_scenario,
                                run_scenario, scenario_names)
@@ -53,7 +53,6 @@ SECTIONS = {
     "fig5": fig5_throughput.main,
     "fig6": fig6_latency.main,
     "micro": microbench.main,
-    "roofline": roofline.main,
     "serving": serving_curves.main,
 }
 

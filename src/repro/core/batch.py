@@ -49,15 +49,38 @@ aggregation of finished superchunks overlaps the still-in-flight ones.
 Edge padding is bounded below the mesh width (rows are only rounded up
 to a device-count multiple); the final superchunk is trimmed to the true
 remaining rows rather than padded to a full unit.
-``exec_stats()`` exposes the dispatch/compile counters so benchmarks
-(``benchmarks/perfcheck.py``) can record the dispatch-count reduction.
-``repro.experiments.ExecOptions`` carries (backend, devices, chunk) as
-one immutable object through the benchmark suite — there is no
-process-wide execution state.
+``exec_stats()`` exposes the dispatch and shape-key counters and the
+kernel's VMEM plans. ``repro.experiments.ExecOptions`` carries (backend,
+devices, chunk) as one immutable object through the benchmark suite —
+there is no process-wide execution state.
+
+Profiler spans
+--------------
+Each ``sweep`` call marks its host stages with
+``jax.profiler.TraceAnnotation`` spans (the ``SPAN_*`` names below). Under
+a profiler session they land in the trace on the clock of the device
+planes, so every gap in a device's timeline can be put down to a stage;
+with no session a span costs one TraceMe constructor. Every span of one
+call carries the same ``sweep`` ordinal in its metadata:
+
+  * ``batch.sweep`` — the whole call (``configs``, ``seeds``, ``events``,
+    ``buckets``);
+  * ``batch.lower`` — ``lower()`` of every config and the bucketing;
+  * ``batch.assemble`` — one bucket's host operand arrays (``bucket``,
+    ``rows``, ``phases``);
+  * ``batch.dispatch`` — the device put of the operands, the VMEM plan
+    and the jitted call up to its return: the enqueue, plus any compile
+    (per bucket, or per superchunk when sharded; ``bucket``, ``rows``,
+    ``bytes`` put, ``new_shape``);
+  * ``batch.fetch`` — the wait for the device and the copy of the outputs
+    back to the host (``bucket``, ``bytes`` fetched);
+  * ``batch.aggregate`` — one bucket's reshapes into ``BatchResult``
+    objects.
 """
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 import warnings
 from typing import NamedTuple, Sequence
@@ -65,6 +88,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.cost_model import CostModel, N_COST_ROWS
@@ -73,21 +97,34 @@ from repro.core.sim import (LAT_SAMPLES, SimConfig, SimResult, _run_events,
 from repro.workloads import (Workload, WorkloadOperands, as_workload, lower,
                              pad_phases)
 
+# -- profiler spans (see the module docstring) --------------------------------
+SPAN_SWEEP = "batch.sweep"
+SPAN_LOWER = "batch.lower"
+SPAN_ASSEMBLE = "batch.assemble"
+SPAN_DISPATCH = "batch.dispatch"
+SPAN_FETCH = "batch.fetch"
+SPAN_AGGREGATE = "batch.aggregate"
+_SWEEP_IDS = itertools.count()
+
 # -- execution statistics ----------------------------------------------------
 # A "dispatch" is one host->device call of a compiled bucket runner (covering
-# every device in its mesh); a "compile" is one new (runner, input shape)
-# pair. perfcheck.py records these next to events/sec. "vmem_plan" is the
-# most recent event-loop kernel VMEM plan (repro.kernels.event_loop.vmem) —
-# tile auto-shrinks and byte totals ride into the benchmark reports with it
-# — and "vmem_plans" every distinct plan since the reset, one per bucket.
+# every device in its mesh). "compiles" counts new (runner, input shape)
+# keys seen by this process: a cache-miss count on the Python side, not a
+# count of backend compiles (a key first seen may still load from the
+# persistent compile cache, and jit may compile for reasons the key does
+# not hold); backend compiles are what jax.monitoring reports. "vmem_plan"
+# is the most recent event-loop kernel VMEM plan
+# (repro.kernels.event_loop.vmem) and "vmem_plans" every distinct plan
+# since the reset, one per bucket.
 _STATS = {"dispatches": 0, "compiles": 0}
 _COMPILED: set = set()
 
 
 def exec_stats() -> dict:
     """Snapshot of {dispatches, compiles, vmem_plan, vmem_plans} since the
-    last reset (``vmem_plan`` is None and ``vmem_plans`` empty unless the
-    Pallas backend planned a kernel)."""
+    last reset. ``compiles`` counts new (runner, shape) keys, a cache-miss
+    count of this module, not backend compiles. ``vmem_plan`` is None and
+    ``vmem_plans`` empty unless the Pallas backend planned a kernel."""
     from repro.kernels.event_loop import vmem
     st = dict(_STATS)
     plan = vmem.last_plan()
@@ -103,11 +140,18 @@ def reset_exec_stats() -> None:
     vmem.clear_plan()
 
 
-def _note_call(key) -> None:
+def _note_call(key) -> bool:
+    """Count one dispatch; True if its (runner, shape) key is new."""
     _STATS["dispatches"] += 1
-    if key not in _COMPILED:
-        _COMPILED.add(key)
-        _STATS["compiles"] += 1
+    if key in _COMPILED:
+        return False
+    _COMPILED.add(key)
+    _STATS["compiles"] += 1
+    return True
+
+
+def _nbytes(arrays) -> int:
+    return sum(int(a.nbytes) for a in arrays)
 
 
 def shape_key(cfg, n_events: int):
@@ -314,7 +358,8 @@ class BatchResult(NamedTuple):
 
 
 def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
-                 backend: str, devices, chunk):
+                 backend: str, devices, chunk, sweep_id: int,
+                 bucket: int):
     """Run one flattened bucket (B rows) and return the 6 output arrays.
 
     ``wl`` leaves carry the flattened (workload x seed) axis B — the
@@ -324,31 +369,36 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
     measured in units of ``chunk`` rows per device, the unit count is
     decomposed into greedy power-of-two superchunks, and each superchunk
     is one non-blocking dispatch over the device mesh (see the module
-    docstring); aggregation converts finished superchunks while later
+    docstring); the fetch converts finished superchunks while later
     ones are still in flight and only the final concatenate forces the
-    last dispatch.
+    last dispatch. ``sweep_id`` and ``bucket`` label the profiler spans.
     """
     alg, T, N, K, n_events, R = key
     B = wl.seed.shape[0]
     n_phases = wl.edges.shape[1]
     if devices is None and chunk is None:
-        with jax.enable_x64(True):
-            wj = WorkloadOperands(*(jnp.asarray(a) for a in wl))
-            if backend == "pallas":
-                from repro.kernels.event_loop.ops import (plan_for_run,
-                                                          run_events_jit)
-                # re-record the VMEM plan per dispatch: planning inside
-                # run_events is trace-time only, so a cached executable
-                # would otherwise leave exec_stats()["vmem_plan"] stale
-                plan_for_run(B, n_phases, n_events, T, N, K, R=R,
-                             hl=alg == "hlock", rw=alg == "alock-rw")
-                out = run_events_jit(alg, T, N, K, n_events, wj,
-                                     thread_node, lock_node)
-            else:
-                out = _run_events_batch(alg, T, N, K, n_events, wj,
-                                        thread_node, lock_node)
-        _note_call((key, n_phases, backend, "bucket", B))
-        return tuple(np.asarray(o) for o in out)
+        with TraceAnnotation(SPAN_DISPATCH, sweep=sweep_id, bucket=bucket,
+                             rows=B, bytes=_nbytes(wl)) as span:
+            with jax.enable_x64(True):
+                wj = WorkloadOperands(*(jnp.asarray(a) for a in wl))
+                if backend == "pallas":
+                    from repro.kernels.event_loop.ops import (
+                        plan_for_run, run_events_jit)
+                    # re-record the VMEM plan per dispatch: planning inside
+                    # run_events is trace-time only, so a cached executable
+                    # would otherwise leave exec_stats()["vmem_plan"] stale
+                    plan_for_run(B, n_phases, n_events, T, N, K, R=R,
+                                 hl=alg == "hlock", rw=alg == "alock-rw")
+                    out = run_events_jit(alg, T, N, K, n_events, wj,
+                                         thread_node, lock_node)
+                else:
+                    out = _run_events_batch(alg, T, N, K, n_events, wj,
+                                            thread_node, lock_node)
+            span.set_metadata(new_shape=_note_call(
+                (key, n_phases, backend, "bucket", B)))
+        with TraceAnnotation(SPAN_FETCH, sweep=sweep_id, bucket=bucket,
+                             bytes=_nbytes(out)):
+            return tuple(np.asarray(o) for o in out)
 
     devs = list(devices) if devices is not None else jax.devices()
     mesh = Mesh(np.asarray(devs), ("data",))
@@ -387,23 +437,30 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
         off = 0
         for sz in sizes:
             nrows = min(sz * step, Bp - off)   # multiple of D by induction
-            if backend == "pallas":
-                # each shard's kernel sees nrows/D replicas (same
-                # trace-time-only caveat as the unsharded branch above)
-                from repro.kernels.event_loop.ops import plan_for_run
-                plan_for_run(nrows // D, n_phases, n_events, T, N, K, R=R,
-                             hl=alg == "hlock", rw=alg == "alock-rw")
             sl = slice(off, off + nrows)
-            # async: the call returns device futures — every superchunk
-            # is issued before any result is forced below
-            outs.append(runner(*(a[sl] for a in leaves), tn, ln))
-            _note_call((ck, nrows))
+            args = [a[sl] for a in leaves]
+            with TraceAnnotation(SPAN_DISPATCH, sweep=sweep_id,
+                                 bucket=bucket, rows=nrows,
+                                 bytes=_nbytes(args)) as span:
+                if backend == "pallas":
+                    # each shard's kernel sees nrows/D replicas (same
+                    # trace-time-only caveat as the unsharded branch above)
+                    from repro.kernels.event_loop.ops import plan_for_run
+                    plan_for_run(nrows // D, n_phases, n_events, T, N, K,
+                                 R=R, hl=alg == "hlock",
+                                 rw=alg == "alock-rw")
+                # async: the call returns device futures — every
+                # superchunk is issued before any result is forced below
+                outs.append(runner(*args, tn, ln))
+                span.set_metadata(new_shape=_note_call((ck, nrows)))
             off += nrows
-    # aggregation is the only blocking point: np.asarray forces the
+    # the fetch is the only blocking point: np.asarray forces the
     # superchunks in dispatch order, so converting an early (large) one
     # overlaps the later in-flight dispatches
-    return tuple(np.concatenate([np.asarray(o[j]) for o in outs])[:B]
-                 for j in range(10 if R else 6))
+    with TraceAnnotation(SPAN_FETCH, sweep=sweep_id, bucket=bucket,
+                         bytes=sum(_nbytes(o) for o in outs)):
+        return tuple(np.concatenate([np.asarray(o[j]) for o in outs])[:B]
+                     for j in range(10 if R else 6))
 
 
 def sweep(configs: Sequence[SimConfig | Workload], n_seeds: int = 1,
@@ -446,78 +503,104 @@ def sweep(configs: Sequence[SimConfig | Workload], n_seeds: int = 1,
         raise ValueError(f"n_seeds must be >= 1, got {n_seeds}")
     backend = resolve_backend(backend)
     configs = list(configs)
-    lowered = [lower(as_workload(c), n_events, cm) for c in configs]
-    buckets: dict[tuple, list[int]] = {}
-    for i, lw in enumerate(lowered):
-        buckets.setdefault(lw.shape_key, []).append(i)
-
-    out: list[BatchResult | None] = [None] * len(configs)
-    for key, idxs in buckets.items():
-        alg, T, N, K, _, R = key
-        kpn = K // N
-        thread_node, lock_node, _ = topology(alg, N, T // N, K, cm)
-        C, S = len(idxs), n_seeds
-        # scenarios with fewer phases pad up to the bucket max with
-        # unreachable phases, so mixed phase programs share one executable
-        # (open-loop arrival rows pad identically; R is part of the key)
-        Pmax = max(lowered[i].operands.n_phases for i in idxs)
-        loc = np.empty((C, S, Pmax, T), np.float32)
-        zc = np.empty((C, S, Pmax, kpn), np.float32)
-        ed = np.empty((C, S, Pmax), np.int32)
-        th = np.empty((C, S, Pmax), np.int32)
-        ac = np.empty((C, S, Pmax, T), np.int32)
-        bi = np.empty((C, S, Pmax, 2), np.int32)
-        cr = np.empty((C, S, Pmax, N_COST_ROWS), np.int32)
-        nm = np.empty((C, S, Pmax, N), np.float32)
-        sd = np.empty((C, S), np.int32)
-        ag = np.empty((C, S, Pmax), np.float32)
-        ae = np.empty((C, S, Pmax), np.int32)
-        aq = np.empty((C, S, Pmax), np.int32)
-        at = np.empty((C, S, Pmax, 2), np.float32)
-        af = np.empty((C, S, R), np.int32)
-        rk = np.empty((C, S, N), np.int32)
-        rf = np.empty((C, S, Pmax, T), np.float32)
-        for row, i in enumerate(idxs):
-            o = pad_phases(lowered[i].operands, Pmax)
-            loc[row], zc[row], ed[row] = o.locality, o.zcdf, o.edges
-            th[row], ac[row], bi[row] = o.think_ns, o.active, o.b_init
-            cr[row], nm[row] = o.cost_rows, o.node_mult
-            ag[row], ae[row], aq[row] = (o.arr_gap_ns, o.arr_edges,
-                                         o.arr_qcap)
-            at[row], af[row] = o.arr_token, o.arr_fix
-            rk[row], rf[row] = o.rack, o.read_frac
-            sd[row] = int(o.seed) + np.arange(S, dtype=np.int32)
-
-        def flat(a):
-            return a.reshape((C * S,) + a.shape[2:])
-
-        wl = WorkloadOperands(flat(loc), flat(zc), flat(ed), flat(th),
-                              flat(ac), flat(bi), flat(sd), flat(cr),
-                              flat(nm), flat(ag), flat(ae), flat(aq),
-                              flat(at), flat(af), flat(rk), flat(rf))
-        outs = _exec_bucket(
-            key, thread_node, lock_node, wl, backend, devices, chunk)
-        done, lat, _lat_n, t_end, nreacq, npass = outs[:6]
-        done = done.reshape(C, S, T)
-        lat = lat.reshape(C, S, LAT_SAMPLES)
-        t_end = t_end.reshape(C, S)
-        nreacq = nreacq.reshape(C, S)
-        npass = npass.reshape(C, S)
-        extras = None
-        if R:
-            extras = tuple(o.reshape(C, S, R) for o in outs[6:])
-
-        for row, i in enumerate(idxs):
-            ops = done[row].sum(axis=1).astype(np.int64)
-            sim_ns = np.maximum(t_end[row].astype(np.int64), 1)
-            # per-element arithmetic matches simulate()'s scalar formula
-            # bitwise: ops / sim_ns * 1e3 in float64 either way
-            mops = ops / sim_ns * 1e3
-            kw = {}
-            if extras is not None:
-                kw = dict(arr_ns=extras[0][row], wait_ns=extras[1][row],
-                          sojourn_ns=extras[2][row], rstat=extras[3][row])
-            out[i] = BatchResult(configs[i], n_events, sd[row], ops,
-                                 sim_ns, mops, lat[row], done[row],
-                                 nreacq[row], npass[row], **kw)
+    sid = next(_SWEEP_IDS)
+    with TraceAnnotation(SPAN_SWEEP, sweep=sid, configs=len(configs),
+                         seeds=n_seeds, events=n_events) as span:
+        with TraceAnnotation(SPAN_LOWER, sweep=sid):
+            lowered = [lower(as_workload(c), n_events, cm) for c in configs]
+            buckets: dict[tuple, list[int]] = {}
+            for i, lw in enumerate(lowered):
+                buckets.setdefault(lw.shape_key, []).append(i)
+        span.set_metadata(buckets=len(buckets))
+        out: list[BatchResult | None] = [None] * len(configs)
+        for b, (key, idxs) in enumerate(buckets.items()):
+            with TraceAnnotation(SPAN_ASSEMBLE, sweep=sid, bucket=b,
+                                 rows=len(idxs) * n_seeds) as asm:
+                thread_node, lock_node, wl, seeds = _assemble(
+                    key, [lowered[i].operands for i in idxs], n_seeds, cm)
+                asm.set_metadata(phases=wl.edges.shape[1])
+            outs = _exec_bucket(key, thread_node, lock_node, wl, backend,
+                                devices, chunk, sid, b)
+            with TraceAnnotation(SPAN_AGGREGATE, sweep=sid, bucket=b):
+                _aggregate(key, outs, idxs, seeds, configs, n_events, out)
     return out
+
+
+def _assemble(key, operands, n_seeds: int, cm: CostModel):
+    """One bucket's operands: the broadcast topology rows and every
+    replica's ``WorkloadOperands`` flattened over (workload x seed), with
+    the (C, S) seed grid."""
+    alg, T, N, K, _, R = key
+    kpn = K // N
+    thread_node, lock_node, _ = topology(alg, N, T // N, K, cm)
+    C, S = len(operands), n_seeds
+    # scenarios with fewer phases pad up to the bucket max with
+    # unreachable phases, so mixed phase programs share one executable
+    # (open-loop arrival rows pad identically; R is part of the key)
+    Pmax = max(o.n_phases for o in operands)
+    loc = np.empty((C, S, Pmax, T), np.float32)
+    zc = np.empty((C, S, Pmax, kpn), np.float32)
+    ed = np.empty((C, S, Pmax), np.int32)
+    th = np.empty((C, S, Pmax), np.int32)
+    ac = np.empty((C, S, Pmax, T), np.int32)
+    bi = np.empty((C, S, Pmax, 2), np.int32)
+    cr = np.empty((C, S, Pmax, N_COST_ROWS), np.int32)
+    nm = np.empty((C, S, Pmax, N), np.float32)
+    sd = np.empty((C, S), np.int32)
+    ag = np.empty((C, S, Pmax), np.float32)
+    ae = np.empty((C, S, Pmax), np.int32)
+    aq = np.empty((C, S, Pmax), np.int32)
+    at = np.empty((C, S, Pmax, 2), np.float32)
+    af = np.empty((C, S, R), np.int32)
+    rk = np.empty((C, S, N), np.int32)
+    rf = np.empty((C, S, Pmax, T), np.float32)
+    for row, op in enumerate(operands):
+        o = pad_phases(op, Pmax)
+        loc[row], zc[row], ed[row] = o.locality, o.zcdf, o.edges
+        th[row], ac[row], bi[row] = o.think_ns, o.active, o.b_init
+        cr[row], nm[row] = o.cost_rows, o.node_mult
+        ag[row], ae[row], aq[row] = (o.arr_gap_ns, o.arr_edges,
+                                     o.arr_qcap)
+        at[row], af[row] = o.arr_token, o.arr_fix
+        rk[row], rf[row] = o.rack, o.read_frac
+        sd[row] = int(o.seed) + np.arange(S, dtype=np.int32)
+
+    def flat(a):
+        return a.reshape((C * S,) + a.shape[2:])
+
+    wl = WorkloadOperands(flat(loc), flat(zc), flat(ed), flat(th),
+                          flat(ac), flat(bi), flat(sd), flat(cr),
+                          flat(nm), flat(ag), flat(ae), flat(aq),
+                          flat(at), flat(af), flat(rk), flat(rf))
+    return thread_node, lock_node, wl, sd
+
+
+def _aggregate(key, outs, idxs, sd, configs, n_events: int,
+               out: list) -> None:
+    """Reshape one bucket's outputs into a ``BatchResult`` per workload,
+    written into ``out`` at the workload's index."""
+    T, R = key[1], key[5]
+    C, S = sd.shape
+    done, lat, _lat_n, t_end, nreacq, npass = outs[:6]
+    done = done.reshape(C, S, T)
+    lat = lat.reshape(C, S, LAT_SAMPLES)
+    t_end = t_end.reshape(C, S)
+    nreacq = nreacq.reshape(C, S)
+    npass = npass.reshape(C, S)
+    extras = None
+    if R:
+        extras = tuple(o.reshape(C, S, R) for o in outs[6:])
+
+    for row, i in enumerate(idxs):
+        ops = done[row].sum(axis=1).astype(np.int64)
+        sim_ns = np.maximum(t_end[row].astype(np.int64), 1)
+        # per-element arithmetic matches simulate()'s scalar formula
+        # bitwise: ops / sim_ns * 1e3 in float64 either way
+        mops = ops / sim_ns * 1e3
+        kw = {}
+        if extras is not None:
+            kw = dict(arr_ns=extras[0][row], wait_ns=extras[1][row],
+                      sojourn_ns=extras[2][row], rstat=extras[3][row])
+        out[i] = BatchResult(configs[i], n_events, sd[row], ops,
+                             sim_ns, mops, lat[row], done[row],
+                             nreacq[row], npass[row], **kw)

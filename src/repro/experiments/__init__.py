@@ -22,7 +22,7 @@ there is no process-wide execution state.
 
 Named scenario programs live in the registry (``run_scenario`` /
 ``scenario_names``) — the single entry point behind
-``benchmarks.run --scenario`` and ``benchmarks/perfcheck.py``. A scenario
+``benchmarks.run --scenario``. A scenario
 can carry an :class:`Slo` (simulated-p99 ceiling, wall-clock events/sec
 floor); ``benchmarks.run --check-slo`` evaluates it with
 :func:`check_slo` and gates CI on the result.

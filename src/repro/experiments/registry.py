@@ -3,14 +3,14 @@
 A *scenario* is a named function that builds and runs a workload program —
 an :class:`~repro.experiments.experiment.Experiment` over the simulator,
 or a threaded coordination-plane stress (``repro.coord.stress``) — and
-returns CSV-able rows. The registry gives ``benchmarks.run --scenario``,
-``benchmarks/perfcheck.py`` and CI one entry point: every registered name
+returns CSV-able rows. The registry gives ``benchmarks.run --scenario``
+and CI one entry point: every registered name
 is runnable with nothing but ``(n_seeds, n_events, options)``.
 
 Rows are dicts with at least ``name`` / ``us_per_call`` / ``derived``
 (the benchmark suite's CSV columns); simulator rows additionally carry
 ``p99_lat_ns`` / ``mean_mops``; extra keys ride into the JSON artifacts
-(``BENCH_events_per_sec.json`` records them per row together with the
+(the ``--scenario-out`` file records them per row together with the
 scenario name).
 
 A scenario may declare an :class:`~repro.experiments.slo.Slo` — a
@@ -616,7 +616,7 @@ def _rack_locality(n_seeds, n_events, options):
 
 
 def fig5_workloads() -> list[Workload]:
-    """The Fig.5-shaped perf grid (shared by perfcheck and `paper-fig5`)."""
+    """The paper's Fig.5 grid: 3 algorithms x 3 localities."""
     return [Workload(alg, n_nodes=10, threads_per_node=8, n_locks=100,
                      locality=loc)
             for alg in ("alock", "spinlock", "mcs")
@@ -624,7 +624,7 @@ def fig5_workloads() -> list[Workload]:
 
 
 @scenario("paper-fig5",
-          "the paper's Fig.5 throughput grid (perfcheck's measuring stick)",
+          "the paper's Fig.5 throughput grid",
           workloads=fig5_workloads)
 def _paper_fig5(n_seeds, n_events, options):
     exp = Experiment("paper-fig5", n_seeds=n_seeds, n_events=n_events,

@@ -15,9 +15,8 @@ The two bounds deliberately live on different clocks:
     fixed spec + seed set — a tightened bound fails reproducibly, which
     the exit-code tests rely on);
   * ``min_events_per_sec`` reads the harness's *wall-clock* event rate
-    (the perf trajectory perfcheck records) — registered scenarios keep
-    this floor loose enough for CI smoke runs and let perfcheck carry the
-    fine-grained trajectory.
+    — registered scenarios keep this floor loose enough for CI smoke runs
+    on the CPU; the chip's speed is measured by ``bench/run.py``.
 
 Beyond the scenario-wide bounds, ``per_label`` attaches a *sub-contract*
 to individual result rows by name: ``Slo(per_label={"alock-rw.rf99":

@@ -42,7 +42,7 @@ Clock representation
   per-row scatter but expressible in Mosaic, which rejects per-row
   dynamic scatters against VMEM state. The i64 fast path keeps the
   O(1)-per-event scatter (the one-hot form costs O(lat_samples) lane-ops
-  per event, which would tax the interpret-mode CI/perfcheck runs for no
+  per event, which would tax the interpret-mode CI runs for no
   benefit). The ring-overflow tests hold the two forms identical.
 
 Randomness + workload operands

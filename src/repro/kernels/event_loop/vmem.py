@@ -34,7 +34,7 @@ event count per chunk instead of masking dead steps.
 the breakdown shapes are checked against the buffers ``ops.run_events``
 actually allocates in interpret mode. The chosen plan is recorded via
 ``note_plan`` and surfaced through ``repro.core.batch.exec_stats()`` and
-the ``benchmarks/perfcheck.py`` / ``benchmarks.run`` report rows.
+the ``benchmarks.run`` scenario summaries.
 
 >>> p = plan_vmem(tile=8, ev_chunk=512, T=16, N=4, K=16, P=1,
 ...               lat_samples=1 << 15, repr32=True)
