@@ -209,8 +209,8 @@ def _kernel_dynamic_scatter(ep):
                 f"`{site.eqn.primitive.name}` inside the kernel jaxpr — "
                 f"Mosaic rejects per-row dynamic scatter/gather against "
                 f"VMEM state",
-                "re-express as a masked one-hot select over the indexed "
-                "axis (see the latency-ring accumulate in "
+                "re-express as a masked one-hot select over an aligned "
+                "window of the indexed axis (see draw() in "
                 "kernels/event_loop/kernel.py)"))
     return out
 
@@ -443,11 +443,11 @@ def check_vmem_consistency(ep, table_fn=None) -> list[Finding]:
     findings = []
     dims = ep.meta["dims"]            # {T, N, K, P, R}
     table = table_fn(tile=plan.tile, ev_chunk=plan.ev_chunk,
-                     lat_samples=plan.lat_samples, repr32=ep.repr32,
-                     **dims)
+                     repr32=ep.repr32, **dims)
+    streamed = vmem.STREAMED_INPUTS + vmem.STREAMED_OUTPUTS
     expected = []
     for name, (shape, nbytes) in table.items():
-        factor = vmem.PIPELINE_FACTOR if name in vmem.STREAMED_INPUTS else 1
+        factor = vmem.PIPELINE_FACTOR if name in streamed else 1
         itemsize = nbytes // (int(np.prod(shape)) * factor)
         expected.append((name, tuple(shape), itemsize))
     for site in calls:

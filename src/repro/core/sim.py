@@ -20,9 +20,10 @@ Two execution backends share this module's semantics:
     ``sem_step`` (argmin + ``lax.switch`` per event). This path is the
     correctness oracle.
   * ``backend="pallas"`` — ``repro.kernels.event_loop``: the same loop as a
-    Pallas kernel with all per-replica state (Sem, ready/busy clocks,
-    latency ring) resident in VMEM for the whole run, replicas tiled across
-    the grid, branch dispatch re-expressed as masked ``jnp.select`` over PC
+    Pallas kernel with all per-replica state (Sem, ready/busy clocks)
+    resident in VMEM for the whole run, each event's latency streamed out
+    and the latency ring built from it, replicas tiled across the grid,
+    branch dispatch re-expressed as masked ``jnp.select`` over PC
     classes. Bitwise-identical outputs to the XLA path (tested); the
     workload draws are precomputed per event from the same counter-based
     ``jax.random.fold_in`` stream so per-seed results match exactly.

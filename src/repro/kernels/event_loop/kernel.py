@@ -3,17 +3,18 @@
 ``sim._run_events`` is a serial argmin+switch ``fori_loop`` lowered through
 XLA: every event re-dispatches a chain of gather/scatter/select HLOs against
 HBM-resident state. This kernel keeps ALL per-replica state — the semantic
-``Sem`` machine (tails/victim/word, per-thread descriptors), the ``ready``/
-``busy``/``op_start`` clocks and the latency ring — resident in VMEM for the
-entire ``n_events`` run: one HBM read and one write per replica, replicas
+``Sem`` machine (tails/victim/word, per-thread descriptors) and the
+``ready``/``busy``/``op_start`` clocks — resident in VMEM for the entire
+``n_events`` run: one HBM read and one write per replica, replicas
 tiled across the first grid axis exactly like ``kernels/alock_tick``.
 
 Layout
   grid = (replica_tiles, event_chunks); the second axis is the innermost
   (sequential) one, so VMEM scratch carries the simulation state from chunk
   to chunk while each chunk streams in its (tile, ev_chunk) slice of the
-  precomputed workload draws. Outputs index-map to the same block for every
-  chunk and are only flushed to HBM when the tile changes.
+  precomputed workload draws and streams out its (tile, ev_chunk) slice of
+  per-event latencies. The other outputs index-map to the same block for
+  every chunk and are only flushed to HBM when the tile changes.
 
 Branch dispatch
   ``sim.sem_step``'s ``lax.switch`` over 14 PC branches is re-expressed as
@@ -24,9 +25,9 @@ Branch dispatch
 
 Clock representation
   Every 64-bit quantity — the ``ready``/``busy``/``op_start`` clocks, the
-  latency ring and the parked-thread ``never`` sentinel — goes through one
-  of two interchangeable representations selected by the static ``repr32``
-  flag (``ops.py`` resolves it):
+  per-event latencies and the parked-thread ``never`` sentinel — goes
+  through one of two interchangeable representations selected by the
+  static ``repr32`` flag (``ops.py`` resolves it):
 
   * ``_I64Clocks`` — plain int64 arrays. The fast path for interpret mode
     and XLA-adjacent hosts; callers hold ``jax.enable_x64(True)``.
@@ -36,14 +37,13 @@ Clock representation
     runs with x64 entirely off. Bitwise-equal to the i64 path (the
     ``tests/test_event_loop_native_repr.py`` differential suite).
 
-  Under the pair representation the latency ring is written as a masked
-  one-hot accumulate over the ``lat_samples`` axis (2D
-  ``broadcasted_iota`` == slot, then select) — bitwise-identical to the
-  per-row scatter but expressible in Mosaic, which rejects per-row
-  dynamic scatters against VMEM state. The i64 fast path keeps the
-  O(1)-per-event scatter (the one-hot form costs O(lat_samples) lane-ops
-  per event, which would tax the interpret-mode CI runs for no
-  benefit). The ring-overflow tests hold the two forms identical.
+  The kernel holds no latency ring. Every event writes its latency, or
+  -1 when it completes nothing, into lane ``e`` of a ``(tile, ev_chunk)``
+  output block streamed along the event axis like the draws, through
+  the same aligned 128-lane window and one-hot select ``draw()`` reads
+  with; ``ops.py`` builds the ``(B, lat_samples)`` ring from that
+  stream outside the kernel. Both representations take this one write
+  path. The ring-overflow tests hold it identical to the XLA engine.
 
 Randomness + workload operands
   The XLA loop draws from ``jax.random.fold_in(key, i)`` per event. The
@@ -74,8 +74,8 @@ from jax.experimental import pallas as pl
 
 from repro.core import machine as mc
 from repro.core.cost_model import N_COST_ROWS
-from repro.core.sim import (LAT_SAMPLES, OP_CS, OP_LOCAL, OP_LOOP, OP_POLL,
-                            OP_RDMA, OP_THINK)
+from repro.core.sim import (OP_CS, OP_LOCAL, OP_LOOP, OP_POLL, OP_RDMA,
+                            OP_THINK)
 from repro.kernels.event_loop import i32pair as p32
 from repro.traffic.metrics import COMPLETED, DROPPED, IN_SERVICE
 
@@ -116,12 +116,12 @@ class _I64Clocks:
     nrefs = 1
 
     @staticmethod
-    def read(refs):
-        return refs[0][...]
+    def read(refs, idx=...):
+        return refs[0][idx]
 
     @staticmethod
-    def write(refs, v):
-        refs[0][...] = v
+    def write(refs, v, idx=...):
+        refs[0][idx] = v
 
     @staticmethod
     def zeros(shape):
@@ -186,13 +186,13 @@ class _PairClocks:
     nrefs = 2
 
     @staticmethod
-    def read(refs):
-        return (refs[0][...], refs[1][...])
+    def read(refs, idx=...):
+        return (refs[0][idx], refs[1][idx])
 
     @staticmethod
-    def write(refs, v):
-        refs[0][...] = v[0]
-        refs[1][...] = v[1]
+    def write(refs, v, idx=...):
+        refs[0][idx] = v[0]
+        refs[1][idx] = v[1]
 
     zeros = staticmethod(p32.pzeros)
 
@@ -221,8 +221,7 @@ class _PairClocks:
 
 
 def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
-                      n_events: int, ev_chunk: int,
-                      lat_samples: int = LAT_SAMPLES, repr32: bool = False,
+                      n_events: int, ev_chunk: int, repr32: bool = False,
                       R: int = 0):
     """One (replica_tile, event_chunk) grid step.
 
@@ -316,11 +315,14 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         for crefs, shape in ((ready_refs, (tile, T)), (busy_refs, (tile, N)),
                              (opst_refs, (tile, T))):
             C.write(crefs, C.zeros(shape))
-        C.write(lat_refs, C.full_m1((tile, lat_samples)))
         if R > 0:
             s_curreq[...] = jnp.full((tile, T), -1, I32)
             C.write(wq_refs, C.full_m1((tile, R)))
             C.write(soj_refs, C.full_m1((tile, R)))
+
+    # the latency block streams out once per chunk: -1 (no completion)
+    # in every lane an event does not overwrite, the ragged tail included
+    C.write(lat_refs, C.full_m1((tile, ev_chunk)))
 
     edges = edges_ref[...].astype(I32)              # (tile, P)
     think = think_ref[...].astype(I32)              # (tile, P)
@@ -357,10 +359,6 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
     # slice at a dynamic lane offset must be provably 128-aligned
     win = 128 if ev_chunk % 128 == 0 else ev_chunk
     wio = _iota((tile, win), 1)
-    if repr32:
-        sio = _iota((tile, lat_samples), 1)   # ring one-hot (Mosaic path)
-    else:
-        rows = jnp.arange(tile)               # ring scatter (fast path)
 
     def gat_t(arr, idx):
         """(tile, T) gathered at per-row thread idx -> (tile,). The sum
@@ -375,13 +373,25 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
                                  arr.dtype.type(0)), axis=1,
                        dtype=arr.dtype)
 
+    def window(e):
+        """The aligned ``win``-lane window of a streamed block holding
+        event ``e``, and the one-hot mask of ``e``'s lane in it."""
+        base = pl.multiple_of(lax.div(e, _I(win)) * _I(win), win)
+        return (slice(None), pl.ds(base, win)), wio == lax.rem(e, _I(win))
+
     def draw(ref, e):
         """Event ``e``'s (tile,) column of a streamed draw block."""
-        base = pl.multiple_of(lax.div(e, _I(win)) * _I(win), win)
-        blk = ref[:, pl.ds(base, win)]
-        return jnp.sum(jnp.where(wio == lax.rem(e, _I(win)), blk,
-                                 blk.dtype.type(0)), axis=1,
+        w, lane = window(e)
+        blk = ref[w]
+        return jnp.sum(jnp.where(lane, blk, blk.dtype.type(0)), axis=1,
                        dtype=blk.dtype)
+
+    def emit(refs, e, m, v):
+        """Write clock column ``v`` into event ``e``'s lane of a streamed
+        output block, in the rows where ``m`` holds."""
+        w, lane = window(e)
+        C.write(refs, C.where(lane & m[:, None], C.col(v), C.read(refs, w)),
+                w)
 
     def phase_row(flat, ph, w):
         """Phase ``ph``'s (tile, w) row of a flat (tile, P*w) block: a
@@ -396,7 +406,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
     state = (s_t0[...], s_t1[...], s_vic[...], s_pc[...], s_bud[...],
              s_nxt[...], s_prev[...], s_tgt[...], s_coh[...],
              C.read(ready_refs), C.read(busy_refs), C.read(opst_refs),
-             done_ref[...], C.read(lat_refs), latn_ref[...][:, 0],
+             done_ref[...], latn_ref[...][:, 0],
              reacq_ref[...][:, 0], npass_ref[...][:, 0])
     if R > 0:
         def gat_r(arr, idx):
@@ -419,12 +429,12 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             st = st[:-1]
         if R > 0:
             (t0, t1, vic, pc, bud, nxt, prv, tgt, coh, ready, busy, opst,
-             done, lat, latn, reacq, npass,
+             done, latn, reacq, npass,
              rstat, curreq, arrptr, qlen, wq, soj) = st
             sem_old = (t0, t1, vic, pc, bud, nxt, prv, tgt, coh)
         else:
             (t0, t1, vic, pc, bud, nxt, prv, tgt, coh, ready, busy, opst,
-             done, lat, latn, reacq, npass) = st
+             done, latn, reacq, npass) = st
 
         # -- phase resolve (pure function of the global event index) -------
         gi = j * ev_chunk + e
@@ -773,7 +783,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         else:
             ready = C.where(ohT, C.col(new_ready), ready)
 
-        # -- completion accounting (latency ring, counters) ----------------
+        # -- completion accounting (latency stream, counters) --------------
         fin_m = is_rc | is_ps | is_slr
         if is_rw:
             # a reader's RD_REL decrement is its release — it completes an
@@ -782,19 +792,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         finished = fin_m & (new_pc == mc.NCS)
         if R > 0:
             finished = finished & step_ok
-        lat_val = C.sub(now, C.gather(ohT, opst))
-        slot = latn % _I(lat_samples)
-        if repr32:
-            # masked one-hot accumulate over the sample axis — bitwise
-            # the scatter below, but expressible in Mosaic (which rejects
-            # per-row dynamic scatters against VMEM state)
-            ohS = (sio == slot[:, None]) & finished[:, None]
-            lat = C.where(ohS, C.col(lat_val), lat)
-        else:
-            # interpret/XLA fast path: the O(1)-per-event scatter (the
-            # one-hot form costs O(lat_samples) lane-ops per event)
-            lat = lat.at[rows, slot].set(
-                jnp.where(finished, lat_val, lat[rows, slot]))
+        emit(lat_refs, e, finished, C.sub(now, C.gather(ohT, opst)))
         latn = latn + finished.astype(I32)
         done = done + jnp.where(ohT & finished[:, None], _I(1), _I(0))
         if R > 0:
@@ -815,7 +813,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             rstat = jnp.where(ohRq & cm, _I(COMPLETED), rstat)
             curreq = jnp.where(ohT & cm, _I(-1), curreq)
             new_st = (t0, t1, vic, pc, bud, nxt, prv, tgt, coh, ready,
-                      busy, opst, done, lat, latn, reacq, npass,
+                      busy, opst, done, latn, reacq, npass,
                       rstat, curreq, arrptr, qlen, wq, soj)
         else:
             opst = C.where(is_ncs[:, None] & ohT, C.col(new_ready), opst)
@@ -823,7 +821,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
                              & (new_pc == mc.SET_VICTIM_R)).astype(I32)
             npass = npass + is_ps.astype(I32)
             new_st = (t0, t1, vic, pc, bud, nxt, prv, tgt, coh, ready,
-                      busy, opst, done, lat, latn, reacq, npass)
+                      busy, opst, done, latn, reacq, npass)
         if is_rw:
             new_st = new_st + (wrd,)
         return new_st
@@ -848,7 +846,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         s_word[...] = state[-1]
         state = state[:-1]
     (t0, t1, vic, pc, bud, nxt, prv, tgt, coh, ready, busy, opst,
-     done, lat, latn, reacq, npass) = state[:17]
+     done, latn, reacq, npass) = state[:16]
 
     for ref, val in ((s_t0, t0), (s_t1, t1), (s_vic, vic), (s_pc, pc),
                      (s_bud, bud), (s_nxt, nxt), (s_prev, prv), (s_tgt, tgt),
@@ -858,13 +856,12 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
                        (opst_refs, opst)):
         C.write(crefs, val)
     done_ref[...] = done
-    C.write(lat_refs, lat)
     latn_ref[...] = latn[:, None]
     C.write(tend_refs, C.col(C.reduce_max(ready)))
     reacq_ref[...] = reacq[:, None]
     npass_ref[...] = npass[:, None]
     if R > 0:
-        (rstat, curreq, arrptr, qlen, wq, soj) = state[17:]
+        (rstat, curreq, arrptr, qlen, wq, soj) = state[16:]
         rstat_ref[...] = rstat
         s_curreq[...] = curreq
         s_arrptr[...] = arrptr[:, None]

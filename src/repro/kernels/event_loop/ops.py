@@ -6,7 +6,9 @@ and returns the same tuple (done, lat, lat_n, t_end, nreacq, npass).
 Replicas are padded to a tile multiple and tiled across the first grid
 axis; events are padded to a chunk multiple and streamed along the second
 (sequential) grid axis while the simulation state persists in VMEM
-scratch.
+scratch. The kernel streams each event's latency out the same way, and
+``latency_ring`` builds the ``(B, lat_samples)`` ring from that stream
+after the ``pallas_call``, so ``lat_samples`` sizes no VMEM buffer.
 
 Clock representation
   ``representation="auto" | "i64" | "i32pair"`` picks how the kernel holds
@@ -211,6 +213,41 @@ def plan_for_run(B, P, n_events, T, N, K, *, R: int = 0,
     return plan
 
 
+def latency_ring(stream, lat_n, lat_samples: int):
+    """The ``(B, lat_samples)`` latency ring from the kernel's per-event
+    stream: the same ring ``sim._run_events`` writes at ``lat_n %
+    lat_samples`` on each completion, last write winning.
+
+    ``stream`` is a tuple of ``(B, E)`` arrays holding one clock per
+    event (one i64 array, or the hi and lo words of a pair), -1 where the
+    event completed nothing; a latency is ``now - op_start >= 0``, so the
+    first word's sign marks a completion. A completion's rank is the
+    number of completions before it; only the last ``lat_samples`` ranks
+    survive a wrap, so those are keyed by their slot ``rank %
+    lat_samples`` and every other event by ``lat_samples`` plus its
+    index. The keys are distinct, and sorting each row by them puts the
+    ring in its first ``lat_samples`` lanes: the kept slots, then events
+    that completed nothing (-1) where fewer than ``lat_samples`` did. On
+    a v5e the sort takes about a fifth of the time of the equivalent
+    ``scatter``.
+
+    >>> import numpy as np
+    >>> s = np.array([[5, -1, 7, 9, -1, 4]], np.int32)
+    >>> np.asarray(latency_ring((s,), np.array([4]), 3)[0]).tolist()
+    [[4, 7, 9]]
+    """
+    fin = stream[0] >= 0
+    n = fin.astype(I32)
+    rank = jnp.cumsum(n, axis=1, dtype=I32) - n
+    keep = fin & (rank >= (lat_n - lat_samples)[:, None])
+    key = jnp.where(keep, rank % lat_samples,
+                    lat_samples + jax.lax.broadcasted_iota(I32, fin.shape, 1))
+    _, *ring = jax.lax.sort((key, *stream), dimension=1, num_keys=1)
+    pad = ((0, 0), (0, max(0, lat_samples - fin.shape[1])))
+    return tuple(jnp.pad(w[:, :lat_samples], pad, constant_values=-1)
+                 for w in ring)
+
+
 def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
                    tile, ev_chunk, interpret, repr32, lat_samples,
                    vmem_budget, device_kind):
@@ -276,19 +313,23 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     def row(w):
         return pl.BlockSpec((tile, w), lambda i, j: (i, _ZERO))
 
-    def clock_out(w):
+    # a (tile, ev_chunk) block per grid step, streamed along the events
+    chunk = pl.BlockSpec((tile, ev_chunk), lambda i, j: (i, j))
+
+    def clock_out(w, spec=None):
         """One (Bp, w) i64 output, or an (hi, lo) pair of i32 outputs."""
+        spec = spec or row(w)
         if repr32:
-            return ([row(w), row(w)],
-                    [jax.ShapeDtypeStruct((Bp, w), I32)] * 2)
-        return [row(w)], [jax.ShapeDtypeStruct((Bp, w), I64)]
+            return ([spec, spec], [jax.ShapeDtypeStruct((Bp, w), I32)] * 2)
+        return [spec], [jax.ShapeDtypeStruct((Bp, w), I64)]
 
     def clock_scratch(w):
         if repr32:
             return [pltpu.VMEM((tile, w), I32)] * 2
         return [pltpu.VMEM((tile, w), I64)]
 
-    lat_specs, lat_shapes = clock_out(lat_samples)
+    # each event's latency (-1: no completion), streamed out by chunk
+    lat_specs, lat_shapes = clock_out(n_events + pad_e, chunk)
     tend_specs, tend_shapes = clock_out(1)
     out_specs = ([row(T)] + lat_specs + [row(1)] + tend_specs
                  + [row(1), row(1)])
@@ -320,7 +361,7 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
         *clock_scratch(T),            # op_start
     ]
     in_specs = (
-        [pl.BlockSpec((tile, ev_chunk), lambda i, j: (i, j))] * len(streams)
+        [chunk] * len(streams)
         + [row(P), row(P), row(P * T)]
         + ([row(P * T)] if is_rw else [])          # read_frac rows
         + [row(P * T), row(P * 2), row(P * N_COST_ROWS), row(P * N),
@@ -349,7 +390,7 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     out = pl.pallas_call(
         functools.partial(event_loop_kernel, alg=alg, T=T, N=N, K=K, P=P,
                           n_events=n_events, ev_chunk=ev_chunk,
-                          lat_samples=lat_samples, repr32=repr32, R=R),
+                          repr32=repr32, R=R),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -364,13 +405,16 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     if repr32:
         (done, lat_hi, lat_lo, lat_n, te_hi, te_lo, nreacq, npass,
          *extra) = out
-        base = (done, (lat_hi, lat_lo), lat_n[:, 0],
-                (te_hi[:, 0], te_lo[:, 0]), nreacq[:, 0], npass[:, 0])
+        base = (done, latency_ring((lat_hi, lat_lo), lat_n[:, 0],
+                                   lat_samples),
+                lat_n[:, 0], (te_hi[:, 0], te_lo[:, 0]), nreacq[:, 0],
+                npass[:, 0])
         if R:
             wq_hi, wq_lo, soj_hi, soj_lo, rstat = extra
             return base + (arr, (wq_hi, wq_lo), (soj_hi, soj_lo), rstat)
         return base
     done, lat, lat_n, t_end, nreacq, npass, *extra = out
+    (lat,) = latency_ring((lat,), lat_n[:, 0], lat_samples)
     base = (done, lat, lat_n[:, 0], t_end[:, 0], nreacq[:, 0],
             npass[:, 0])
     if R:

@@ -2,12 +2,13 @@
 
 The kernel keeps *every* per-replica buffer VMEM-resident for the whole
 ``n_events`` run, so its footprint per grid step is a closed-form function
-of ``(tile, ev_chunk, T, N, K, P, lat_samples, representation)``. On real
-TPU an oversized ``(tile, lat_samples)`` request dies inside Mosaic as an
-opaque VMEM-exhaustion error; this planner computes the byte table up
-front, **deterministically shrinks the replica tile** (halving) until the
-configured budget fits, and raises an actionable ``ValueError`` when even
-the smallest legal tile cannot fit — never a silent wrong answer.
+of ``(tile, ev_chunk, T, N, K, P, representation)``; the latency ring is
+built outside the kernel, so ``lat_samples`` costs no VMEM. On real TPU
+an oversized tile dies inside Mosaic as an opaque VMEM-exhaustion error;
+this planner computes the byte table up front, **deterministically
+shrinks the replica tile** (halving) until the configured budget fits,
+and raises an actionable ``ValueError`` when even the smallest legal
+tile cannot fit — never a silent wrong answer.
 
 Byte formula (one grid step = one replica tile; int32/float32 = 4 bytes,
 clocks = 8 bytes, as one i64 buffer or an (hi, lo) i32 pair):
@@ -18,8 +19,10 @@ clocks = 8 bytes, as one i64 buffer or an (hi, lo) i32 pair):
                     ``tile*P*T*4`` each; b_init ``tile*P*2*4``; cost_rows
                     ``tile*P*8*4``; node_mult ``tile*P*N*4``; thread_node
                     ``T*4``; lock_node ``K*4``
-  outputs           done ``tile*T*4``; latency ring ``tile*lat_samples*8``;
-                    lat_n/reacq/npass ``tile*4`` each; t_end ``tile*8``
+  outputs           done ``tile*T*4``; per-event latency stream
+                    ``tile*ev_chunk*8``, **x2** double-buffered like the
+                    draws; lat_n/reacq/npass ``tile*4`` each; t_end
+                    ``tile*8``
   scratch           tails/victim ``3 * tile*K*4``; six per-thread i32
                     descriptors ``tile*T*4``; ready/op_start ``tile*T*8``;
                     busy ``tile*N*8``
@@ -43,7 +46,7 @@ the ``benchmarks.run`` scenario summaries.
 (8, False, True)
 >>> tight = plan_vmem(tile=64, ev_chunk=512, T=16, N=4, K=16, P=1,
 ...                   lat_samples=1 << 15, repr32=True,
-...                   budget=4 * 2**20)
+...                   budget=256 * 2**10)
 >>> tight.requested_tile, tight.tile, tight.shrunk
 (64, 8, True)
 """
@@ -88,14 +91,18 @@ def budget_for(device_kind: str) -> int:
 _I32 = 4
 _F32 = 4
 _CLOCK = 8          # one i64 buffer, or an (hi, lo) i32 pair — same bytes
-#: the sequential event axis streams u1/r2/r3 chunk by chunk; Pallas
-#: double-buffers streamed inputs so the next chunk loads during compute
+#: the sequential event axis streams u1/r2/r3 in and the per-event
+#: latencies out chunk by chunk; Pallas double-buffers streamed blocks so
+#: the next chunk moves during compute
 PIPELINE_FACTOR = 2
 #: the table entries the pipeline factor applies to — `repro.analysis`'s
 #: vmem-consistency rule divides it back out when diffing the table
 #: against the traced kernel's buffer bindings (in.u4 is the alock-rw
 #: reader/writer coin stream and only present when ``rw=True``)
 STREAMED_INPUTS = ("in.u1", "in.r2", "in.r3", "in.u4")
+#: the streamed outputs: the per-event latency, one i64 block or a pair
+STREAMED_OUTPUTS = ("out.lat_stream", "out.lat_stream.hi",
+                    "out.lat_stream.lo")
 
 
 def _entries(name, shape, itemsize, factor=1):
@@ -105,18 +112,18 @@ def _entries(name, shape, itemsize, factor=1):
     return (name, (shape, n * itemsize))
 
 
-def _clock_entries(name, shape, repr32: bool):
+def _clock_entries(name, shape, repr32: bool, factor=1):
     """One i64 buffer, or two i32 buffers for the hi/lo representation —
     the shapes here must match ``ops.run_events``'s allocations exactly."""
     if repr32:
-        return [_entries(f"{name}.hi", shape, _I32),
-                _entries(f"{name}.lo", shape, _I32)]
-    return [_entries(name, shape, _CLOCK)]
+        return [_entries(f"{name}.hi", shape, _I32, factor),
+                _entries(f"{name}.lo", shape, _I32, factor)]
+    return [_entries(name, shape, _CLOCK, factor)]
 
 
 def buffer_table(tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
-                 lat_samples: int, repr32: bool, R: int = 0,
-                 hl: bool = False, rw: bool = False) -> dict:
+                 repr32: bool, R: int = 0, hl: bool = False,
+                 rw: bool = False) -> dict:
     """name -> (block shape, bytes) for every VMEM buffer of one grid step.
 
     Mirrors the ``in_specs`` / ``out_specs`` / ``scratch_shapes`` that
@@ -155,9 +162,11 @@ def buffer_table(tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
            _entries("in.tok", (tile, R), _I32),
            _entries("in.tokcum", (tile, R), _I32),
            _entries("in.qcap", (tile, R), _I32)] if R else []),
-        # outputs (flushed when the replica tile changes)
+        # outputs (flushed when the replica tile changes, but for the
+        # latency stream: STREAMED_OUTPUTS, double-buffered like the draws)
         _entries("out.done", (tile, T), _I32),
-        *_clock_entries("out.lat", (tile, lat_samples), repr32),
+        *_clock_entries("out.lat_stream", (tile, ev_chunk), repr32,
+                        PIPELINE_FACTOR),
         _entries("out.lat_n", (tile, 1), _I32),
         *_clock_entries("out.t_end", (tile, 1), repr32),
         _entries("out.reacq", (tile, 1), _I32),
@@ -238,8 +247,7 @@ def plan_vmem(*, tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
     requested = tile
     t = tile
     while True:
-        table = buffer_table(t, ev_chunk, T, N, K, P, lat_samples, repr32,
-                             R, hl, rw)
+        table = buffer_table(t, ev_chunk, T, N, K, P, repr32, R, hl, rw)
         total = sum(b for _, b in table.values())
         if budget is None or total <= budget or t <= align:
             break
@@ -251,8 +259,8 @@ def plan_vmem(*, tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
         raise ValueError(
             f"event-loop kernel cannot fit VMEM budget {budget:,}B even at "
             f"tile={t} (needs {total:,}B; largest buffers: {detail}). Lower "
-            f"lat_samples ({lat_samples}) or ev_chunk ({ev_chunk}), or "
-            f"raise the budget (run_events(vmem_budget=...)).")
+            f"ev_chunk ({ev_chunk}), or raise the budget "
+            f"(run_events(vmem_budget=...)).")
     return VmemPlan(requested_tile=requested, tile=t, ev_chunk=ev_chunk,
                     lat_samples=lat_samples,
                     representation="i32pair" if repr32 else "i64",

@@ -311,6 +311,62 @@ def test_ring_overflow_identical_across_engines():
         assert np.percentile(eng[1][0], 99) == np.percentile(ref[1][0], 99)
 
 
+def _ring_bucket(alg, ev):
+    """Three replicas of one bucket (B=3, so a tile of 2 pads a row) on
+    2 nodes x 2 threads with one lock per node. The middle replica's
+    critical section costs 2**30 ns: its holders stay inside while the
+    other threads spin through every event, so it completes nothing."""
+    N, tpn, K = 2, 2, 2
+    topo = (0, 0) if alg == "hlock" else None     # one rack: three tiers
+    _, wl = _stack_operands([Workload(alg, N, tpn, K, locality=0.8,
+                                      seed=21 + s, topology=topo)
+                             for s in range(3)], ev)
+    cost = np.array(wl.cost_rows)
+    cost[1, :, 2] = 2**30                         # the CS cost row
+    tn, ln, _ = topology(alg, N, tpn, K)
+    return N * tpn, N, K, wl._replace(cost_rows=jnp.asarray(cost)), tn, ln
+
+
+#: XLA oracle outputs by (alg, lat_samples), shared by both
+#: representations' cases of the wraparound test
+_RING_REFS: dict = {}
+
+
+def _ring_ref(alg, ev, lat_samples):
+    if (alg, lat_samples) not in _RING_REFS:
+        T, N, K, wl, tn, ln = _ring_bucket(alg, ev)
+        with jax.enable_x64(True):
+            _RING_REFS[alg, lat_samples] = [
+                np.asarray(r) for r in
+                run_events_ref(alg, T, N, K, ev, wl, tn, ln,
+                               lat_samples=lat_samples)]
+    return _RING_REFS[alg, lat_samples]
+
+
+@pytest.mark.parametrize("representation", ["i64", "i32pair"])
+@pytest.mark.parametrize("alg", ["alock", "mcs", "spinlock", "hlock"])
+def test_streamed_ring_wraparound_bitwise(alg, representation):
+    """The ring ``ops.py`` builds from the kernel's per-event latency
+    stream equals the XLA engine's ring bitwise, whatever the ring size:
+    completions that fill exactly ``lat_samples``, one more and one fewer
+    than it, and three or more wraps; with a ragged last event chunk
+    (700 % 256 != 0), B=3 against a tile of 2, and a replica that
+    completes nothing."""
+    ev = 700
+    T, N, K, wl, tn, ln = _ring_bucket(alg, ev)
+    n = _ring_ref(alg, ev, 8)[2]            # lat_n: any ring size gives it
+    assert n[1] == 0 and n[0] >= 40, n      # the idle replica, and wraps
+    for lat_samples in (n[0], n[0] - 1, n[0] + 1, n[0] // 3 - 1):
+        ref = _ring_ref(alg, ev, int(lat_samples))
+        with jax.enable_x64(True):
+            got = [np.asarray(r) for r in
+                   run_events(alg, T, N, K, ev, wl, tn, ln, interpret=True,
+                              representation=representation, tile=2,
+                              ev_chunk=256, lat_samples=int(lat_samples))]
+        _assert_bitwise(ref, got)
+        assert (ref[1][1] == -1).all()      # the idle replica's ring
+
+
 def test_packed_run_events_i32pair_matches_i64():
     """The public ``run_events(representation=)`` contract: both
     representations return identical int64 outputs under x64."""

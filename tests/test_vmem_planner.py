@@ -19,6 +19,8 @@ from repro.workloads import Arrivals, Workload, WorkloadOperands, lower
 
 ARGS = dict(tile=4, ev_chunk=256, T=12, N=3, K=6, P=2,
             lat_samples=LAT_SAMPLES)
+#: ``buffer_table`` takes the kernel's shapes; ``lat_samples`` is not one
+SHAPE = {k: v for k, v in ARGS.items() if k != "lat_samples"}
 
 
 def test_buffer_table_matches_kernel_allocations():
@@ -27,7 +29,7 @@ def test_buffer_table_matches_kernel_allocations():
     parameters (the interpret-mode allocations the acceptance criterion
     points at)."""
     for repr32 in (False, True):
-        t = vmem.buffer_table(repr32=repr32, **ARGS)
+        t = vmem.buffer_table(repr32=repr32, **SHAPE)
         tile, ev_chunk, T, N, K, P = (ARGS["tile"], ARGS["ev_chunk"],
                                       ARGS["T"], ARGS["N"], ARGS["K"],
                                       ARGS["P"])
@@ -37,15 +39,18 @@ def test_buffer_table_matches_kernel_allocations():
         assert t["in.cost_rows"][0] == (tile, P * N_COST_ROWS)
         assert t["in.thread_node"][0] == (1, T)
         assert t["in.lock_node"][0] == (1, K)
-        # outputs: one i64 ring vs an (hi, lo) i32 pair, same total bytes
+        # outputs: the per-event latency stream, one i64 block vs an
+        # (hi, lo) i32 pair, same total bytes, double-buffered like the
+        # draws; no (tile, lat_samples) ring is priced
+        stream = tile * ev_chunk * 8 * vmem.PIPELINE_FACTOR
         if repr32:
-            assert t["out.lat.hi"][0] == (tile, LAT_SAMPLES)
-            assert (t["out.lat.hi"][1] + t["out.lat.lo"][1]
-                    == tile * LAT_SAMPLES * 8)
-            assert "out.lat" not in t
+            assert t["out.lat_stream.hi"][0] == (tile, ev_chunk)
+            assert (t["out.lat_stream.hi"][1] + t["out.lat_stream.lo"][1]
+                    == stream)
+            assert "out.lat_stream" not in t
         else:
-            assert t["out.lat"] == ((tile, LAT_SAMPLES),
-                                    tile * LAT_SAMPLES * 8)
+            assert t["out.lat_stream"] == ((tile, ev_chunk), stream)
+        assert not any(LAT_SAMPLES in shape for shape, _ in t.values())
         # scratch: semantic i32 + clock buffers
         assert t["scr.tail0"] == ((tile, K), tile * K * 4)
         assert t["scr.pc"] == ((tile, T), tile * T * 4)
@@ -95,7 +100,8 @@ def test_plan_matches_measured_pallas_buffers(monkeypatch):
     # native representation), x2 for the double-buffered event streams
     for k, (shape, nbytes) in t.items():
         factor = (vmem.PIPELINE_FACTOR
-                  if k in ("in.u1", "in.r2", "in.r3") else 1)
+                  if k in ("in.u1", "in.r2", "in.r3", "out.lat_stream.hi",
+                           "out.lat_stream.lo") else 1)
         assert nbytes == int(np.prod(shape)) * 4 * factor, k
 
 
@@ -153,9 +159,20 @@ def test_plan_representations_cost_identical_bytes():
     assert a.total_bytes == b.total_bytes
 
 
+def test_plan_total_does_not_grow_with_lat_samples():
+    """The ring is built outside the kernel: ``lat_samples`` changes no
+    VMEM buffer, so the priced total is the same at any ring size."""
+    totals = {vmem.plan_vmem(repr32=repr32, **dict(ARGS, lat_samples=n))
+              .total_bytes for repr32 in (False, True)
+              for n in (64, 1024, LAT_SAMPLES, 4 * LAT_SAMPLES)}
+    assert len(totals) == 1
+
+
 def test_oversize_tile_auto_shrinks_deterministically():
     kw = dict(ARGS, tile=64)
-    budget = 4 * 2**20
+    # ~11 KiB a tile row (the double-buffered draw and latency streams):
+    # tile 64 needs ~700 KiB, so 256 KiB forces the 64 -> 32 -> 16 path
+    budget = 256 * 2**10
     p1 = vmem.plan_vmem(repr32=True, budget=budget, **kw)
     p2 = vmem.plan_vmem(repr32=True, budget=budget, **kw)
     assert p1 == p2                       # deterministic
@@ -170,7 +187,9 @@ def test_oversize_tile_auto_shrinks_deterministically():
 
 
 def test_impossible_budget_raises_actionable_error():
-    with pytest.raises(ValueError, match="lat_samples"):
+    # tile 1 needs 11,268 B at ev_chunk 256; the hint names ev_chunk, the
+    # knob that sizes the streamed blocks
+    with pytest.raises(ValueError, match="ev_chunk"):
         vmem.plan_vmem(repr32=True, budget=10_000, **ARGS)
     # bad arguments are real errors too
     with pytest.raises(ValueError, match="tile"):
@@ -196,9 +215,10 @@ def test_budgeted_run_shrinks_tile_and_stays_bitwise():
     is never allowed to become a silent wrong answer)."""
     wl, tn, ln, ev = _replicas(B=6)
     lat_samples = 1024
-    # 6 replicas at lat_samples=1024 / ev_chunk=128 cost ~12 KiB per tile
-    # row; 24 KiB forces the 6 -> 3 -> 1 halving path
-    budget = 24 * 1024
+    # ev_chunk=128 costs ~5.5 KiB per tile row (the double-buffered draw
+    # and latency streams; lat_samples costs no VMEM): tile 3 needs
+    # ~16 KiB, tile 1 ~5.4 KiB, so 12 KiB forces the 6 -> 3 -> 1 halving
+    budget = 12 * 1024
     with jax.enable_x64(True):
         ref = [np.asarray(r) for r in
                run_events_ref("alock", 4, 2, 8, ev, wl, tn, ln,
