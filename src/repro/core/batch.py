@@ -71,7 +71,8 @@ call carries the same ``sweep`` ordinal in its metadata:
   * ``batch.dispatch`` — the device put of the operands, the VMEM plan
     and the jitted call up to its return: the enqueue, plus any compile
     (per bucket, or per superchunk when sharded; ``bucket``, ``rows``,
-    ``bytes`` put, ``new_shape``);
+    ``bytes`` put, ``new_shape``, and the bucket's ``lock_lanes`` K,
+    ``draw_streams`` 3 or 4 and ``zipf_ranks`` K/N);
   * ``batch.fetch`` — the wait for the device and the copy of the outputs
     back to the host (``bucket``, ``bytes`` fetched);
   * ``batch.aggregate`` — one bucket's reshapes into ``BatchResult``
@@ -357,6 +358,15 @@ class BatchResult(NamedTuple):
                            / np.sqrt(len(per_seed)))
 
 
+def _draw_shape(alg: str, N: int, K: int) -> dict:
+    """A bucket's lock-state and draw-stream widths, as dispatch-span
+    metadata: the lanes of the lock table, the precomputed draw streams
+    (the alock-rw reader coin is the fourth) and the Zipf ranks a node's
+    inverse-CDF scans."""
+    return {"lock_lanes": K, "draw_streams": 4 if alg == "alock-rw" else 3,
+            "zipf_ranks": K // N}
+
+
 def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
                  backend: str, devices, chunk, sweep_id: int,
                  bucket: int):
@@ -378,7 +388,8 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
     n_phases = wl.edges.shape[1]
     if devices is None and chunk is None:
         with TraceAnnotation(SPAN_DISPATCH, sweep=sweep_id, bucket=bucket,
-                             rows=B, bytes=_nbytes(wl)) as span:
+                             rows=B, bytes=_nbytes(wl),
+                             **_draw_shape(alg, N, K)) as span:
             with jax.enable_x64(True):
                 wj = WorkloadOperands(*(jnp.asarray(a) for a in wl))
                 if backend == "pallas":
@@ -441,7 +452,8 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
             args = [a[sl] for a in leaves]
             with TraceAnnotation(SPAN_DISPATCH, sweep=sweep_id,
                                  bucket=bucket, rows=nrows,
-                                 bytes=_nbytes(args)) as span:
+                                 bytes=_nbytes(args),
+                                 **_draw_shape(alg, N, K)) as span:
                 if backend == "pallas":
                     # each shard's kernel sees nrows/D replicas (same
                     # trace-time-only caveat as the unsharded branch above)

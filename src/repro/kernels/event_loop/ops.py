@@ -98,6 +98,9 @@ LANES = 128
 #: index-map constant: a python 0 would trace as int64 under x64, which
 #: Mosaic refuses in the grid's index functions
 _ZERO = np.int32(0)
+#: the named scope of the draw precompute and its stream padding: their
+#: HLO ops carry it in their ``op_name`` metadata
+DRAWS_SCOPE = "event_loop.draws"
 
 _REPRESENTATIONS = ("auto", "i64", "i32pair")
 
@@ -141,7 +144,7 @@ def precompute_draws(seed, edges, zcdf, n_events: int, N: int, kpn: int,
     function of the event index), so consuming the stream in-kernel
     reproduces the XLA path bit for bit. ``rw=True`` mirrors the
     alock-rw engine's 4-way split and appends the reader/writer coin
-    uniform (f32) as a fourth stream.
+    uniform (f32) as a fourth stream. Traced under ``DRAWS_SCOPE``.
     """
     def one(sd, ed, cdf):
         key = jax.random.key(sd)
@@ -164,7 +167,8 @@ def precompute_draws(seed, edges, zcdf, n_events: int, N: int, kpn: int,
 
         return jax.vmap(ev)(jnp.arange(n_events))
 
-    return jax.vmap(one)(seed, edges, zcdf)
+    with jax.named_scope(DRAWS_SCOPE):
+        return jax.vmap(one)(seed, edges, zcdf)
 
 
 def plan_for_run(B, P, n_events, T, N, K, *, R: int = 0,
@@ -276,8 +280,9 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
         return jnp.pad(a, ((0, pad_b),) + ((0, 0),) * (a.ndim - 1),
                        mode="edge") if pad_b else a
 
-    streams = [jnp.pad(prep(a), ((0, 0), (0, pad_e))) if pad_e
-               else prep(a) for a in streams]
+    with jax.named_scope(DRAWS_SCOPE):
+        streams = [jnp.pad(prep(a), ((0, 0), (0, pad_e))) if pad_e
+                   else prep(a) for a in streams]
     # per-phase payloads ride flattened to 2D blocks (P*T / P*2 / P*8
     # lanes); the kernel reshapes them back — P is static via the shape
     locp = prep(wl.locality.reshape(B, P * T))

@@ -98,7 +98,11 @@ def check_call(spans, n_configs, n_seeds, n_events, bucket_rows):
         assert fetch[3]["bytes"] >= rows * LAT_SAMPLES * 4
         for d in mine[1:-2]:
             assert set(d[3]) == {"sweep", "bucket", "rows", "bytes",
-                                 "new_shape"}
+                                 "new_shape", "lock_lanes", "draw_streams",
+                                 "zipf_ranks"}
+            # every bucket here: 2 nodes, 4 locks, exclusive machines
+            assert (d[3]["lock_lanes"], d[3]["draw_streams"],
+                    d[3]["zipf_ranks"]) == (4, 3, 2)
             # every operand leaf has the dispatch's rows as leading axis
             assert d[3]["bytes"] > 0 and d[3]["bytes"] % d[3]["rows"] == 0
             assert d[3]["new_shape"] in (0, 1)

@@ -17,7 +17,8 @@ import jax
 from repro.core.sim import topology
 from repro.experiments import fig5_workloads, scenario_workloads
 from repro.kernels.event_loop import ops
-from repro.workloads import WorkloadOperands, lower, pad_phases
+from repro.workloads import (Workload, WorkloadOperands, lower,
+                             pad_phases)
 
 #: long enough for several event chunks and a ragged last one
 EVENTS = 20_000
@@ -116,6 +117,19 @@ def test_hlock_with_racks_compiles(one_chip):
 def test_alock_rw_compiles(one_chip):
     ws = [w for w in scenario_workloads("read-heavy") if w.alg == "alock-rw"]
     _compile(one_chip, ws, n_seeds=2)
+
+
+def test_ycsb_b_bucket_compiles(one_chip):
+    """The YCSB-B lock table (``bench/configs/ycsb-b-n10-k1000.json``):
+    alock-rw on the fig5 cluster with 1,000 locks (a K axis of 8 lane
+    vregs, not a multiple of 128), Zipf 0.99 draws and the reader coin,
+    3 localities x 24 seeds = 72 replicas in one bucket."""
+    ws = [Workload("alock-rw", 10, 8, 1000, locality=loc, zipf_s=0.99,
+                   read_frac=0.95, b_init=(5, 20), seed=1 + 100 * i)
+          for i, loc in enumerate((0.85, 0.95, 1.0))]
+    plan = _compile(one_chip, ws, n_seeds=24)
+    assert (plan.tile, plan.representation) == (8, "i32pair")
+    assert not plan.shrunk and plan.total_bytes <= plan.budget
 
 
 def test_ragged_batch_compiles(one_chip):
