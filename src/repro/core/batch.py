@@ -72,7 +72,9 @@ call carries the same ``sweep`` ordinal in its metadata:
     and the jitted call up to its return: the enqueue, plus any compile
     (per bucket, or per superchunk when sharded; ``bucket``, ``rows``,
     ``bytes`` put, ``new_shape``, and the bucket's ``lock_lanes`` K,
-    ``draw_streams`` 3 or 4 and ``zipf_ranks`` K/N);
+    ``draw_streams`` 3 or 4 and ``zipf_ranks`` K/N; on the Pallas
+    backend also each kernel call's ``tile``, ``tiles`` and
+    ``padded_rows``, per shard when sharded);
   * ``batch.fetch`` — the wait for the device and the copy of the outputs
     back to the host (``bucket``, ``bytes`` fetched);
   * ``batch.aggregate`` — one bucket's reshapes into ``BatchResult``
@@ -398,8 +400,11 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
                     # re-record the VMEM plan per dispatch: planning inside
                     # run_events is trace-time only, so a cached executable
                     # would otherwise leave exec_stats()["vmem_plan"] stale
-                    plan_for_run(B, n_phases, n_events, T, N, K, R=R,
-                                 hl=alg == "hlock", rw=alg == "alock-rw")
+                    plan = plan_for_run(B, n_phases, n_events, T, N, K, R=R,
+                                        hl=alg == "hlock",
+                                        rw=alg == "alock-rw")
+                    span.set_metadata(tile=plan.tile, tiles=plan.tiles,
+                                      padded_rows=plan.padded_rows)
                     out = run_events_jit(alg, T, N, K, n_events, wj,
                                          thread_node, lock_node)
                 else:
@@ -458,9 +463,11 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
                     # each shard's kernel sees nrows/D replicas (same
                     # trace-time-only caveat as the unsharded branch above)
                     from repro.kernels.event_loop.ops import plan_for_run
-                    plan_for_run(nrows // D, n_phases, n_events, T, N, K,
-                                 R=R, hl=alg == "hlock",
-                                 rw=alg == "alock-rw")
+                    plan = plan_for_run(nrows // D, n_phases, n_events, T,
+                                        N, K, R=R, hl=alg == "hlock",
+                                        rw=alg == "alock-rw")
+                    span.set_metadata(tile=plan.tile, tiles=plan.tiles,
+                                      padded_rows=plan.padded_rows)
                 # async: the call returns device futures — every
                 # superchunk is issued before any result is forced below
                 outs.append(runner(*args, tn, ln))

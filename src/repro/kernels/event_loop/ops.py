@@ -25,15 +25,27 @@ Clock representation
   (hi, lo) arrays and never touches an int64 — that is the entry point
   the x64-off CI leg exercises.
 
+Replica tile
+  Each grid tile runs its rows' events as one dependent chain of
+  cross-lane reductions, and the tiles run one after another, so an
+  event costs about one chain per tile whatever the rows in it: on a
+  v5e one event of a tile took 1.12 us at 8 rows and 1.41 us at 24
+  (``MAX_TILE`` holds the curve). ``plan_for_run`` therefore derives the
+  tile from the bucket's rows B: the fewest tiles of at most
+  ``MAX_TILE`` rows, split evenly so the edge padding is least, each a
+  multiple of 8 rows or the whole batch under Mosaic. A bucket of up to
+  ``MAX_TILE`` rows is one tile. ``tile=`` overrides the ceiling.
+
 VMEM budget
   Before tracing, the ``vmem.py`` planner prices every VMEM buffer of one
-  grid step and deterministically shrinks the replica tile to fit
-  ``vmem_budget`` bytes (default: ``vmem.budget_for`` the device's
-  ``device_kind`` for native lowering — an unknown device is an error —
-  unconstrained under interpret); the native ``pallas_call`` gets the
-  device's ``vmem.limit_for`` as ``vmem_limit_bytes``, whatever the
-  budget. ``device_kind`` names the target when compiling for a device
-  that is not attached (default: ``jax.devices()[0]``). An impossible budget raises an actionable
+  grid step and deterministically shrinks the tile's ceiling to fit
+  ``vmem_budget`` bytes before the rows are split under it (default:
+  ``vmem.budget_for`` the device's ``device_kind`` for native lowering —
+  an unknown device is an error — unconstrained under interpret); the
+  native ``pallas_call`` gets the device's ``vmem.limit_for`` as
+  ``vmem_limit_bytes``, whatever the budget. ``device_kind`` names the
+  target when compiling for a device that is not attached (default:
+  ``jax.devices()[0]``). An impossible budget raises an actionable
   ``ValueError`` instead of a Mosaic OOM; the chosen plan is recorded and
   surfaced through ``repro.core.batch.exec_stats()`` and the benchmark
   reports.
@@ -73,6 +85,7 @@ True
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 
@@ -90,7 +103,18 @@ from repro.kernels.event_loop.kernel import event_loop_kernel
 from repro.traffic.stream import (arrival_plan, arrival_times_i64,
                                   arrival_times_pairs)
 
-DEFAULT_TILE = 8
+#: the most replica rows one grid tile holds. One event of one tile took,
+#: on a v5e (us, kernel device time over 20,000 events): at T=80, K=100
+#: (fig5 alock) 1.12 at 8 rows, 1.20 at 16, 1.41 at 24, 1.83 at 56, 2.26
+#: at 72, 3.23 at 128 and 4.43 at 192; at K=1000 (alock-rw) 1.07 at 8,
+#: 2.98 at 72 and 5.11 at 144; at T=240 1.13 at 8, 2.02 at 56 and 3.61 at
+#: 128; open loop (R=256) 1.59 at 8 and 3.95 at 96. The cost per row
+#: falls in every shape as far as measured, so a bucket runs best in the
+#: fewest tiles. The ceiling is where the gain flattens: 192 rows gained
+#: 8% per row over 128 (23.1 against 25.2 ns per replica-event), and 128
+#: rows of the widest measured shape (K=1000, 27.8 MB priced) still fit
+#: the v5e budget unhalved
+MAX_TILE = 128
 DEFAULT_EV_CHUNK = 4096
 #: the (8, 128) vreg tiling Mosaic's block shapes must respect
 SUBLANES = 8
@@ -172,31 +196,42 @@ def precompute_draws(seed, edges, zcdf, n_events: int, N: int, kpn: int,
 
 
 def plan_for_run(B, P, n_events, T, N, K, *, R: int = 0,
-                 tile: int = DEFAULT_TILE,
+                 tile: int | None = None,
                  ev_chunk: int = DEFAULT_EV_CHUNK, interpret=None,
                  representation: str = "auto",
                  lat_samples: int = LAT_SAMPLES,
                  vmem_budget: int | None = None,
                  device_kind: str | None = None,
                  hl: bool = False, rw: bool = False) -> vmem.VmemPlan:
-    """Resolve representation/budget, clamp (tile, ev_chunk) exactly like
-    ``run_events`` will, and record the resulting VMEM plan.
+    """Resolve representation/budget, derive and clamp (tile, ev_chunk)
+    exactly like ``run_events`` will, and record the resulting VMEM plan.
+
+    The tile is derived from B (module docstring, "Replica tile"). The
+    ceiling is ``MAX_TILE`` rows, or ``tile`` where given, rounded up to
+    a multiple of 8 under Mosaic unless it is the whole batch; the VMEM
+    planner halves that ceiling until the budget fits. B is then split
+    evenly into the fewest tiles under the ceiling, ``ceil(B / tiles)``
+    rows each, aligned the same way, so even a shrunk tile pads least.
+    The plan records B as ``rows``, with the grid's ``tiles`` and
+    ``padded_rows``; ``requested_tile`` is the tile without a budget.
 
     This is the *single* clamping+planning code path — ``run_events`` goes
     through it at trace time, and ``batch._exec_bucket`` calls it per
-    dispatch so ``exec_stats()["vmem_plan"]`` stays populated even when
+    dispatch so ``exec_stats()["vmem_plans"]`` stays populated even when
     the jitted kernel call is a cache hit (planning is python-level and
     does not re-run inside a cached executable).
+
+    >>> [plan_for_run(b, 1, 512, 80, 10, 100, interpret=False,
+    ...               device_kind="TPU v5 lite").tile
+    ...  for b in (8, 9, 24, 72, 130)]
+    [8, 9, 24, 72, 72]
     """
     if interpret is None:
         interpret = default_interpret()
     repr32 = resolve_representation(representation, interpret) == "i32pair"
     if vmem_budget is None and not interpret:
         vmem_budget = vmem.budget_for(device_kind or _device_kind())
-    tile = max(1, min(tile, B))
-    # same grid-dim count, minimal edge padding: B=9, tile=8 pads 7 rows
-    # of dead kernel work; tile=5 runs the same two tiles padding 1
-    tile = -(-B // -(-B // tile))
+    cap = max(1, min(MAX_TILE if tile is None else tile, B))
     ev_chunk = max(1, min(ev_chunk, max(n_events, 1)))
     align = 1
     if not interpret:
@@ -204,15 +239,26 @@ def plan_for_run(B, P, n_events, T, N, K, *, R: int = 0,
         # the whole batch, the event (lane) dim a multiple of 128 or the
         # whole padded run; rows and events are padded to match
         align = SUBLANES
-        if tile < B:
-            tile = min(-(-tile // SUBLANES) * SUBLANES, B)
+        if cap < B:
+            cap = min(-(-cap // SUBLANES) * SUBLANES, B)
         if ev_chunk < n_events:
             ev_chunk = max(LANES, ev_chunk // LANES * LANES)
-    # price the VMEM footprint up front: shrink the replica tile to fit
-    # the budget (or raise actionably) instead of dying inside Mosaic
-    plan = vmem.plan_vmem(tile=tile, ev_chunk=ev_chunk, T=T, N=N, K=K, P=P,
-                          lat_samples=lat_samples, repr32=repr32, R=R,
-                          hl=hl, rw=rw, budget=vmem_budget, align=align)
+
+    def split(cap):
+        # the fewest tiles of at most `cap` rows, split evenly: B=9 at
+        # cap 8 runs two tiles either way, and a tile of 5 pads 1 row,
+        # not 7. Never above an aligned cap, so never above its bytes
+        t = -(-B // -(-B // cap))
+        return t if t == B else min(-(-t // align) * align, B)
+
+    # price the VMEM footprint up front: the budget sets the ceiling (or
+    # raises actionably) instead of the kernel dying inside Mosaic
+    shape = dict(ev_chunk=ev_chunk, T=T, N=N, K=K, P=P,
+                 lat_samples=lat_samples, repr32=repr32, R=R, hl=hl, rw=rw,
+                 budget=vmem_budget, align=align)
+    fit = vmem.plan_vmem(tile=cap, **shape).tile
+    plan = dataclasses.replace(vmem.plan_vmem(tile=split(fit), **shape),
+                               requested_tile=split(cap), rows=B)
     vmem.note_plan(plan)
     return plan
 
@@ -271,8 +317,7 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
                         representation="i32pair" if repr32 else "i64",
                         lat_samples=lat_samples, vmem_budget=vmem_budget,
                         device_kind=device_kind, hl=is_hl, rw=is_rw)
-    tile, ev_chunk = plan.tile, plan.ev_chunk
-    pad_b = -B % tile
+    tile, ev_chunk, pad_b = plan.tile, plan.ev_chunk, plan.padded_rows
     pad_e = -n_events % ev_chunk
 
     def prep(a):
@@ -429,7 +474,7 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
 
 
 def run_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
-               tile: int = DEFAULT_TILE, ev_chunk: int = DEFAULT_EV_CHUNK,
+               tile: int | None = None, ev_chunk: int = DEFAULT_EV_CHUNK,
                interpret=None, representation: str = "auto",
                lat_samples: int = LAT_SAMPLES,
                vmem_budget: int | None = None,
@@ -452,10 +497,11 @@ def run_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     dispatched/completed) and rstat (B,R) i32 ``repro.traffic`` status
     codes.
 
-    B need not divide the replica tile and n_events need not divide the
-    event chunk: replicas are edge-padded (duplicates, sliced off) and the
-    final chunk masks events past n_events inside the kernel. The tile may
-    additionally be shrunk by the VMEM planner (see module docstring);
+    ``tile=None`` derives the replica tile from B (module docstring,
+    "Replica tile"). B need not divide the tile and n_events need not
+    divide the event chunk: replicas are edge-padded (duplicates, sliced
+    off) and the final chunk masks events past n_events inside the
+    kernel. The tile may additionally be shrunk by the VMEM planner;
     ``vmem_budget=None`` means the default budget for native lowering and
     unconstrained under interpret.
     """
@@ -494,7 +540,7 @@ def run_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
 
 
 def run_events_pairs(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
-                     tile: int = DEFAULT_TILE,
+                     tile: int | None = None,
                      ev_chunk: int = DEFAULT_EV_CHUNK, interpret=None,
                      lat_samples: int = LAT_SAMPLES,
                      vmem_budget: int | None = None,
@@ -538,7 +584,7 @@ _jit_run_events = functools.partial(
 
 
 def run_events_jit(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
-                   tile: int = DEFAULT_TILE,
+                   tile: int | None = None,
                    ev_chunk: int = DEFAULT_EV_CHUNK, interpret=None,
                    representation: str = "auto",
                    lat_samples: int = LAT_SAMPLES,

@@ -27,9 +27,11 @@ clocks = 8 bytes, as one i64 buffer or an (hi, lo) i32 pair):
                     descriptors ``tile*T*4``; ready/op_start ``tile*T*8``;
                     busy ``tile*N*8``
 
-The ``tile`` this planner receives is already padding-minimized by
-``ops.plan_for_run`` (``ceil(B / ceil(B / tile))``: same grid-dim count,
-smallest edge pad), so the byte table prices the tile the kernel really
+``ops.plan_for_run`` derives the tile from the bucket's rows B: it
+first lets this planner halve the ceiling (``ops.MAX_TILE`` rows, or
+``tile=``) until the budget fits, then splits B evenly into the fewest
+tiles under that ceiling (``ceil(B / ceil(B / ceiling))`` rows each:
+smallest edge pad). The byte table prices the tile the kernel really
 runs, and the ragged event loop bounds itself at the true remaining
 event count per chunk instead of masking dead steps.
 
@@ -66,7 +68,11 @@ VMEM_CAPACITY = {"TPU v5 lite": 128 * 2**20}
 #: loop-carried state (a value copy of the scratch and outputs) and the
 #: step's temporaries in VMEM. Compiled for a described v5e at the
 #: paper-fig5, hot-key-storm and open-loop-ramp shapes (tile 8), the
-#: kernel needed between 2.5x and 2.75x the priced bytes
+#: kernel needed between 2.5x and 2.75x the priced bytes while it carried
+#: the latency ring. With the ring outside it, compiled at tiles the
+#: planner derives (fig5 8, 24, 72 and 128 rows; fig4 56 and 128; K=1000
+#: 72 and 128; open loop 48; storm 16), every one fit a limit of 1.5x
+#: its priced bytes, and seven of the ten failed at 1.25x
 MOSAIC_FACTOR = 3
 
 
@@ -210,10 +216,24 @@ class VmemPlan:
     budget: int | None                       # bytes; None = unconstrained
     total_bytes: int
     breakdown: Mapping[str, tuple]           # name -> (shape, bytes)
+    rows: int | None = None                  # the call's replicas B
 
     @property
     def shrunk(self) -> bool:
         return self.tile != self.requested_tile
+
+    @property
+    def tiles(self) -> int | None:
+        """The grid's replica tiles for ``rows``: one latency chain each
+        per event, run one after another."""
+        return None if self.rows is None else -(-self.rows // self.tile)
+
+    @property
+    def padded_rows(self) -> int | None:
+        """The edge copies that fill the last tile past ``rows``; the
+        kernel runs them and the caller slices them off."""
+        return (None if self.rows is None
+                else self.tiles * self.tile - self.rows)
 
     def as_dict(self) -> dict:
         """Compact form for ``exec_stats()`` / benchmark JSON rows."""
@@ -222,6 +242,8 @@ class VmemPlan:
             "ev_chunk": self.ev_chunk, "lat_samples": self.lat_samples,
             "representation": self.representation, "budget": self.budget,
             "total_bytes": self.total_bytes, "shrunk": self.shrunk,
+            "rows": self.rows, "tiles": self.tiles,
+            "padded_rows": self.padded_rows,
         }
 
 

@@ -69,9 +69,11 @@ def inside(inner, outer) -> bool:
     return outer[1] <= inner[1] and inner[2] <= outer[2]
 
 
-def check_call(spans, n_configs, n_seeds, n_events, bucket_rows):
+def check_call(spans, n_configs, n_seeds, n_events, bucket_rows,
+               devices=1):
     """One call's spans: their nesting, order and metadata. Returns the
-    call's ordinal and its dispatch spans."""
+    call's ordinal and its dispatch spans. ``devices``: the chips each
+    dispatch is sharded over, one kernel call each."""
     (sw,) = [s for s in spans if s[0] == batch.SPAN_SWEEP]
     sid = sw[3]["sweep"]
     assert sw[3] == {"sweep": sid, "configs": n_configs, "seeds": n_seeds,
@@ -99,10 +101,15 @@ def check_call(spans, n_configs, n_seeds, n_events, bucket_rows):
         for d in mine[1:-2]:
             assert set(d[3]) == {"sweep", "bucket", "rows", "bytes",
                                  "new_shape", "lock_lanes", "draw_streams",
-                                 "zipf_ranks"}
+                                 "zipf_ranks", "tile", "tiles",
+                                 "padded_rows"}
             # every bucket here: 2 nodes, 4 locks, exclusive machines
             assert (d[3]["lock_lanes"], d[3]["draw_streams"],
                     d[3]["zipf_ranks"]) == (4, 3, 2)
+            # a kernel call's rows fit one derived tile: one chain, no
+            # padding
+            assert (d[3]["tile"], d[3]["tiles"], d[3]["padded_rows"]) == (
+                d[3]["rows"] // devices, 1, 0)
             # every operand leaf has the dispatch's rows as leading axis
             assert d[3]["bytes"] > 0 and d[3]["bytes"] % d[3]["rows"] == 0
             assert d[3]["new_shape"] in (0, 1)
@@ -152,7 +159,7 @@ plain = batch.sweep(ws, 8, 300, backend="pallas", devices=jax.devices(),
                     chunk=2)
 (res,), spans = t.traced_sweeps([(ws, 8, 300)], backend="pallas",
                                 devices=jax.devices(), chunk=2)
-sid, dispatches = t.check_call(spans, 3, 8, 300, [24])
+sid, dispatches = t.check_call(spans, 3, 8, 300, [24], devices=4)
 print(json.dumps({{"devices": len(jax.devices()),
                   "same": t.same_results(plain, res),
                   "dispatch_rows": [d[3]["rows"] for d in dispatches[0]]}}))

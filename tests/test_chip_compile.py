@@ -57,7 +57,7 @@ def _bucket(workloads, n_seeds):
     return key, wl
 
 
-def _compile(sharding, workloads, n_seeds, *, pairs=False):
+def _compile(sharding, workloads, n_seeds, *, pairs=False, tile=None):
     key, wl = _bucket(workloads, n_seeds)
     alg, T, N, K, n_events, _ = key
     tn, ln, _ = topology(alg, N, T // N, K)
@@ -67,7 +67,7 @@ def _compile(sharding, workloads, n_seeds, *, pairs=False):
         return jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype,
                                     sharding=sharding)
 
-    kw = dict(interpret=False, device_kind=kind)
+    kw = dict(interpret=False, device_kind=kind, tile=tile)
     if pairs:
         # the zero-int64 entry point, x64 off
         def fn(w, a, b):
@@ -83,17 +83,42 @@ def _compile(sharding, workloads, n_seeds, *, pairs=False):
             spec(ln)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return ops.plan_for_run(wl.seed.shape[0], wl.edges.shape[1], n_events,
-                            T, N, K, R=key[5], interpret=False,
+                            T, N, K, R=key[5], tile=tile, interpret=False,
                             device_kind=kind,
                             hl=alg == "hlock", rw=alg == "alock-rw")
 
 
 @pytest.mark.parametrize("alg", ["alock", "mcs", "spinlock"])
 def test_fig5_bucket_compiles(one_chip, alg):
-    """The paper-fig5 shape (T=80, N=10, K=100) at 72 replicas."""
+    """The paper-fig5 shape (T=80, N=10, K=100) at 72 replicas: one
+    derived tile of all 72 rows, within the budget unshrunk."""
     ws = [w for w in fig5_workloads() if w.alg == alg]
     plan = _compile(one_chip, ws, n_seeds=24)
-    assert (plan.tile, plan.representation) == (8, "i32pair")
+    assert (plan.tile, plan.representation) == (72, "i32pair")
+    assert not plan.shrunk
+
+
+def test_fig4_bucket_compiles(one_chip):
+    """The Fig. 4 budget study (T=240, N=20, K=100): 14 budget/locality
+    points x 4 seeds = 56 replicas in one derived tile."""
+    points = ([(loc, b) for loc in (0.95, 0.9, 0.85)
+               for b in ((5, 5), (5, 10), (5, 20))]
+              + [(0.9, b) for b in ((1, 1), (2, 2), (2, 8), (2, 20),
+                                    (20, 5))])
+    ws = [Workload("alock", 20, 12, 100, locality=loc, b_init=b,
+                   seed=1 + 100 * i) for i, (loc, b) in enumerate(points)]
+    plan = _compile(one_chip, ws, n_seeds=4)
+    assert (plan.tile, plan.representation) == (56, "i32pair")
+    assert not plan.shrunk
+
+
+def test_sharded_16_row_shard_compiles(one_chip):
+    """What each chip's kernel runs in the larger superchunk of the
+    4-chip fig5 sweep: 16 rows of one bucket, one tile of 16."""
+    ws = [w for w in fig5_workloads() if w.alg == "alock"][:2]
+    plan = _compile(one_chip, ws, n_seeds=8)
+    assert (plan.tile, plan.representation) == (16, "i32pair")
+    assert not plan.shrunk
 
 
 def test_multi_phase_storm_compiles(one_chip):
@@ -128,14 +153,26 @@ def test_ycsb_b_bucket_compiles(one_chip):
                    read_frac=0.95, b_init=(5, 20), seed=1 + 100 * i)
           for i, loc in enumerate((0.85, 0.95, 1.0))]
     plan = _compile(one_chip, ws, n_seeds=24)
-    assert (plan.tile, plan.representation) == (8, "i32pair")
+    assert (plan.tile, plan.representation) == (72, "i32pair")
     assert not plan.shrunk and plan.total_bytes <= plan.budget
 
 
 def test_ragged_batch_compiles(one_chip):
-    """B=9: the tile stays a multiple of 8 and the rows pad to 16."""
-    plan = _compile(one_chip, fig5_workloads()[:1], n_seeds=9)
-    assert plan.tile == 8
+    """B=9 at a tile of 8: the tile stays a multiple of 8 and the rows
+    pad to 16, a grid of two tiles."""
+    plan = _compile(one_chip, fig5_workloads()[:1], n_seeds=9, tile=8)
+    assert (plan.tile, plan.tiles, plan.padded_rows) == (8, 2, 7)
+
+
+@pytest.mark.parametrize("B, grid", [(9, (9, 1, 0)), (130, (72, 2, 14))])
+def test_derived_grid_compiles(one_chip, B, grid):
+    """The derived tile, unshrunk: B=9 is one tile of the whole batch,
+    Mosaic's other legal block besides a multiple of 8, so no row pads;
+    B=130, past ``ops.MAX_TILE``, is two tiles of 72, 14 rows of them
+    edge copies."""
+    plan = _compile(one_chip, fig5_workloads()[:1], n_seeds=B)
+    assert (plan.tile, plan.tiles, plan.padded_rows) == grid
+    assert not plan.shrunk
 
 
 def test_pairs_path_with_x64_off_compiles(one_chip):

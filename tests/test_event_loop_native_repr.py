@@ -367,6 +367,46 @@ def test_streamed_ring_wraparound_bitwise(alg, representation):
         assert (ref[1][1] == -1).all()      # the idle replica's ring
 
 
+#: XLA oracle outputs by algorithm, shared by both representations'
+#: cases of the derived-tile test
+_TILE_REFS: dict = {}
+
+
+def _ragged_bucket(alg):
+    """B=15 replicas of one bucket on 2 nodes x 2 threads, 4 locks:
+    distinct seeds and localities; hlock on one rack (three tiers),
+    alock-rw with a read mix."""
+    topo = (0, 0) if alg == "hlock" else None
+    rf = 0.6 if alg == "alock-rw" else 0.0
+    _, wl = _stack_operands([Workload(alg, 2, 2, 4, locality=0.6 + s / 40,
+                                      read_frac=rf, topology=topo,
+                                      seed=31 + s)
+                             for s in range(15)], 400)
+    tn, ln, _ = topology(alg, 2, 2, 4)
+    return wl, tn, ln
+
+
+@pytest.mark.parametrize("representation", ["i64", "i32pair"])
+@pytest.mark.parametrize("alg", ["alock", "mcs", "spinlock", "alock-rw",
+                                 "hlock"])
+def test_derived_tile_bitwise(alg, representation):
+    """The derived tile (all 15 ragged rows in one tile) and a tile of 8
+    (two tiles, the second padded by an edge copy) give outputs equal to
+    each other and to the XLA engine, bit for bit: replica rows are
+    independent whatever tile they share."""
+    wl, tn, ln = _ragged_bucket(alg)
+    if alg not in _TILE_REFS:
+        with jax.enable_x64(True):
+            _TILE_REFS[alg] = [np.asarray(r) for r in run_events_ref(
+                alg, 4, 2, 4, 400, wl, tn, ln)]
+    for tile in (None, 8):
+        with jax.enable_x64(True):
+            got = [np.asarray(r) for r in run_events(
+                alg, 4, 2, 4, 400, wl, tn, ln, interpret=True,
+                representation=representation, tile=tile, ev_chunk=256)]
+        _assert_bitwise(_TILE_REFS[alg], got)
+
+
 def test_packed_run_events_i32pair_matches_i64():
     """The public ``run_events(representation=)`` contract: both
     representations return identical int64 outputs under x64."""

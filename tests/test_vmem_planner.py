@@ -308,6 +308,61 @@ def test_native_plan_respects_mosaic_block_rules():
         native(64, 64, tile=64, vmem_budget=at8 - 1)
 
 
+#: the cells' bucket shapes: (T, N, K, rw) of fig5, fig4 and ycsb-b
+CELL_SHAPES = {"fig5": (80, 10, 100, False), "fig4": (240, 20, 100, False),
+               "ycsb-b": (80, 10, 1000, True)}
+
+
+def _v5e_plan(B, shape, **kw):
+    """The native plan of a B-row bucket of a cell's shape at the v5e
+    budget, over the cells' 150,000 events."""
+    from repro.kernels.event_loop.ops import plan_for_run
+    T, N, K, rw = CELL_SHAPES[shape]
+    return plan_for_run(B, 1, 150_000, T, N, K, rw=rw, interpret=False,
+                        device_kind="TPU v5 lite", **kw)
+
+
+@pytest.mark.parametrize("shape", sorted(CELL_SHAPES))
+@pytest.mark.parametrize("B, tile, tiles", [
+    (8, 8, 1), (9, 9, 1), (16, 16, 1), (24, 24, 1), (56, 56, 1),
+    (72, 72, 1),
+    # past MAX_TILE: the fewest tiles, split evenly, rounded up to 8
+    (130, 72, 2), (300, 104, 3)])
+def test_derived_tile_fits_the_bucket(shape, B, tile, tiles):
+    """With no ``tile=``, a bucket of up to ``MAX_TILE`` rows is one
+    whole tile (no padding, whatever its rows: 9 is the whole batch);
+    past it, the fewest tiles. The rule reads the rows alone, and every
+    cell's tile fits the v5e budget unshrunk."""
+    from repro.kernels.event_loop.ops import MAX_TILE
+    plan = _v5e_plan(B, shape)
+    assert (plan.rows, plan.tile, plan.tiles) == (B, tile, tiles)
+    assert plan.padded_rows == tiles * tile - B
+    assert not plan.shrunk and plan.total_bytes <= plan.budget
+    assert (plan.tile == B) == (B <= MAX_TILE)
+
+
+def test_explicit_tile_overrides_the_derived_one():
+    """``tile=`` is the ceiling instead of ``MAX_TILE``: 72 rows at tile 8
+    run nine tiles of 8, 24 rows at tile 16 two of 16 (8 padded)."""
+    assert _v5e_plan(72, "fig5", tile=8).tile == 8
+    assert _v5e_plan(24, "fig5", tile=16).tile == 16
+    assert _v5e_plan(24, "fig5").tile == 24
+
+
+def test_vmem_halving_composes_with_the_derived_tile():
+    """A budget that cannot hold the derived tile of 72 halves the
+    ceiling to 32 (a multiple of 8), and the 72 rows split evenly under
+    it: three tiles of 24, none padded. Interpret mode derives the whole
+    batch, unaligned, and never shrinks."""
+    from repro.kernels.event_loop.ops import plan_for_run
+    at32 = _v5e_plan(32, "ycsb-b").total_bytes
+    p = _v5e_plan(72, "ycsb-b", vmem_budget=at32)
+    assert (p.requested_tile, p.tile, p.shrunk) == (72, 24, True)
+    assert (p.rows, p.tiles, p.padded_rows) == (72, 3, 0)
+    assert p.total_bytes <= at32
+    assert plan_for_run(200, 1, 64, 12, 3, 6, interpret=True).tile == 100
+
+
 def test_vmem_budget_is_keyed_by_device_kind():
     """The native budget comes from one table keyed by device_kind: the
     v5e entry, and an error (not a default) for an unknown device."""
