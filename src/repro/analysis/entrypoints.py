@@ -12,22 +12,20 @@ nothing dispatches, no TPU required:
   ============== ==========================================================
   xla-batch      ``batch._run_events_batch`` (the vmapped XLA oracle),
                  under x64 — int64 clocks are that path's contract
-  pallas-i64     ``ops.run_events`` with ``representation="i64"`` in
-                 interpret mode (the CPU fast path), under x64
-  pallas-native  ``ops.run_events`` with ``representation="i32pair"`` and
-                 ``interpret=False`` — the kernel exactly as Mosaic would
-                 receive it (tracing never invokes Mosaic)
+  pallas-native  ``ops.run_events`` with ``interpret=False``, under x64 —
+                 the kernel exactly as Mosaic would receive it (tracing
+                 never invokes Mosaic)
   pallas-pairs   ``ops.run_events_pairs`` with x64 **disabled** — the
                  zero-int64 contract the x64-off CI leg runs
   ============== ==========================================================
 
-``pallas-native`` and ``pallas-pairs`` entrypoints carry ``repr32=True``
-(the Mosaic-lowerability family applies) and their ``meta`` records the
+The Mosaic-lowerability family applies to the Pallas kinds
+(``Entrypoint.is_pallas``), and their ``meta`` records the
 VMEM plan + static dims the vmem-consistency rule diffs the byte table
 against. Tracing runs under an explicit x64 context per row, so the
 catalog is identical whether the host process enables x64 or not.
 
-Open-loop buckets ride in through the same four kinds: a scenario whose
+Open-loop buckets ride in through the same three kinds: a scenario whose
 workloads carry :class:`~repro.workloads.Arrivals` lowers to a shape key
 with ``R > 0`` request slots, so the traced jaxprs include the arrival
 ingestion/dispatch lanes and the per-request outputs, and the rules lint
@@ -37,12 +35,12 @@ the open-loop buffer table through the same ``R``.
 
 >>> eps = trace_entrypoints(scenarios=["node-churn"], n_events=512)
 >>> sorted({ep.kind for ep in eps})
-['pallas-i64', 'pallas-native', 'pallas-pairs', 'xla-batch']
+['pallas-native', 'pallas-pairs', 'xla-batch']
 >>> pairs = [ep for ep in eps if ep.kind == "pallas-pairs"]
->>> pairs[0].x64_off and pairs[0].repr32
+>>> pairs[0].x64_off and pairs[0].is_pallas
 True
->>> pairs[0].meta["plan"].representation
-'i32pair'
+>>> pairs[0].meta["plan"].budget is not None
+True
 >>> ramp = trace_entrypoints(scenarios=["burst-storm"], n_events=512,
 ...                          kinds=["xla-batch"])
 >>> all(ep.meta["open_loop"] and ep.meta["dims"]["R"] > 0 for ep in ramp)
@@ -71,11 +69,15 @@ LINT_DEVICE_KIND = "TPU v5 lite"
 class Entrypoint:
     """One traced engine entrypoint: a closed jaxpr + rule context."""
     name: str            # e.g. "pallas-pairs:('alock', 16, 4, 16, 2048, 0)"
-    kind: str            # xla-batch | pallas-i64 | pallas-native | ...
+    kind: str            # xla-batch | pallas-native | pallas-pairs
     jaxpr: Any           # jax.core.ClosedJaxpr
-    repr32: bool         # Mosaic-lowerability rules apply
     x64_off: bool        # x64-cleanliness rule applies
     meta: dict = field(default_factory=dict, compare=False)
+
+    @property
+    def is_pallas(self) -> bool:
+        """A Pallas kernel entrypoint: Mosaic-lowerability rules apply."""
+        return self.kind.startswith("pallas-")
 
 
 def collect_buckets(scenarios: Iterable[str] | None = None,
@@ -125,7 +127,7 @@ def trace_entrypoints(scenarios: Iterable[str] | None = None,
     """Build the full traced-entrypoint catalog for the rule engine.
 
     One entrypoint per (bucket, kind); ``kinds`` filters (default: all
-    four). Tracing is abstract evaluation only — no executable is built,
+    three). Tracing is abstract evaluation only — no executable is built,
     no kernel runs, and the process-wide x64 flag is saved/restored.
     """
     import jax
@@ -135,7 +137,7 @@ def trace_entrypoints(scenarios: Iterable[str] | None = None,
     from repro.workloads import WorkloadOperands
 
     want = set(kinds) if kinds is not None else {
-        "xla-batch", "pallas-i64", "pallas-native", "pallas-pairs"}
+        "xla-batch", "pallas-native", "pallas-pairs"}
     eps: list[Entrypoint] = []
     for key, (wl, bmeta) in collect_buckets(scenarios, n_events).items():
         alg, T, N, K, ne, R = key
@@ -159,14 +161,7 @@ def trace_entrypoints(scenarios: Iterable[str] | None = None,
                 jx = _trace(functools.partial(
                     _run_events_batch, alg, T, N, K, ne), wlj, tn, ln)
             eps.append(Entrypoint(f"xla-batch:{key}", "xla-batch", jx,
-                                  repr32=False, x64_off=False, meta=meta))
-        if "pallas-i64" in want:
-            with jax.enable_x64(True):
-                jx = _trace(functools.partial(
-                    el_ops.run_events, alg, T, N, K, ne, interpret=True,
-                    representation="i64"), wlj, tn, ln)
-            eps.append(Entrypoint(f"pallas-i64:{key}", "pallas-i64", jx,
-                                  repr32=False, x64_off=False, meta=meta))
+                                  x64_off=False, meta=meta))
         # the native rows re-plan exactly like run_events will (single
         # clamping+planning code path), so the vmem rule diffs the same
         # (tile, ev_chunk) the traced pallas_call actually bound
@@ -174,22 +169,19 @@ def trace_entrypoints(scenarios: Iterable[str] | None = None,
             plan = el_ops.plan_for_run(B, P, ne, T, N, K, R=R,
                                        hl=dims["hl"], rw=dims["rw"],
                                        interpret=False,
-                                       representation="i32pair",
                                        device_kind=LINT_DEVICE_KIND)
             with jax.enable_x64(True):
                 jx = _trace(functools.partial(
                     el_ops.run_events, alg, T, N, K, ne, interpret=False,
-                    representation="i32pair",
                     device_kind=LINT_DEVICE_KIND),
                     wlj, tn, ln)
             eps.append(Entrypoint(f"pallas-native:{key}", "pallas-native",
-                                  jx, repr32=True, x64_off=False,
+                                  jx, x64_off=False,
                                   meta=dict(meta, plan=plan)))
         if "pallas-pairs" in want:
             plan = el_ops.plan_for_run(B, P, ne, T, N, K, R=R,
                                        hl=dims["hl"], rw=dims["rw"],
                                        interpret=False,
-                                       representation="i32pair",
                                        device_kind=LINT_DEVICE_KIND)
             with jax.enable_x64(False):
                 jx = _trace(functools.partial(
@@ -197,7 +189,7 @@ def trace_entrypoints(scenarios: Iterable[str] | None = None,
                     interpret=False, device_kind=LINT_DEVICE_KIND),
                     wlj, tn, ln)
             eps.append(Entrypoint(f"pallas-pairs:{key}", "pallas-pairs",
-                                  jx, repr32=True, x64_off=True,
+                                  jx, x64_off=True,
                                   meta=dict(meta, plan=plan,
                                             lat_samples=LAT_SAMPLES)))
     return eps

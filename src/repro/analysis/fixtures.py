@@ -21,9 +21,6 @@ Offenders:
     declared x64-off (X001);
   * ``weak_offender`` — a python scalar fed straight into a trace, leaving
     a weak_type operand aval (R001);
-  * ``lazy_resolver`` — a ``resolve_representation`` look-alike that
-    ignores ``REPRO_EVENT_CLOCKS`` (R002: the env no longer keys the jit
-    cache);
   * ``bucket_offender`` — one sweep bucket holding two different abstract
     signatures (R003: a silent recompile per sweep);
   * ``corrupt_buffer_table`` — a VMEM byte table whose ``scr.victim`` row
@@ -51,13 +48,11 @@ import numpy as np
 
 from repro.analysis.entrypoints import Entrypoint
 from repro.analysis.rules import (RULES, _stamp, check_bucket_signatures,
-                                  check_env_resolution,
                                   check_vmem_consistency, run_rules)
 
 __all__ = ["run_corpus", "mosaic_offender", "rack_offender",
-           "x64_offender", "weak_offender", "lazy_resolver",
-           "bucket_offender", "corrupt_buffer_table",
-           "corrupt_open_buffer_table"]
+           "x64_offender", "weak_offender", "bucket_offender",
+           "corrupt_buffer_table", "corrupt_open_buffer_table"]
 
 
 def mosaic_offender() -> Entrypoint:
@@ -84,7 +79,7 @@ def mosaic_offender() -> Entrypoint:
     with jax.enable_x64(True):
         jx = jax.make_jaxpr(call)(np.zeros((8, 8), np.int32))
     return Entrypoint("corpus:mosaic-offender", "pallas-native", jx,
-                      repr32=True, x64_off=False)
+                      x64_off=False)
 
 
 def rack_offender() -> Entrypoint:
@@ -113,7 +108,7 @@ def rack_offender() -> Entrypoint:
     with jax.enable_x64(True):
         jx = jax.make_jaxpr(call)(np.zeros((1, 8), np.int32))
     return Entrypoint("corpus:rack-offender", "pallas-native", jx,
-                      repr32=True, x64_off=False)
+                      x64_off=False)
 
 
 def x64_offender() -> Entrypoint:
@@ -128,7 +123,7 @@ def x64_offender() -> Entrypoint:
     with jax.enable_x64(True):
         jx = jax.make_jaxpr(leaky)(np.zeros(4, np.int32))
     return Entrypoint("corpus:x64-offender", "pallas-pairs", jx,
-                      repr32=False, x64_off=True)
+                      x64_off=True)
 
 
 def weak_offender() -> Entrypoint:
@@ -136,14 +131,7 @@ def weak_offender() -> Entrypoint:
     import jax
     jx = jax.make_jaxpr(lambda x: x * 2)(1.0)     # python float, not array
     return Entrypoint("corpus:weak-offender", "xla-batch", jx,
-                      repr32=False, x64_off=False)
-
-
-def lazy_resolver(representation: str, interpret: bool) -> str:
-    """Ignores ``REPRO_EVENT_CLOCKS`` — the bug ``run_events_jit`` exists
-    to prevent: the env read happens at trace time only, so a cached
-    executable of the other representation would be silently reused."""
-    return "i64" if interpret else "i32pair"
+                      x64_off=False)
 
 
 def bucket_offender() -> dict:
@@ -211,7 +199,6 @@ def run_corpus() -> dict:
         rules=["M001", "M002", "M003"])
     out["x64-cleanliness"] = run_rules([x64_offender()], rules=["X001"])
     retrace = run_rules([weak_offender()], rules=["R001"])
-    retrace += _stamp(RULES["R002"], check_env_resolution(lazy_resolver))
     retrace += _stamp(RULES["R003"], check_bucket_signatures(
         lowered_by_bucket=bucket_offender()))
     out["retrace-hazards"] = retrace

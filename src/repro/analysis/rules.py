@@ -14,14 +14,13 @@ Shipped families (rule ids are stable — baselines and CI grep them):
   ============ ======== ====================================================
   family       rules    catches
   ============ ======== ====================================================
-  mosaic-      M001     64-bit avals inside a native-representation kernel
+  mosaic-      M001     64-bit avals inside a Pallas kernel
   lowerability M002     dynamic scatter/gather inside the kernel
                M003     1-D iota inside the kernel (Mosaic requires >= 2D)
   x64-         X001     any 64-bit aval in the x64-off pairs path
   cleanliness
   retrace-     R001     weak_type leaking into traced entrypoint operands
-  hazards      R002     env-keyed static args resolved lazily (jit cache)
-               R003     >1 abstract signature per sweep bucket (recompiles)
+  hazards      R003     >1 abstract signature per sweep bucket (recompiles)
   vmem-        V001     ``vmem.py`` byte-table drift vs the kernel's actual
   consistency           pallas_call buffers
   ============ ======== ====================================================
@@ -43,7 +42,6 @@ returns the combined findings (empty list == lint-clean).
 """
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass, field, replace
 from typing import Callable, Iterable
 
@@ -52,7 +50,6 @@ import numpy as np
 from repro.analysis.walk import all_avals, walk_jaxpr
 
 __all__ = ["Finding", "Rule", "RULES", "rule", "run_rules",
-           "check_env_resolution", "check_runner_cache_keys",
            "check_bucket_signatures", "check_vmem_consistency",
            "bucket_signature"]
 
@@ -167,15 +164,15 @@ def _f(ep_name, where, message, hint="") -> Finding:
 
 
 # ---------------------------------------------------------------------------
-# mosaic-lowerability: applies to entrypoints targeting the native TPU
-# kernel (repr32 — Mosaic has no 64-bit vector registers, rejects dynamic
-# scatters against VMEM state, and requires >= 2D iota)
+# mosaic-lowerability: applies to Pallas kernel entrypoints (Mosaic has no
+# 64-bit vector registers, rejects dynamic scatters against VMEM state,
+# and requires >= 2D iota)
 
 
 @rule("M001", family="mosaic-lowerability",
-      summary="64-bit aval inside the native-representation kernel")
+      summary="64-bit aval inside the Pallas kernel")
 def _kernel_wide_dtype(ep):
-    if not ep.repr32:
+    if not ep.is_pallas:
         return []
     out, seen = [], set()
     for site in walk_jaxpr(ep.jaxpr):
@@ -199,7 +196,7 @@ def _kernel_wide_dtype(ep):
 @rule("M002", family="mosaic-lowerability",
       summary="dynamic scatter/gather inside the kernel")
 def _kernel_dynamic_scatter(ep):
-    if not ep.repr32:
+    if not ep.is_pallas:
         return []
     out = []
     for site in walk_jaxpr(ep.jaxpr):
@@ -218,7 +215,7 @@ def _kernel_dynamic_scatter(ep):
 @rule("M003", family="mosaic-lowerability",
       summary="1-D iota inside the kernel")
 def _kernel_1d_iota(ep):
-    if not ep.repr32:
+    if not ep.is_pallas:
         return []
     out = []
     for site in walk_jaxpr(ep.jaxpr):
@@ -284,82 +281,6 @@ def _weak_operands(ep):
                 f"captured constant {i} has a weak_type aval — pin its "
                 f"dtype", ""))
     return out
-
-
-def check_env_resolution(resolver=None) -> list[Finding]:
-    """R002 core: ``REPRO_EVENT_CLOCKS`` must be resolved *eagerly* so it
-    participates in jit cache keys. Flips the env var through both values
-    and asserts the resolver actually follows it (a lazy resolver — one
-    that reads the env only at trace time — returns a stale value here
-    and would silently reuse a cached executable of the other
-    representation). Pure python, no tracing.
-    """
-    if resolver is None:
-        from repro.kernels.event_loop.ops import resolve_representation
-        resolver = resolve_representation
-    findings = []
-    old = os.environ.get("REPRO_EVENT_CLOCKS")
-    try:
-        for interpret in (False, True):
-            for env in ("i64", "i32pair"):
-                os.environ["REPRO_EVENT_CLOCKS"] = env
-                got = resolver("auto", interpret)
-                if got != env:
-                    findings.append(_f(
-                        "<global>",
-                        f"resolver(auto, interpret={interpret})",
-                        f"REPRO_EVENT_CLOCKS={env!r} resolved to {got!r} "
-                        f"— the env override is not applied eagerly, so "
-                        f"it cannot key the jit cache",
-                        "resolve env/static args before the jit boundary "
-                        "(ops.run_events_jit pattern)"))
-    finally:
-        if old is None:
-            os.environ.pop("REPRO_EVENT_CLOCKS", None)
-        else:
-            os.environ["REPRO_EVENT_CLOCKS"] = old
-    return findings
-
-
-def check_runner_cache_keys() -> list[Finding]:
-    """R002, second leg: the *sharded* bucket-runner cache
-    (``repro.core.batch._bucket_runner``) must key on the resolved
-    representation — two different ``REPRO_EVENT_CLOCKS`` settings must
-    yield two different cache keys. Builds the runners (python-level;
-    nothing is traced or dispatched) and compares the keys."""
-    import jax
-    from jax.sharding import Mesh
-    from repro.core import batch
-    findings = []
-    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
-    key = ("alock", 4, 2, 8, 64, 0)
-    old = os.environ.get("REPRO_EVENT_CLOCKS")
-    try:
-        cks = {}
-        for env in ("i64", "i32pair"):
-            os.environ["REPRO_EVENT_CLOCKS"] = env
-            _, cks[env] = batch._bucket_runner(key, 1, "pallas", mesh)
-        if cks["i64"] == cks["i32pair"]:
-            findings.append(_f(
-                "<global>", "batch._bucket_runner",
-                "the sharded bucket-runner cache key is identical under "
-                "REPRO_EVENT_CLOCKS=i64 and =i32pair — a mid-process env "
-                "flip would silently reuse a trace of the other "
-                "representation",
-                "include resolve_representation(...) in the runner cache "
-                "key"))
-    finally:
-        if old is None:
-            os.environ.pop("REPRO_EVENT_CLOCKS", None)
-        else:
-            os.environ["REPRO_EVENT_CLOCKS"] = old
-    return findings
-
-
-@rule("R002", family="retrace-hazards", scope="global",
-      summary="env-keyed static args must resolve eagerly (jit cache)")
-def _lazy_env(_eps):
-    return check_env_resolution() + check_runner_cache_keys()
 
 
 def bucket_signature(operands) -> tuple:
@@ -442,8 +363,7 @@ def check_vmem_consistency(ep, table_fn=None) -> list[Finding]:
         return []
     findings = []
     dims = ep.meta["dims"]            # {T, N, K, P, R}
-    table = table_fn(tile=plan.tile, ev_chunk=plan.ev_chunk,
-                     repr32=ep.repr32, **dims)
+    table = table_fn(tile=plan.tile, ev_chunk=plan.ev_chunk, **dims)
     streamed = vmem.STREAMED_INPUTS + vmem.STREAMED_OUTPUTS
     expected = []
     for name, (shape, nbytes) in table.items():

@@ -203,16 +203,7 @@ def _bucket_runner(key, n_phases: int, backend: str, mesh: Mesh):
     donated.
     """
     alg, T, N, K, n_events, R = key
-    rep = None
-    if backend == "pallas":
-        # the clock representation is env-overridable (REPRO_EVENT_CLOCKS)
-        # and must key the cached runner, or a mid-process flip would
-        # silently reuse a trace of the other representation
-        from repro.kernels.event_loop.ops import (default_interpret,
-                                                  resolve_representation)
-        rep = resolve_representation("auto", default_interpret())
-    ck = (key, n_phases, backend, rep,
-          tuple(d.id for d in mesh.devices.flat))
+    ck = (key, n_phases, backend, tuple(d.id for d in mesh.devices.flat))
     if ck in _RUNNER_CACHE:
         return _RUNNER_CACHE[ck], ck
     n_fields = len(WorkloadOperands._fields)

@@ -444,8 +444,8 @@ def _scale_cost(c, m):
     Round-to-nearest in float32: cost rows are < 2^24 ns so the f32
     product is exact, which makes ``m == 1.0`` bitwise inert (the
     healthy-cluster path reproduces the pre-fault engine exactly) and
-    keeps the scaled delta i32 — both clock representations of the
-    Pallas kernel consume it unchanged. The kernel mirrors this formula
+    keeps the scaled delta i32 — the Pallas kernel's clock pairs consume
+    it unchanged. The kernel mirrors this formula
     verbatim; any change here must be mirrored there.
     """
     return jnp.round(jnp.asarray(c, jnp.float32) * m).astype(I32)

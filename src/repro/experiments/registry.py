@@ -42,7 +42,7 @@ class Scenario:
     #: for simulator scenarios: a zero-arg callable returning the exact
     #: ``Workload`` specs the scenario sweeps — the differential test
     #: harness (``tests/test_event_loop_native_repr.py``) replays every
-    #: one through the native-representation kernel and diffs it bitwise
+    #: one through the Pallas kernel's clock pairs and diffs it bitwise
     #: against the XLA engine. None for non-simulator scenarios
     #: (coord-stress drives the threaded coordination plane instead).
     workloads: Callable | None = None

@@ -6,7 +6,7 @@ HBM-resident state. This kernel keeps ALL per-replica state — the semantic
 ``Sem`` machine (tails/victim/word, per-thread descriptors) and the
 ``ready``/``busy``/``op_start`` clocks — resident in VMEM for the entire
 ``n_events`` run: one HBM read and one write per replica, replicas
-tiled across the first grid axis exactly like ``kernels/alock_tick``.
+tiled across the first grid axis.
 
 Layout
   grid = (replica_tiles, event_chunks); the second axis is the innermost
@@ -18,32 +18,29 @@ Layout
 
 Branch dispatch
   ``sim.sem_step``'s ``lax.switch`` over 14 PC branches is re-expressed as
-  masked ``jnp.select`` over the PC classes (the ``alock_tick`` pattern):
-  per event each replica row computes every branch's update and keeps the
-  one selected by its thread's PC. Scatters at per-row indices (lock k,
+  masked ``jnp.select`` over the PC classes: per event each replica row
+  computes every branch's update and keeps the one selected by its
+  thread's PC. Scatters at per-row indices (lock k,
   thread tid/pred/succ, node) are one-hot masked writes.
 
 Clock representation
   Every 64-bit quantity — the ``ready``/``busy``/``op_start`` clocks, the
-  per-event latencies and the parked-thread ``never`` sentinel — goes
-  through one of two interchangeable representations selected by the
-  static ``repr32`` flag (``ops.py`` resolves it):
-
-  * ``_I64Clocks`` — plain int64 arrays. The fast path for interpret mode
-    and XLA-adjacent hosts; callers hold ``jax.enable_x64(True)``.
-  * ``_PairClocks`` — hi/lo int32 pairs with carry-correct add/sub and
-    lexicographic compare/argmin (``i32pair.py``). Mosaic has no 64-bit
-    vector registers, so this is the *native-TPU* representation; it also
-    runs with x64 entirely off. Bitwise-equal to the i64 path (the
-    ``tests/test_event_loop_native_repr.py`` differential suite).
+  per-event latencies and the parked-thread ``never`` sentinel — is a
+  hi/lo int32 pair with carry-correct add/sub and lexicographic
+  compare/argmin (``i32pair.py``), held in an (hi, lo) pair of refs.
+  Mosaic has no 64-bit vector registers, so this is the one
+  representation that lowers natively; it runs with x64 entirely off,
+  and interpret mode traces the same kernel program as the chip. The
+  ``tests/test_event_loop_native_repr.py`` differential suite holds it
+  bitwise-equal to the int64 XLA engine.
 
   The kernel holds no latency ring. Every event writes its latency, or
   -1 when it completes nothing, into lane ``e`` of a ``(tile, ev_chunk)``
   output block streamed along the event axis like the draws, through
   the same aligned 128-lane window and one-hot select ``draw()`` reads
   with; ``ops.py`` builds the ``(B, lat_samples)`` ring from that
-  stream outside the kernel. Both representations take this one write
-  path. The ring-overflow tests hold it identical to the XLA engine.
+  stream outside the kernel. The ring-overflow tests hold it identical
+  to the XLA engine.
 
 Randomness + workload operands
   The XLA loop draws from ``jax.random.fold_in(key, i)`` per event. The
@@ -66,7 +63,6 @@ Randomness + workload operands
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -80,7 +76,6 @@ from repro.kernels.event_loop import i32pair as p32
 from repro.traffic.metrics import COMPLETED, DROPPED, IN_SERVICE
 
 I32 = jnp.int32
-I64 = jnp.int64
 
 
 def _iota(shape, dim):
@@ -111,126 +106,41 @@ def _select(conds, vals, default):
     return acc
 
 
-class _I64Clocks:
-    """Clock values are plain int64 arrays (interpret / XLA fast path)."""
-    nrefs = 1
-
-    @staticmethod
-    def read(refs, idx=...):
-        return refs[0][idx]
-
-    @staticmethod
-    def write(refs, v, idx=...):
-        refs[0][idx] = v
-
-    @staticmethod
-    def zeros(shape):
-        return jnp.zeros(shape, I64)
-
-    @staticmethod
-    def full_m1(shape):
-        return jnp.full(shape, -1, I64)
-
-    @staticmethod
-    def where(c, a, b):
-        return jnp.where(c, a, b)
-
-    @staticmethod
-    def col(v):
-        return v[:, None]
-
-    @staticmethod
-    def gather(oh, v):
-        """One-hot gather along axis 1; the sum dtype is pinned (under x64
-        ``jnp.sum`` would otherwise widen and poison carry dtypes)."""
-        return jnp.sum(jnp.where(oh, v, 0), axis=1, dtype=v.dtype)
-
-    @staticmethod
-    def add_i32(v, d):
-        return v + d
-
-    @staticmethod
-    def sub(a, b):
-        return a - b
-
-    @staticmethod
-    def max2(a, b):
-        return jnp.maximum(a, b)
-
-    @staticmethod
-    def le(a, b):
-        return a <= b
-
-    @staticmethod
-    def reduce_min_masked(v, mask):
-        return jnp.min(jnp.where(mask, v, jnp.iinfo(jnp.int64).max), axis=1)
-
-    @staticmethod
-    def reduce_max(v):
-        return jnp.max(v, axis=1)
-
-    @staticmethod
-    def argmin_masked(v, mask=None):
-        if mask is not None:
-            v = jnp.where(mask, v, jnp.iinfo(jnp.int64).max)
-        return jnp.argmin(v, axis=1).astype(I32)
-
-    @staticmethod
-    def is_never(v):
-        return v == jnp.iinfo(jnp.int64).max
+# Every 64-bit clock is an (hi, lo) int32 pair (module docstring); these
+# helpers are the pair forms the ``i32pair`` functions do not cover.
 
 
-class _PairClocks:
-    """Clock values are (hi, lo) int32 pairs — the Mosaic-lowerable
-    representation (see ``i32pair.py``); needs no x64 anywhere."""
-    nrefs = 2
+def _read(refs, idx=...):
+    """A clock from its (hi, lo) pair of refs."""
+    return (refs[0][idx], refs[1][idx])
 
-    @staticmethod
-    def read(refs, idx=...):
-        return (refs[0][idx], refs[1][idx])
 
-    @staticmethod
-    def write(refs, v, idx=...):
-        refs[0][idx] = v[0]
-        refs[1][idx] = v[1]
+def _write(refs, v, idx=...):
+    refs[0][idx] = v[0]
+    refs[1][idx] = v[1]
 
-    zeros = staticmethod(p32.pzeros)
 
-    @staticmethod
-    def full_m1(shape):
-        return p32.pfull(shape, -1)
+def _col(v):
+    return (v[0][:, None], v[1][:, None])
 
-    where = staticmethod(p32.pwhere)
 
-    @staticmethod
-    def col(v):
-        return (v[0][:, None], v[1][:, None])
+def _full_m1(shape):
+    return p32.pfull(shape, -1)
 
-    gather = staticmethod(p32.pgather)
-    add_i32 = staticmethod(p32.padd_i32)
-    sub = staticmethod(p32.psub)
-    max2 = staticmethod(p32.pmax2)
-    le = staticmethod(p32.ple)
-    reduce_min_masked = staticmethod(p32.reduce_min_masked)
-    reduce_max = staticmethod(p32.reduce_max)
-    argmin_masked = staticmethod(p32.argmin_masked)
 
-    @staticmethod
-    def is_never(v):
-        return p32.peq(v, p32.NEVER)
+def _is_never(v):
+    return p32.peq(v, p32.NEVER)
 
 
 def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
-                      n_events: int, ev_chunk: int, repr32: bool = False,
-                      R: int = 0):
+                      n_events: int, ev_chunk: int, R: int = 0):
     """One (replica_tile, event_chunk) grid step.
 
     ``refs`` arrive flat from ``pl.pallas_call`` — 12 inputs (plus the
     open-loop arrival rows when ``R > 0``, the read coin/probability rows
     for ``alock-rw`` and the rack row for ``hlock``), then the outputs and
-    scratch whose *count* depends on the clock representation (one ref per
-    clock buffer for i64, an (hi, lo) pair for i32) — and are regrouped
-    here from the static ``repr32`` / ``R`` / alg flags. ``R == 0`` is the
+    scratch, every clock buffer as an (hi, lo) pair of refs — and are
+    regrouped here from the static ``R`` / alg flags. ``R == 0`` is the
     closed loop and parses/traces exactly the pre-traffic program (every
     ``if R > 0`` block below is python-level dead code then); likewise the
     ``is_hl`` / ``is_rw`` blocks are dead for every other algorithm, so
@@ -241,8 +151,6 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
     zero (those PCs are unreachable). alock-rw adds an s_word scratch
     holding per-lock reader counts.
     """
-    C = _PairClocks if repr32 else _I64Clocks
-    nc = C.nrefs
     is_hl = alg == "hlock"
     is_rw = alg == "alock-rw"
     # hlock and alock-rw run the full ALock tail/victim/budget machinery;
@@ -266,32 +174,26 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         rack_ref = refs[pos]                # per-node rack ids
         pos += 1
     if R > 0:
-        arr_refs = refs[pos:pos + nc]
-        tok_ref, tokcum_ref, qcap_ref = refs[pos + nc:pos + nc + 3]
-        pos += nc + 3
+        arr_refs = refs[pos:pos + 2]
+        tok_ref, tokcum_ref, qcap_ref = refs[pos + 2:pos + 5]
+        pos += 5
     rest = refs[pos:]
-    done_ref = rest[0]
-    lat_refs = rest[1:1 + nc]
-    latn_ref = rest[1 + nc]
-    tend_refs = rest[2 + nc:2 + 2 * nc]
-    reacq_ref, npass_ref = rest[2 + 2 * nc:4 + 2 * nc]
-    pos = 4 + 2 * nc
+    done_ref, lat_refs, latn_ref = rest[0], rest[1:3], rest[3]
+    tend_refs, (reacq_ref, npass_ref) = rest[4:6], rest[6:8]
+    pos = 8
     if R > 0:
-        wq_refs = rest[pos:pos + nc]
-        soj_refs = rest[pos + nc:pos + 2 * nc]
-        rstat_ref = rest[pos + 2 * nc]
-        pos += 2 * nc + 1
+        wq_refs, soj_refs, rstat_ref = rest[8:10], rest[10:12], rest[12]
+        pos = 13
     scr = rest[pos:]
     (s_t0, s_t1, s_vic, s_pc, s_bud, s_nxt, s_prev, s_tgt, s_coh) = scr[:9]
     pos = 9
     if is_rw:
         s_word = scr[pos]                   # per-lock reader counts
         pos += 1
-    ready_refs = scr[pos:pos + nc]
-    busy_refs = scr[pos + nc:pos + 2 * nc]
-    opst_refs = scr[pos + 2 * nc:pos + 3 * nc]
+    ready_refs, busy_refs = scr[pos:pos + 2], scr[pos + 2:pos + 4]
+    opst_refs = scr[pos + 4:pos + 6]
     if R > 0:
-        s_curreq, s_arrptr, s_qlen = scr[pos + 3 * nc:pos + 3 * nc + 3]
+        s_curreq, s_arrptr, s_qlen = scr[pos + 6:pos + 9]
 
     is_alock = alg in ("alock", "hlock", "alock-rw")
     is_spin = alg == "spinlock"
@@ -314,15 +216,15 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         s_bud[...] = jnp.full((tile, T), -1, I32)
         for crefs, shape in ((ready_refs, (tile, T)), (busy_refs, (tile, N)),
                              (opst_refs, (tile, T))):
-            C.write(crefs, C.zeros(shape))
+            _write(crefs, p32.pzeros(shape))
         if R > 0:
             s_curreq[...] = jnp.full((tile, T), -1, I32)
-            C.write(wq_refs, C.full_m1((tile, R)))
-            C.write(soj_refs, C.full_m1((tile, R)))
+            _write(wq_refs, _full_m1((tile, R)))
+            _write(soj_refs, _full_m1((tile, R)))
 
     # the latency block streams out once per chunk: -1 (no completion)
     # in every lane an event does not overwrite, the ragged tail included
-    C.write(lat_refs, C.full_m1((tile, ev_chunk)))
+    _write(lat_refs, _full_m1((tile, ev_chunk)))
 
     edges = edges_ref[...].astype(I32)              # (tile, P)
     think = think_ref[...].astype(I32)              # (tile, P)
@@ -343,7 +245,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
     if R > 0:
         # open-loop arrival rows: times (clock), token admit mask +
         # exclusive prefix count, per-request queue bound (all (tile, R))
-        arr = C.read(arr_refs)
+        arr = _read(arr_refs)
         tok = tok_ref[...].astype(I32)
         tokcum = tokcum_ref[...].astype(I32)
         qcap = qcap_ref[...].astype(I32)
@@ -390,8 +292,8 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         """Write clock column ``v`` into event ``e``'s lane of a streamed
         output block, in the rows where ``m`` holds."""
         w, lane = window(e)
-        C.write(refs, C.where(lane & m[:, None], C.col(v), C.read(refs, w)),
-                w)
+        _write(refs, p32.pwhere(lane & m[:, None], _col(v), _read(refs, w)),
+               w)
 
     def phase_row(flat, ph, w):
         """Phase ``ph``'s (tile, w) row of a flat (tile, P*w) block: a
@@ -405,7 +307,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
 
     state = (s_t0[...], s_t1[...], s_vic[...], s_pc[...], s_bud[...],
              s_nxt[...], s_prev[...], s_tgt[...], s_coh[...],
-             C.read(ready_refs), C.read(busy_refs), C.read(opst_refs),
+             _read(ready_refs), _read(busy_refs), _read(opst_refs),
              done_ref[...], latn_ref[...][:, 0],
              reacq_ref[...][:, 0], npass_ref[...][:, 0])
     if R > 0:
@@ -416,7 +318,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
 
         state = state + (rstat_ref[...], s_curreq[...],
                          s_arrptr[...][:, 0], s_qlen[...][:, 0],
-                         C.read(wq_refs), C.read(soj_refs))
+                         _read(wq_refs), _read(soj_refs))
     if is_rw:
         # reader counts ride at the tail of the carry so every existing
         # unpack position stays fixed for the other algorithms
@@ -458,12 +360,13 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             was_act = phase_row(actp, jnp.maximum(ph - _I(1), _I(0)), T)
             rejoin = (_any(gi == edges)[:, None]
                       & (act_row != 0) & (was_act == 0))
-            cont_min = C.reduce_min_masked(ready,
-                                           (act_row != 0) & (was_act != 0))
-            now_min = C.where(C.is_never(cont_min),
-                              C.reduce_min_masked(ready, act_row != 0),
-                              cont_min)
-            ready = C.where(rejoin, C.max2(ready, C.col(now_min)), ready)
+            cont_min = p32.reduce_min_masked(ready, (act_row != 0)
+                                             & (was_act != 0))
+            now_min = p32.pwhere(_is_never(cont_min),
+                                 p32.reduce_min_masked(ready, act_row != 0),
+                                 cont_min)
+            ready = p32.pwhere(rejoin, p32.pmax2(ready, _col(now_min)),
+                               ready)
             actm = act_row != 0
         else:
             # single phase: the flat PR-2 hot path, no phase machinery
@@ -482,16 +385,13 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             # their own clocks (mirror of sim._run_events' elig)
             pend = (pc == mc.NCS) & (curreq < _I(0))
             avail = (rstat == _I(0)) & (tok == _I(1))
-            next_arr = C.reduce_min_masked(arr, avail)
-            elig = C.where(pend, C.max2(ready, C.col(next_arr)), ready)
+            next_arr = p32.reduce_min_masked(arr, avail)
+            elig = p32.pwhere(pend, p32.pmax2(ready, _col(next_arr)), ready)
         else:
             elig = ready
-        if actm is not None:
-            tid = C.argmin_masked(elig, actm)
-        else:
-            tid = C.argmin_masked(elig)
+        tid = p32.argmin_masked(elig, actm)
         ohT = tids == tid[:, None]
-        now = C.gather(ohT, elig)
+        now = p32.pgather(ohT, elig)
         me = tid + 1
         p = gat_t(pc, tid)
         tg = gat_t(tgt, tid)
@@ -533,7 +433,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             new_r = u4e < rf_t
 
         if R > 0:
-            live = jnp.logical_not(C.is_never(now))
+            live = jnp.logical_not(_is_never(now))
             pend_tid = jnp.sum(jnp.where(ohT, pend.astype(I32), _I(0)),
                                axis=1, dtype=I32) == _I(1)
             # -- arrival ingestion: every request with arr <= now either
@@ -541,7 +441,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             # `rank` orders token-admitted newcomers for exact tail drop.
             # Integer-exact, so the one-hot forms here agree bitwise with
             # the XLA loop's dynamic gathers/scatters.
-            arrived = C.le(arr, C.col(now))
+            arrived = p32.ple(arr, _col(now))
             cnt_now = jnp.where(
                 live, jnp.sum(arrived.astype(I32), axis=1, dtype=I32),
                 arrptr)
@@ -563,8 +463,8 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             dm = do_disp[:, None]
             rstat = jnp.where(ohR & dm, _I(IN_SERVICE), rstat)
             curreq = jnp.where(ohT & dm, hd[:, None], curreq)
-            wqv = C.sub(now, C.gather(ohR, arr))
-            wq = C.where(ohR & dm, C.col(wqv), wq)
+            wqv = p32.psub(now, p32.pgather(ohR, arr))
+            wq = p32.pwhere(ohR & dm, _col(wqv), wq)
             qlen = qlen - do_disp.astype(I32)
             # an idle thread with nothing to take makes no machine step
             step_ok = live & (~pend_tid | do_disp)
@@ -767,21 +667,21 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
                         .astype(jnp.float32) * nm_t).astype(I32)
         wire = jnp.round(jnp.where(code == OP_LOOP, cst[:, 7], cst[:, 6])
                          .astype(jnp.float32) * nm_t).astype(I32)
-        busy_t = C.gather(ohN, busy)
-        start = C.max2(now, busy_t)
-        fin = C.add_i32(start, svc)
-        busy = C.where(is_rdma[:, None] & ohN, C.col(fin), busy)
+        busy_t = p32.pgather(ohN, busy)
+        start = p32.pmax2(now, busy_t)
+        fin = p32.padd_i32(start, svc)
+        busy = p32.pwhere(is_rdma[:, None] & ohN, _col(fin), busy)
         dt_plain = jnp.round(_select(
             [code == OP_LOCAL, code == OP_POLL, code == OP_CS,
              code == OP_THINK],
             [cst[:, 0], cst[:, 1], cst[:, 2], think_e], cst[:, 0])
             .astype(jnp.float32) * nm_my).astype(I32)
-        new_ready = C.where(is_rdma, C.add_i32(fin, wire),
-                            C.add_i32(now, dt_plain))
+        new_ready = p32.pwhere(is_rdma, p32.padd_i32(fin, wire),
+                               p32.padd_i32(now, dt_plain))
         if R > 0:
-            ready = C.where(ohT & sm, C.col(new_ready), ready)
+            ready = p32.pwhere(ohT & sm, _col(new_ready), ready)
         else:
-            ready = C.where(ohT, C.col(new_ready), ready)
+            ready = p32.pwhere(ohT, _col(new_ready), ready)
 
         # -- completion accounting (latency stream, counters) --------------
         fin_m = is_rc | is_ps | is_slr
@@ -792,12 +692,12 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         finished = fin_m & (new_pc == mc.NCS)
         if R > 0:
             finished = finished & step_ok
-        emit(lat_refs, e, finished, C.sub(now, C.gather(ohT, opst)))
+        emit(lat_refs, e, finished, p32.psub(now, p32.pgather(ohT, opst)))
         latn = latn + finished.astype(I32)
         done = done + jnp.where(ohT & finished[:, None], _I(1), _I(0))
         if R > 0:
-            opst = C.where((is_ncs & step_ok)[:, None] & ohT,
-                           C.col(new_ready), opst)
+            opst = p32.pwhere((is_ncs & step_ok)[:, None] & ohT,
+                              _col(new_ready), opst)
             reacq = reacq + (is_sb & (new_pc == mc.SET_VICTIM_R)
                              & step_ok).astype(I32)
             npass = npass + (is_ps & step_ok).astype(I32)
@@ -808,15 +708,15 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             rq = jnp.maximum(req, _I(0))
             ohRq = rio == rq[:, None]
             cm = comp[:, None]
-            sojv = C.sub(new_ready, C.gather(ohRq, arr))
-            soj = C.where(ohRq & cm, C.col(sojv), soj)
+            sojv = p32.psub(new_ready, p32.pgather(ohRq, arr))
+            soj = p32.pwhere(ohRq & cm, _col(sojv), soj)
             rstat = jnp.where(ohRq & cm, _I(COMPLETED), rstat)
             curreq = jnp.where(ohT & cm, _I(-1), curreq)
             new_st = (t0, t1, vic, pc, bud, nxt, prv, tgt, coh, ready,
                       busy, opst, done, latn, reacq, npass,
                       rstat, curreq, arrptr, qlen, wq, soj)
         else:
-            opst = C.where(is_ncs[:, None] & ohT, C.col(new_ready), opst)
+            opst = p32.pwhere(is_ncs[:, None] & ohT, _col(new_ready), opst)
             reacq = reacq + (is_sb
                              & (new_pc == mc.SET_VICTIM_R)).astype(I32)
             npass = npass + is_ps.astype(I32)
@@ -830,18 +730,14 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
     # instead of running ev_chunk - (n_events % ev_chunk) masked no-op
     # steps through the whole state tree
     nev_here = jnp.minimum(_I(ev_chunk), _I(n_events) - j * _I(ev_chunk))
-    if repr32:
-        # explicit i32-counter while_loop: under x64, fori_loop's induction
-        # variable is int64 — the one 64-bit aval Mosaic would still see in
-        # this kernel. The i64 fast path keeps the fori_loop below (its
-        # traced i32 bound keeps the induction variable i32 there too).
-        carry = lax.while_loop(
-            lambda c: c[0] < nev_here,
-            lambda c: (c[0] + _I(1), step(c[0], c[1])),
-            (jnp.zeros((), I32), state))
-        state = carry[1]
-    else:
-        state = lax.fori_loop(_I(0), nev_here, step, state)
+    # explicit i32-counter while_loop: under x64, fori_loop's induction
+    # variable is int64 — the one 64-bit aval Mosaic would still see in
+    # this kernel
+    carry = lax.while_loop(
+        lambda c: c[0] < nev_here,
+        lambda c: (c[0] + _I(1), step(c[0], c[1])),
+        (jnp.zeros((), I32), state))
+    state = carry[1]
     if is_rw:
         s_word[...] = state[-1]
         state = state[:-1]
@@ -854,10 +750,10 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         ref[...] = val
     for crefs, val in ((ready_refs, ready), (busy_refs, busy),
                        (opst_refs, opst)):
-        C.write(crefs, val)
+        _write(crefs, val)
     done_ref[...] = done
     latn_ref[...] = latn[:, None]
-    C.write(tend_refs, C.col(C.reduce_max(ready)))
+    _write(tend_refs, _col(p32.reduce_max(ready)))
     reacq_ref[...] = reacq[:, None]
     npass_ref[...] = npass[:, None]
     if R > 0:
@@ -866,5 +762,5 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         s_curreq[...] = curreq
         s_arrptr[...] = arrptr[:, None]
         s_qlen[...] = qlen[:, None]
-        C.write(wq_refs, wq)
-        C.write(soj_refs, soj)
+        _write(wq_refs, wq)
+        _write(soj_refs, soj)

@@ -11,17 +11,12 @@ scratch. The kernel streams each event's latency out the same way, and
 after the ``pallas_call``, so ``lat_samples`` sizes no VMEM buffer.
 
 Clock representation
-  ``representation="auto" | "i64" | "i32pair"`` picks how the kernel holds
-  its 64-bit clock state (see ``kernel.py``): plain int64 (the interpret /
-  XLA-adjacent fast path, callers hold ``jax.enable_x64(True)``) or
-  carry-correct hi/lo int32 pairs (``i32pair.py`` — what Mosaic can
-  actually lower on TPU, and the only mode that works with x64 entirely
-  off). ``"auto"``
-  resolves to ``i32pair`` for native lowering and ``i64`` under interpret;
-  the ``REPRO_EVENT_CLOCKS`` environment variable overrides either way.
-  Both representations are bitwise-equal to the XLA engine
+  The kernel holds its 64-bit clock state as carry-correct hi/lo int32
+  pairs (``i32pair.py``; see ``kernel.py``): Mosaic has no 64-bit
+  vectors, and interpret mode runs the same kernel program as the chip.
+  It is bitwise-equal to the XLA engine
   (``tests/test_event_loop_native_repr.py``). ``run_events`` packs the
-  pair outputs back into int64; ``run_events_pairs`` returns the raw
+  pair outputs into int64 under x64; ``run_events_pairs`` returns the raw
   (hi, lo) arrays and never touches an int64 — that is the entry point
   the x64-off CI leg exercises.
 
@@ -87,7 +82,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -96,12 +90,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.cost_model import N_COST_ROWS
-from repro.core.sim import I32, I64, LAT_SAMPLES
+from repro.core.sim import I32, LAT_SAMPLES
 from repro.kernels.event_loop import i32pair as p32
 from repro.kernels.event_loop import vmem
 from repro.kernels.event_loop.kernel import event_loop_kernel
-from repro.traffic.stream import (arrival_plan, arrival_times_i64,
-                                  arrival_times_pairs)
+from repro.traffic.stream import arrival_plan, arrival_times_pairs
 
 #: the most replica rows one grid tile holds. One event of one tile took,
 #: on a v5e (us, kernel device time over 20,000 events): at T=80, K=100
@@ -126,8 +119,6 @@ _ZERO = np.int32(0)
 #: HLO ops carry it in their ``op_name`` metadata
 DRAWS_SCOPE = "event_loop.draws"
 
-_REPRESENTATIONS = ("auto", "i64", "i32pair")
-
 
 def default_interpret() -> bool:
     """Native Mosaic lowering on TPU; interpreter everywhere else."""
@@ -137,24 +128,6 @@ def default_interpret() -> bool:
 def _device_kind() -> str:
     """The kind of the device native lowering targets by default."""
     return jax.devices()[0].device_kind
-
-
-def resolve_representation(representation: str, interpret: bool) -> str:
-    """'auto' -> hi/lo i32 pairs for native TPU lowering (Mosaic has no
-    64-bit vectors), plain i64 under interpret. ``REPRO_EVENT_CLOCKS``
-    overrides the auto choice either way."""
-    if representation not in _REPRESENTATIONS:
-        raise ValueError(f"representation must be one of "
-                         f"{_REPRESENTATIONS}, got {representation!r}")
-    if representation == "auto":
-        env = os.environ.get("REPRO_EVENT_CLOCKS", "")
-        if env:
-            if env not in ("i64", "i32pair"):
-                raise ValueError(f"REPRO_EVENT_CLOCKS must be 'i64' or "
-                                 f"'i32pair', got {env!r}")
-            return env
-        return "i64" if interpret else "i32pair"
-    return representation
 
 
 def precompute_draws(seed, edges, zcdf, n_events: int, N: int, kpn: int,
@@ -198,12 +171,11 @@ def precompute_draws(seed, edges, zcdf, n_events: int, N: int, kpn: int,
 def plan_for_run(B, P, n_events, T, N, K, *, R: int = 0,
                  tile: int | None = None,
                  ev_chunk: int = DEFAULT_EV_CHUNK, interpret=None,
-                 representation: str = "auto",
                  lat_samples: int = LAT_SAMPLES,
                  vmem_budget: int | None = None,
                  device_kind: str | None = None,
                  hl: bool = False, rw: bool = False) -> vmem.VmemPlan:
-    """Resolve representation/budget, derive and clamp (tile, ev_chunk)
+    """Resolve the budget, derive and clamp (tile, ev_chunk)
     exactly like ``run_events`` will, and record the resulting VMEM plan.
 
     The tile is derived from B (module docstring, "Replica tile"). The
@@ -228,7 +200,6 @@ def plan_for_run(B, P, n_events, T, N, K, *, R: int = 0,
     """
     if interpret is None:
         interpret = default_interpret()
-    repr32 = resolve_representation(representation, interpret) == "i32pair"
     if vmem_budget is None and not interpret:
         vmem_budget = vmem.budget_for(device_kind or _device_kind())
     cap = max(1, min(MAX_TILE if tile is None else tile, B))
@@ -254,7 +225,7 @@ def plan_for_run(B, P, n_events, T, N, K, *, R: int = 0,
     # price the VMEM footprint up front: the budget sets the ceiling (or
     # raises actionably) instead of the kernel dying inside Mosaic
     shape = dict(ev_chunk=ev_chunk, T=T, N=N, K=K, P=P,
-                 lat_samples=lat_samples, repr32=repr32, R=R, hl=hl, rw=rw,
+                 lat_samples=lat_samples, R=R, hl=hl, rw=rw,
                  budget=vmem_budget, align=align)
     fit = vmem.plan_vmem(tile=cap, **shape).tile
     plan = dataclasses.replace(vmem.plan_vmem(tile=split(fit), **shape),
@@ -268,9 +239,9 @@ def latency_ring(stream, lat_n, lat_samples: int):
     stream: the same ring ``sim._run_events`` writes at ``lat_n %
     lat_samples`` on each completion, last write winning.
 
-    ``stream`` is a tuple of ``(B, E)`` arrays holding one clock per
-    event (one i64 array, or the hi and lo words of a pair), -1 where the
-    event completed nothing; a latency is ``now - op_start >= 0``, so the
+    ``stream`` is a tuple of ``(B, E)`` arrays, the words of one clock
+    per event (the kernel's hi and lo), -1 where the event completed
+    nothing; a latency is ``now - op_start >= 0``, so the
     first word's sign marks a completion. A completion's rank is the
     number of completions before it; only the last ``lat_samples`` ranks
     survive a wrap, so those are keyed by their slot ``rank %
@@ -298,14 +269,40 @@ def latency_ring(stream, lat_n, lat_samples: int):
                  for w in ring)
 
 
-def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
-                   tile, ev_chunk, interpret, repr32, lat_samples,
-                   vmem_budget, device_kind):
-    """Shared pallas_call builder: returns the raw kernel outputs
-    (clock outputs as (hi, lo) pairs when ``repr32``)."""
+def run_events_pairs(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
+                     tile: int | None = None,
+                     ev_chunk: int = DEFAULT_EV_CHUNK, interpret=None,
+                     lat_samples: int = LAT_SAMPLES,
+                     vmem_budget: int | None = None,
+                     device_kind: str | None = None):
+    """The hi/lo representation end to end — no int64 anywhere, so it runs
+    with x64 off (the TPU vector constraint the x64-off CI leg emulates).
+
+    Returns (done (B,T) i32, (lat_hi, lat_lo) (B,lat_samples) i32 each,
+    lat_n (B,) i32, (t_end_hi, t_end_lo) (B,) i32 each, nreacq (B,) i32,
+    npass (B,) i32); combine pairs host-side with ``i32pair.pack_np``.
+    Open-loop workloads append (arr, wq, soj) as (hi, lo) pairs of
+    (B,R) i32 each plus rstat (B,R) i32.
+    """
+    if interpret is None:
+        interpret = default_interpret()
     B = wl.seed.shape[0]
     P = wl.edges.shape[1]
     R = wl.arr_fix.shape[-1]
+    if n_events < 1:
+        # degenerate run: match the XLA loop's 0-iteration outputs instead
+        # of tracing a zero-size grid (which Pallas rejects obscurely)
+        z1 = jnp.zeros(B, I32)
+        base = (jnp.zeros((B, T), I32),
+                (jnp.full((B, lat_samples), -1, I32),
+                 jnp.full((B, lat_samples), -1, I32)),
+                z1, (z1, z1), z1, z1)
+        if R:
+            aplan = jax.vmap(lambda w: arrival_plan(w, n_events))(wl)
+            arr = jax.vmap(arrival_times_pairs)(aplan.gaps)
+            m1 = p32.pfull((B, R), -1)
+            return base + (arr, m1, m1, jnp.zeros((B, R), I32))
+        return base
     kpn = K // N
     is_hl = alg == "hlock"
     is_rw = alg == "alock-rw"
@@ -314,7 +311,6 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
 
     plan = plan_for_run(B, P, n_events, T, N, K, R=R, tile=tile,
                         ev_chunk=ev_chunk, interpret=interpret,
-                        representation="i32pair" if repr32 else "i64",
                         lat_samples=lat_samples, vmem_budget=vmem_budget,
                         device_kind=device_kind, hl=is_hl, rw=is_rw)
     tile, ev_chunk, pad_b = plan.tile, plan.ev_chunk, plan.padded_rows
@@ -347,12 +343,8 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
         # helpers the XLA loop traces — the arrival times ride in as a
         # clock-typed input and come back out verbatim as output #7
         aplan = jax.vmap(lambda w: arrival_plan(w, n_events))(wl)
-        if repr32:
-            arr = jax.vmap(arrival_times_pairs)(aplan.gaps)
-            arr_in = [prep(arr[0]), prep(arr[1])]
-        else:
-            arr = jax.vmap(arrival_times_i64)(aplan.gaps)
-            arr_in = [prep(arr)]
+        arr = jax.vmap(arrival_times_pairs)(aplan.gaps)
+        arr_in = [prep(arr[0]), prep(arr[1])]
         tokp = prep(jnp.asarray(aplan.tok, I32))
         tokcp = prep(jnp.asarray(aplan.tokcum, I32))
         qcapp = prep(jnp.asarray(aplan.qcap, I32))
@@ -367,16 +359,12 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     chunk = pl.BlockSpec((tile, ev_chunk), lambda i, j: (i, j))
 
     def clock_out(w, spec=None):
-        """One (Bp, w) i64 output, or an (hi, lo) pair of i32 outputs."""
+        """An (hi, lo) pair of (Bp, w) i32 outputs."""
         spec = spec or row(w)
-        if repr32:
-            return ([spec, spec], [jax.ShapeDtypeStruct((Bp, w), I32)] * 2)
-        return [spec], [jax.ShapeDtypeStruct((Bp, w), I64)]
+        return [spec, spec], [jax.ShapeDtypeStruct((Bp, w), I32)] * 2
 
     def clock_scratch(w):
-        if repr32:
-            return [pltpu.VMEM((tile, w), I32)] * 2
-        return [pltpu.VMEM((tile, w), I64)]
+        return [pltpu.VMEM((tile, w), I32)] * 2
 
     # each event's latency (-1: no completion), streamed out by chunk
     lat_specs, lat_shapes = clock_out(n_events + pad_e, chunk)
@@ -431,7 +419,7 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     if is_hl:
         operands += [jnp.asarray(rackp, I32)]
     if R:
-        in_specs += [row(R)] * (len(arr_in) + 3)
+        in_specs += [row(R)] * 5
         operands += [*arr_in, tokp, tokcp, qcapp]
         scratch_shapes += [pltpu.VMEM((tile, T), I32),   # curreq
                            pltpu.VMEM((tile, 1), I32),   # arrptr
@@ -439,8 +427,7 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
 
     out = pl.pallas_call(
         functools.partial(event_loop_kernel, alg=alg, T=T, N=N, K=K, P=P,
-                          n_events=n_events, ev_chunk=ev_chunk,
-                          repr32=repr32, R=R),
+                          n_events=n_events, ev_chunk=ev_chunk, R=R),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -452,36 +439,25 @@ def _pallas_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     )(*operands)
 
     out = [o[:B] for o in out]
-    if repr32:
-        (done, lat_hi, lat_lo, lat_n, te_hi, te_lo, nreacq, npass,
-         *extra) = out
-        base = (done, latency_ring((lat_hi, lat_lo), lat_n[:, 0],
-                                   lat_samples),
-                lat_n[:, 0], (te_hi[:, 0], te_lo[:, 0]), nreacq[:, 0],
-                npass[:, 0])
-        if R:
-            wq_hi, wq_lo, soj_hi, soj_lo, rstat = extra
-            return base + (arr, (wq_hi, wq_lo), (soj_hi, soj_lo), rstat)
-        return base
-    done, lat, lat_n, t_end, nreacq, npass, *extra = out
-    (lat,) = latency_ring((lat,), lat_n[:, 0], lat_samples)
-    base = (done, lat, lat_n[:, 0], t_end[:, 0], nreacq[:, 0],
+    (done, lat_hi, lat_lo, lat_n, te_hi, te_lo, nreacq, npass,
+     *extra) = out
+    base = (done, latency_ring((lat_hi, lat_lo), lat_n[:, 0], lat_samples),
+            lat_n[:, 0], (te_hi[:, 0], te_lo[:, 0]), nreacq[:, 0],
             npass[:, 0])
     if R:
-        wq, soj, rstat = extra
-        return base + (arr, wq, soj, rstat)
+        wq_hi, wq_lo, soj_hi, soj_lo, rstat = extra
+        return base + (arr, (wq_hi, wq_lo), (soj_hi, soj_lo), rstat)
     return base
 
 
 def run_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
                tile: int | None = None, ev_chunk: int = DEFAULT_EV_CHUNK,
-               interpret=None, representation: str = "auto",
-               lat_samples: int = LAT_SAMPLES,
+               interpret=None, lat_samples: int = LAT_SAMPLES,
                vmem_budget: int | None = None,
                device_kind: str | None = None):
     """Batched Pallas event loop; must run under ``jax.enable_x64(True)``
-    (the int64 output contract — use :func:`run_events_pairs` to stay in
-    pure int32 with x64 off).
+    (the kernel's pair outputs packed to int64 — use
+    :func:`run_events_pairs` to stay in pure int32 with x64 off).
 
     ``wl`` is a ``WorkloadOperands`` with a leading replica axis B on
     every leaf: locality (B,P,T) f32, zcdf (B,P,K//N) f32, edges (B,P)
@@ -505,101 +481,36 @@ def run_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     ``vmem_budget=None`` means the default budget for native lowering and
     unconstrained under interpret.
     """
-    if interpret is None:
-        interpret = default_interpret()
-    repr32 = resolve_representation(representation, interpret) == "i32pair"
-    B = wl.seed.shape[0]
-    R = wl.arr_fix.shape[-1]
-    if n_events < 1:
-        # degenerate run: match the XLA loop's 0-iteration outputs instead
-        # of tracing a zero-size grid (which Pallas rejects obscurely)
-        base = (jnp.zeros((B, T), I32),
-                jnp.full((B, lat_samples), -1, I64), jnp.zeros(B, I32),
-                jnp.zeros(B, I64), jnp.zeros(B, I32), jnp.zeros(B, I32))
-        if R:
-            aplan = jax.vmap(lambda w: arrival_plan(w, n_events))(wl)
-            arr = jax.vmap(arrival_times_i64)(aplan.gaps)
-            return base + (arr, jnp.full((B, R), -1, I64),
-                           jnp.full((B, R), -1, I64),
-                           jnp.zeros((B, R), I32))
-        return base
-    out = _pallas_events(alg, T, N, K, n_events, wl, thread_node,
-                         lock_node, tile=tile, ev_chunk=ev_chunk,
-                         interpret=interpret, repr32=repr32,
-                         lat_samples=lat_samples, vmem_budget=vmem_budget,
-                         device_kind=device_kind)
-    if repr32:
-        done, lat, lat_n, t_end, nreacq, npass = out[:6]
-        base = (done, p32.pack(lat), lat_n, p32.pack(t_end), nreacq, npass)
-        if R:
-            arr, wq, soj, rstat = out[6:]
-            return base + (p32.pack(arr), p32.pack(wq), p32.pack(soj),
-                           rstat)
-        return base
-    return out
-
-
-def run_events_pairs(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
-                     tile: int | None = None,
-                     ev_chunk: int = DEFAULT_EV_CHUNK, interpret=None,
-                     lat_samples: int = LAT_SAMPLES,
-                     vmem_budget: int | None = None,
-                     device_kind: str | None = None):
-    """The hi/lo representation end to end — no int64 anywhere, so it runs
-    with x64 off (the TPU vector constraint the x64-off CI leg emulates).
-
-    Returns (done (B,T) i32, (lat_hi, lat_lo) (B,lat_samples) i32 each,
-    lat_n (B,) i32, (t_end_hi, t_end_lo) (B,) i32 each, nreacq (B,) i32,
-    npass (B,) i32); combine pairs host-side with ``i32pair.pack_np``.
-    Open-loop workloads append (arr, wq, soj) as (hi, lo) pairs of
-    (B,R) i32 each plus rstat (B,R) i32.
-    """
-    if interpret is None:
-        interpret = default_interpret()
-    B = wl.seed.shape[0]
-    R = wl.arr_fix.shape[-1]
-    if n_events < 1:
-        z1 = jnp.zeros(B, I32)
-        base = (jnp.zeros((B, T), I32),
-                (jnp.full((B, lat_samples), -1, I32),
-                 jnp.full((B, lat_samples), -1, I32)),
-                z1, (z1, z1), z1, z1)
-        if R:
-            aplan = jax.vmap(lambda w: arrival_plan(w, n_events))(wl)
-            arr = jax.vmap(arrival_times_pairs)(aplan.gaps)
-            m1 = p32.pfull((B, R), -1)
-            return base + (arr, m1, m1, jnp.zeros((B, R), I32))
-        return base
-    return _pallas_events(alg, T, N, K, n_events, wl, thread_node,
-                          lock_node, tile=tile, ev_chunk=ev_chunk,
-                          interpret=interpret, repr32=True,
-                          lat_samples=lat_samples, vmem_budget=vmem_budget,
-                          device_kind=device_kind)
+    out = run_events_pairs(alg, T, N, K, n_events, wl, thread_node,
+                           lock_node, tile=tile, ev_chunk=ev_chunk,
+                           interpret=interpret, lat_samples=lat_samples,
+                           vmem_budget=vmem_budget, device_kind=device_kind)
+    done, lat, lat_n, t_end, nreacq, npass = out[:6]
+    base = (done, p32.pack(lat), lat_n, p32.pack(t_end), nreacq, npass)
+    if len(out) > 6:
+        arr, wq, soj, rstat = out[6:]
+        return base + (p32.pack(arr), p32.pack(wq), p32.pack(soj), rstat)
+    return base
 
 
 _jit_run_events = functools.partial(
     jax.jit, static_argnames=("alg", "T", "N", "K", "n_events", "tile",
-                              "ev_chunk", "interpret", "representation",
-                              "lat_samples", "vmem_budget"))(run_events)
+                              "ev_chunk", "interpret", "lat_samples",
+                              "vmem_budget"))(run_events)
 
 
 def run_events_jit(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
                    tile: int | None = None,
                    ev_chunk: int = DEFAULT_EV_CHUNK, interpret=None,
-                   representation: str = "auto",
                    lat_samples: int = LAT_SAMPLES,
                    vmem_budget: int | None = None):
-    """Jitted ``run_events`` with the environment-dependent knobs resolved
-    *eagerly*, so they participate in the jit cache key — a cached
-    executable traced under ``"auto"`` would otherwise silently ignore a
-    later ``REPRO_EVENT_CLOCKS`` change (the env read inside a jitted
-    function only happens at trace time)."""
+    """``run_events`` compiled once per shape and static knob: the entry
+    the sweep's unsharded dispatch and ``sim.simulate`` call. ``interpret``
+    is resolved from the default backend *before* the jit boundary, so
+    the executable is keyed by the mode it was traced in."""
     if interpret is None:
         interpret = default_interpret()
-    representation = resolve_representation(representation, interpret)
     return _jit_run_events(alg, T, N, K, n_events, wl, thread_node,
                            lock_node, tile=tile, ev_chunk=ev_chunk,
-                           interpret=interpret,
-                           representation=representation,
-                           lat_samples=lat_samples,
+                           interpret=interpret, lat_samples=lat_samples,
                            vmem_budget=vmem_budget)

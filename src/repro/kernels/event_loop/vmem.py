@@ -2,7 +2,7 @@
 
 The kernel keeps *every* per-replica buffer VMEM-resident for the whole
 ``n_events`` run, so its footprint per grid step is a closed-form function
-of ``(tile, ev_chunk, T, N, K, P, representation)``; the latency ring is
+of ``(tile, ev_chunk, T, N, K, P)``; the latency ring is
 built outside the kernel, so ``lat_samples`` costs no VMEM. On real TPU
 an oversized tile dies inside Mosaic as an opaque VMEM-exhaustion error;
 this planner computes the byte table up front, **deterministically
@@ -11,7 +11,7 @@ and raises an actionable ``ValueError`` when even the smallest legal
 tile cannot fit — never a silent wrong answer.
 
 Byte formula (one grid step = one replica tile; int32/float32 = 4 bytes,
-clocks = 8 bytes, as one i64 buffer or an (hi, lo) i32 pair):
+clocks = 8 bytes, as an (hi, lo) pair of i32 buffers):
 
   streamed inputs   u1/r2/r3: ``3 * tile*ev_chunk*4``, **x2** for the
                     pipeline double-buffer along the sequential event axis
@@ -42,13 +42,12 @@ actually allocates in interpret mode. The chosen plan is recorded via
 the ``benchmarks.run`` scenario summaries.
 
 >>> p = plan_vmem(tile=8, ev_chunk=512, T=16, N=4, K=16, P=1,
-...               lat_samples=1 << 15, repr32=True)
+...               lat_samples=1 << 15)
 >>> p.tile, p.shrunk, p.total_bytes == sum(
 ...     b for _, b in p.breakdown.values())
 (8, False, True)
 >>> tight = plan_vmem(tile=64, ev_chunk=512, T=16, N=4, K=16, P=1,
-...                   lat_samples=1 << 15, repr32=True,
-...                   budget=256 * 2**10)
+...                   lat_samples=1 << 15, budget=256 * 2**10)
 >>> tight.requested_tile, tight.tile, tight.shrunk
 (64, 8, True)
 """
@@ -96,7 +95,6 @@ def budget_for(device_kind: str) -> int:
 
 _I32 = 4
 _F32 = 4
-_CLOCK = 8          # one i64 buffer, or an (hi, lo) i32 pair — same bytes
 #: the sequential event axis streams u1/r2/r3 in and the per-event
 #: latencies out chunk by chunk; Pallas double-buffers streamed blocks so
 #: the next chunk moves during compute
@@ -106,9 +104,8 @@ PIPELINE_FACTOR = 2
 #: against the traced kernel's buffer bindings (in.u4 is the alock-rw
 #: reader/writer coin stream and only present when ``rw=True``)
 STREAMED_INPUTS = ("in.u1", "in.r2", "in.r3", "in.u4")
-#: the streamed outputs: the per-event latency, one i64 block or a pair
-STREAMED_OUTPUTS = ("out.lat_stream", "out.lat_stream.hi",
-                    "out.lat_stream.lo")
+#: the streamed outputs: the per-event latency's hi and lo blocks
+STREAMED_OUTPUTS = ("out.lat_stream.hi", "out.lat_stream.lo")
 
 
 def _entries(name, shape, itemsize, factor=1):
@@ -118,18 +115,15 @@ def _entries(name, shape, itemsize, factor=1):
     return (name, (shape, n * itemsize))
 
 
-def _clock_entries(name, shape, repr32: bool, factor=1):
-    """One i64 buffer, or two i32 buffers for the hi/lo representation —
-    the shapes here must match ``ops.run_events``'s allocations exactly."""
-    if repr32:
-        return [_entries(f"{name}.hi", shape, _I32, factor),
-                _entries(f"{name}.lo", shape, _I32, factor)]
-    return [_entries(name, shape, _CLOCK, factor)]
+def _clock_entries(name, shape, factor=1):
+    """A clock's hi and lo i32 buffers — the shapes here must match
+    ``ops.run_events``'s allocations exactly."""
+    return [_entries(f"{name}.hi", shape, _I32, factor),
+            _entries(f"{name}.lo", shape, _I32, factor)]
 
 
 def buffer_table(tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
-                 repr32: bool, R: int = 0, hl: bool = False,
-                 rw: bool = False) -> dict:
+                 R: int = 0, hl: bool = False, rw: bool = False) -> dict:
     """name -> (block shape, bytes) for every VMEM buffer of one grid step.
 
     Mirrors the ``in_specs`` / ``out_specs`` / ``scratch_shapes`` that
@@ -164,24 +158,24 @@ def buffer_table(tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
         _entries("in.lock_node", (1, K), _I32),
         *([_entries("in.rack", (tile, N), _I32)] if hl else []),
         # open-loop arrival rows (same block every chunk)
-        *([*_clock_entries("in.arr", (tile, R), repr32),
+        *([*_clock_entries("in.arr", (tile, R)),
            _entries("in.tok", (tile, R), _I32),
            _entries("in.tokcum", (tile, R), _I32),
            _entries("in.qcap", (tile, R), _I32)] if R else []),
         # outputs (flushed when the replica tile changes, but for the
         # latency stream: STREAMED_OUTPUTS, double-buffered like the draws)
         _entries("out.done", (tile, T), _I32),
-        *_clock_entries("out.lat_stream", (tile, ev_chunk), repr32,
+        *_clock_entries("out.lat_stream", (tile, ev_chunk),
                         PIPELINE_FACTOR),
         _entries("out.lat_n", (tile, 1), _I32),
-        *_clock_entries("out.t_end", (tile, 1), repr32),
+        *_clock_entries("out.t_end", (tile, 1)),
         _entries("out.reacq", (tile, 1), _I32),
         _entries("out.npass", (tile, 1), _I32),
         # open-loop per-request outputs
-        *([*_clock_entries("out.wq", (tile, R), repr32),
-           *_clock_entries("out.soj", (tile, R), repr32),
+        *([*_clock_entries("out.wq", (tile, R)),
+           *_clock_entries("out.soj", (tile, R)),
            _entries("out.rstat", (tile, R), _I32)] if R else []),
-        # semantic scratch (int32 in every representation)
+        # semantic scratch (int32)
         _entries("scr.tail0", (tile, K), _I32),
         _entries("scr.tail1", (tile, K), _I32),
         _entries("scr.victim", (tile, K), _I32),
@@ -194,9 +188,9 @@ def buffer_table(tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
         # alock-rw reader counts (between semantic and clock scratch)
         *([_entries("scr.word", (tile, K), _I32)] if rw else []),
         # clock scratch
-        *_clock_entries("scr.ready", (tile, T), repr32),
-        *_clock_entries("scr.busy", (tile, N), repr32),
-        *_clock_entries("scr.op_start", (tile, T), repr32),
+        *_clock_entries("scr.ready", (tile, T)),
+        *_clock_entries("scr.busy", (tile, N)),
+        *_clock_entries("scr.op_start", (tile, T)),
         # open-loop dispatch scratch
         *([_entries("scr.curreq", (tile, T), _I32),
            _entries("scr.arrptr", (tile, 1), _I32),
@@ -212,7 +206,6 @@ class VmemPlan:
     tile: int
     ev_chunk: int
     lat_samples: int
-    representation: str                      # "i64" | "i32pair"
     budget: int | None                       # bytes; None = unconstrained
     total_bytes: int
     breakdown: Mapping[str, tuple]           # name -> (shape, bytes)
@@ -236,11 +229,13 @@ class VmemPlan:
                 else self.tiles * self.tile - self.rows)
 
     def as_dict(self) -> dict:
-        """Compact form for ``exec_stats()`` / benchmark JSON rows."""
+        """Compact form for ``exec_stats()`` / benchmark JSON rows. The
+        clock ``representation`` is always ``"i32pair"``; the key stays
+        for the readers that check it."""
         return {
             "requested_tile": self.requested_tile, "tile": self.tile,
             "ev_chunk": self.ev_chunk, "lat_samples": self.lat_samples,
-            "representation": self.representation, "budget": self.budget,
+            "representation": "i32pair", "budget": self.budget,
             "total_bytes": self.total_bytes, "shrunk": self.shrunk,
             "rows": self.rows, "tiles": self.tiles,
             "padded_rows": self.padded_rows,
@@ -248,7 +243,7 @@ class VmemPlan:
 
 
 def plan_vmem(*, tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
-              lat_samples: int, repr32: bool, R: int = 0,
+              lat_samples: int, R: int = 0,
               hl: bool = False, rw: bool = False,
               budget: int | None = None, align: int = 1) -> VmemPlan:
     """Compute the byte table; halve ``tile`` until ``budget`` fits.
@@ -269,7 +264,7 @@ def plan_vmem(*, tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
     requested = tile
     t = tile
     while True:
-        table = buffer_table(t, ev_chunk, T, N, K, P, repr32, R, hl, rw)
+        table = buffer_table(t, ev_chunk, T, N, K, P, R, hl, rw)
         total = sum(b for _, b in table.values())
         if budget is None or total <= budget or t <= align:
             break
@@ -284,9 +279,7 @@ def plan_vmem(*, tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
             f"ev_chunk ({ev_chunk}), or raise the budget "
             f"(run_events(vmem_budget=...)).")
     return VmemPlan(requested_tile=requested, tile=t, ev_chunk=ev_chunk,
-                    lat_samples=lat_samples,
-                    representation="i32pair" if repr32 else "i64",
-                    budget=budget, total_bytes=total, breakdown=table)
+                    lat_samples=lat_samples, budget=budget, total_bytes=total, breakdown=table)
 
 
 # -- plan registry (read by batch.exec_stats / benchmarks) ------------------

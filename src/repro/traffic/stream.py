@@ -23,8 +23,8 @@ is computed here, once, before the event loop runs:
 
 The bounded-queue *tail drop* itself is service-dependent and stays in
 the event loops; both consume the same plan arrays, which is what makes
-the two engines (and both clock representations) bitwise-equal on the
-arrival path — asserted end-to-end in ``tests/test_traffic.py``.
+the two engines (the XLA engine's int64 clocks and the kernel's hi/lo
+pairs) bitwise-equal on the arrival path — asserted end-to-end in ``tests/test_traffic.py``.
 
 All helpers are pure ``jnp`` over f32/i32 with pinned dtypes: they trace
 identically with and without x64 enabled.

@@ -29,8 +29,6 @@ from repro.analysis import (
     RULES,
     bucket_signature,
     check_bucket_signatures,
-    check_env_resolution,
-    check_runner_cache_keys,
     run_rules,
     trace_entrypoints,
     walk_jaxpr,
@@ -56,12 +54,11 @@ def _eps():
 # ---------------------------------------------------------------- gate 1
 
 def test_clean_entrypoints_zero_findings():
-    """The shipped engine must be lint-clean: all 8 rules, all 4 kinds
-    (xla-batch, pallas-i64, pallas-native, pallas-pairs), 0 findings."""
+    """The shipped engine must be lint-clean: all 7 rules, all 3 kinds
+    (xla-batch, pallas-native, pallas-pairs), 0 findings."""
     eps = _eps()
     kinds = {e.kind for e in eps}
-    assert {"xla-batch", "pallas-i64",
-            "pallas-native", "pallas-pairs"} <= kinds, kinds
+    assert kinds == {"xla-batch", "pallas-native", "pallas-pairs"}, kinds
     findings = run_rules(eps)
     assert findings == [], "\n".join(f.format() for f in findings)
 
@@ -115,16 +112,6 @@ def test_every_finding_is_stamped():
             assert f.family == RULES[f.rule].family, f
             assert f.severity in ("error", "warning"), f
             assert f.entrypoint and f.message, f
-
-
-def test_lazy_env_resolution_is_caught():
-    """R002 positive: a resolver that ignores REPRO_EVENT_CLOCKS fires;
-    the real resolver (eager read at call time) stays clean."""
-    from repro.analysis.fixtures import lazy_resolver
-    assert check_env_resolution(lazy_resolver), \
-        "R002 went blind on the lazy-resolver fixture"
-    assert check_env_resolution() == []
-    assert check_runner_cache_keys() == []
 
 
 def test_bucket_signature_drift_is_caught():

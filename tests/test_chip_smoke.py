@@ -57,6 +57,7 @@ def test_mismatches_names_the_differing_replica():
 def test_require_native_accepts_a_chip_plan():
     chip = {"representation": "i32pair", "budget": 1 << 25}
     cs.require_native([chip, chip])
-    for plans in ([], [chip, {"representation": "i64", "budget": None}]):
+    interpreted = {"representation": "i32pair", "budget": None}
+    for plans in ([], [chip, interpreted]):
         with pytest.raises(RuntimeError, match="natively"):
             cs.require_native(plans)

@@ -1,10 +1,10 @@
-"""Differential harness for the native-TPU clock representation.
+"""Differential harness for the event-loop kernel's hi/lo clocks.
 
-Runs the event-loop kernel with the hi/lo i32-pair representation forced
-on (interpret mode, **no** ``enable_x64`` anywhere near the kernel — the
-x64-off CI leg executes this file with ``JAX_ENABLE_X64=0`` to emulate the
-TPU i32-vector constraint) and asserts bitwise equality with the XLA
-engine:
+Runs the event-loop kernel, whose clocks are hi/lo i32 pairs (interpret
+mode, mostly through ``run_events_pairs`` with **no** ``enable_x64``
+anywhere near the kernel — the x64-off CI leg executes this file with
+``JAX_ENABLE_X64=0`` to emulate the TPU i32-vector constraint) and
+asserts bitwise equality with the XLA engine:
 
   * an alg x phased x zipf x churn operand matrix covering **all five
     algorithms** (alock, spinlock, mcs, the hierarchical hlock with a
@@ -16,8 +16,12 @@ engine:
     rack-locality, plus the open-loop open-loop-ramp and burst-storm,
     whose buckets carry R request slots and four extra per-request
     outputs) via ``repro.experiments.scenario_workloads``;
-  * latency-ring overflow (``latn`` wrapping past ``lat_samples``) across
-    all three engines: XLA, i64-pallas, i32-pair-pallas.
+  * latency-ring overflow (``latn`` wrapping past ``lat_samples``), and
+    both entry points (``run_events`` packed under x64,
+    ``run_events_pairs`` packed on the host) at ragged derived tiles and
+    through ring wraparound;
+  * interpret mode traces the very kernel program native lowering
+    traces, so these CPU tests check the chip's own kernel.
 
 The XLA oracle still runs under a local ``jax.enable_x64(True)`` (its
 clocks are real int64); pair outputs are packed host-side with
@@ -31,11 +35,10 @@ import jax.numpy as jnp
 from repro.core.sim import topology, zipf_cdf
 from repro.experiments import scenario_names, scenario_workloads
 from repro.kernels.event_loop import i32pair as p32
-from repro.kernels.event_loop.ops import (resolve_representation,
-                                          run_events, run_events_pairs)
+from repro.kernels.event_loop.ops import run_events, run_events_pairs
 from repro.kernels.event_loop.ref import run_events_ref
-from repro.workloads import (Phase, Workload, WorkloadOperands, lower,
-                             pad_phases)
+from repro.workloads import (Arrivals, Phase, Workload, WorkloadOperands,
+                             lower, pad_phases)
 
 EV = 1100
 
@@ -72,6 +75,22 @@ def _assert_bitwise(ref, got):
     for name, a, b in zip(OUT_NAMES, ref, got):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=f"mismatch in {name}")
+
+
+def _run(entry, alg, T, N, K, ev, wl, tn, ln, **kw):
+    """One interpret-mode kernel run as np int64 outputs: ``run_events``
+    packs them under x64; ``run_events_pairs`` runs with x64 off and its
+    pairs are packed on the host."""
+    if entry == "run_events":
+        with jax.enable_x64(True):
+            return [np.asarray(r) for r in run_events(
+                alg, T, N, K, ev, wl, tn, ln, interpret=True, **kw)]
+    with jax.enable_x64(False):
+        return list(_pack_outputs(run_events_pairs(
+            alg, T, N, K, ev, wl, tn, ln, interpret=True, **kw)))
+
+
+ENTRIES = ["run_events", "run_events_pairs"]
 
 
 def _stack_operands(workloads, n_events, **lower_kw):
@@ -283,7 +302,7 @@ def test_registry_scenarios_bitwise_i32pair():
 
 def test_ring_overflow_identical_across_engines():
     """latn wrapping past lat_samples: ring contents and p50/p99
-    aggregates must match on XLA, i64-pallas and i32-pair-pallas."""
+    aggregates must match on the XLA engine and the i32-pair kernel."""
     alg, N, tpn, K, lat_samples = "alock", 2, 2, 8, 64
     T = N * tpn
     ev = 2500                       # ~400 completions >> 64 slots
@@ -294,21 +313,15 @@ def test_ring_overflow_identical_across_engines():
         ref = [np.asarray(r) for r in
                run_events_ref(alg, T, N, K, ev, wl, tn, ln,
                               lat_samples=lat_samples)]
-        i64 = run_events(alg, T, N, K, ev, wl, tn, ln, interpret=True,
-                         representation="i64", lat_samples=lat_samples,
-                         ev_chunk=512)
-        i64 = [np.asarray(r) for r in i64]
     pair = _pack_outputs(run_events_pairs(
         alg, T, N, K, ev, wl, tn, ln, interpret=True,
         lat_samples=lat_samples, ev_chunk=512))
 
     assert ref[2][0] > 2 * lat_samples      # the ring really wrapped
     assert (ref[1] >= 0).all()              # ... and every slot was filled
-    _assert_bitwise(ref, i64)
     _assert_bitwise(ref, pair)
-    for eng in (i64, pair):
-        assert np.percentile(eng[1][0], 50) == np.percentile(ref[1][0], 50)
-        assert np.percentile(eng[1][0], 99) == np.percentile(ref[1][0], 99)
+    assert np.percentile(pair[1][0], 50) == np.percentile(ref[1][0], 50)
+    assert np.percentile(pair[1][0], 99) == np.percentile(ref[1][0], 99)
 
 
 def _ring_bucket(alg, ev):
@@ -327,8 +340,8 @@ def _ring_bucket(alg, ev):
     return N * tpn, N, K, wl._replace(cost_rows=jnp.asarray(cost)), tn, ln
 
 
-#: XLA oracle outputs by (alg, lat_samples), shared by both
-#: representations' cases of the wraparound test
+#: XLA oracle outputs by (alg, lat_samples), shared by both entry
+#: points' cases of the wraparound test
 _RING_REFS: dict = {}
 
 
@@ -343,9 +356,9 @@ def _ring_ref(alg, ev, lat_samples):
     return _RING_REFS[alg, lat_samples]
 
 
-@pytest.mark.parametrize("representation", ["i64", "i32pair"])
+@pytest.mark.parametrize("entry", ENTRIES)
 @pytest.mark.parametrize("alg", ["alock", "mcs", "spinlock", "hlock"])
-def test_streamed_ring_wraparound_bitwise(alg, representation):
+def test_streamed_ring_wraparound_bitwise(alg, entry):
     """The ring ``ops.py`` builds from the kernel's per-event latency
     stream equals the XLA engine's ring bitwise, whatever the ring size:
     completions that fill exactly ``lat_samples``, one more and one fewer
@@ -358,17 +371,14 @@ def test_streamed_ring_wraparound_bitwise(alg, representation):
     assert n[1] == 0 and n[0] >= 40, n      # the idle replica, and wraps
     for lat_samples in (n[0], n[0] - 1, n[0] + 1, n[0] // 3 - 1):
         ref = _ring_ref(alg, ev, int(lat_samples))
-        with jax.enable_x64(True):
-            got = [np.asarray(r) for r in
-                   run_events(alg, T, N, K, ev, wl, tn, ln, interpret=True,
-                              representation=representation, tile=2,
-                              ev_chunk=256, lat_samples=int(lat_samples))]
+        got = _run(entry, alg, T, N, K, ev, wl, tn, ln, tile=2,
+                   ev_chunk=256, lat_samples=int(lat_samples))
         _assert_bitwise(ref, got)
         assert (ref[1][1] == -1).all()      # the idle replica's ring
 
 
-#: XLA oracle outputs by algorithm, shared by both representations'
-#: cases of the derived-tile test
+#: XLA oracle outputs by algorithm, shared by both entry points' cases
+#: of the derived-tile test
 _TILE_REFS: dict = {}
 
 
@@ -386,10 +396,10 @@ def _ragged_bucket(alg):
     return wl, tn, ln
 
 
-@pytest.mark.parametrize("representation", ["i64", "i32pair"])
+@pytest.mark.parametrize("entry", ENTRIES)
 @pytest.mark.parametrize("alg", ["alock", "mcs", "spinlock", "alock-rw",
                                  "hlock"])
-def test_derived_tile_bitwise(alg, representation):
+def test_derived_tile_bitwise(alg, entry):
     """The derived tile (all 15 ragged rows in one tile) and a tile of 8
     (two tiles, the second padded by an edge copy) give outputs equal to
     each other and to the XLA engine, bit for bit: replica rows are
@@ -400,70 +410,64 @@ def test_derived_tile_bitwise(alg, representation):
             _TILE_REFS[alg] = [np.asarray(r) for r in run_events_ref(
                 alg, 4, 2, 4, 400, wl, tn, ln)]
     for tile in (None, 8):
-        with jax.enable_x64(True):
-            got = [np.asarray(r) for r in run_events(
-                alg, 4, 2, 4, 400, wl, tn, ln, interpret=True,
-                representation=representation, tile=tile, ev_chunk=256)]
+        got = _run(entry, alg, 4, 2, 4, 400, wl, tn, ln, tile=tile,
+                   ev_chunk=256)
         _assert_bitwise(_TILE_REFS[alg], got)
 
 
 def test_packed_run_events_i32pair_matches_i64():
-    """The public ``run_events(representation=)`` contract: both
-    representations return identical int64 outputs under x64."""
+    """The public ``run_events`` contract: under x64 it returns the
+    kernel's pairs packed to int64, field by field what the x64-off
+    ``run_events_pairs`` gives packed on the host with ``pack_np``."""
     alg, N, tpn, K, ev = "mcs", 2, 2, 8, 900
     T = N * tpn
     tn, ln, _ = topology(alg, N, tpn, K)
     w = lower(Workload(alg, N, tpn, K, locality=0.85, seed=5), ev)
     wl = WorkloadOperands(*(jnp.asarray(a)[None] for a in w.operands))
     with jax.enable_x64(True):
-        a = run_events(alg, T, N, K, ev, wl, tn, ln, interpret=True,
-                       representation="i64")
-        b = run_events(alg, T, N, K, ev, wl, tn, ln, interpret=True,
-                       representation="i32pair")
-        for x, y in zip(a, b):
-            assert x.dtype == y.dtype
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        a = [np.asarray(r) for r in run_events(alg, T, N, K, ev, wl, tn, ln,
+                                               interpret=True)]
+    with jax.enable_x64(False):
+        b = _pack_outputs(run_events_pairs(alg, T, N, K, ev, wl, tn, ln,
+                                           interpret=True))
+    assert len(a) == len(b) == 6
+    for name, x, y in zip(OUT_NAMES, a, b):
+        assert x.dtype == y.dtype, (name, x.dtype, y.dtype)
+        np.testing.assert_array_equal(x, y, err_msg=name)
+    assert a[1].dtype == a[3].dtype == np.int64
 
 
-def test_resolve_representation():
-    assert resolve_representation("i64", interpret=True) == "i64"
-    assert resolve_representation("i32pair", interpret=True) == "i32pair"
-    assert resolve_representation("auto", interpret=True) == "i64"
-    assert resolve_representation("auto", interpret=False) == "i32pair"
-    with pytest.raises(ValueError, match="representation"):
-        resolve_representation("i48", interpret=True)
-
-
-def test_env_override_keys_the_jit_cache(monkeypatch):
-    """Flipping REPRO_EVENT_CLOCKS mid-process must re-trace, not reuse a
-    cached executable of the other representation — run_events_jit
-    resolves the env *before* the jit boundary so it keys the cache. A
-    fresh trace is observable through the VMEM plan it records (a cache
-    hit records nothing), and both traces stay bitwise-equal."""
-    from repro.kernels.event_loop import vmem
-    from repro.kernels.event_loop.ops import run_events_jit
-    alg, N, tpn, K, ev = "alock", 2, 2, 8, 600
-    T = N * tpn
-    tn, ln, _ = topology(alg, N, tpn, K)
-    w = lower(Workload(alg, N, tpn, K, locality=0.9, seed=2), ev)
-    wl = WorkloadOperands(*(jnp.asarray(a)[None] for a in w.operands))
+def _kernel_jaxpr(alg, T, N, K, ev, wl, tn, ln, interpret):
+    """The ``pallas_call``'s kernel jaxpr of one ``run_events`` trace."""
+    def f(w, a, b):
+        return run_events(alg, T, N, K, ev, w, a, b, interpret=interpret,
+                          tile=2, ev_chunk=128, vmem_budget=1 << 30,
+                          device_kind="TPU v5 lite")
     with jax.enable_x64(True):
-        a = run_events_jit(alg, T, N, K, ev, wl, tn, ln, interpret=True,
-                           lat_samples=256)
-        vmem.clear_plan()
-        monkeypatch.setenv("REPRO_EVENT_CLOCKS", "i32pair")
-        b = run_events_jit(alg, T, N, K, ev, wl, tn, ln, interpret=True,
-                           lat_samples=256)
-    plan = vmem.last_plan()
-    assert plan is not None and plan.representation == "i32pair"
-    for x, y in zip(a, b):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        jx = jax.make_jaxpr(f)(wl, tn, ln)
+    calls = [e for e in jx.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1, [e.primitive.name for e in jx.jaxpr.eqns]
+    return str(calls[0].params["jaxpr"])
 
 
-def test_resolve_representation_env_override(monkeypatch):
-    monkeypatch.setenv("REPRO_EVENT_CLOCKS", "i32pair")
-    assert resolve_representation("auto", interpret=True) == "i32pair"
-    assert resolve_representation("i64", interpret=True) == "i64"
-    monkeypatch.setenv("REPRO_EVENT_CLOCKS", "bogus")
-    with pytest.raises(ValueError, match="REPRO_EVENT_CLOCKS"):
-        resolve_representation("auto", interpret=True)
+@pytest.mark.parametrize("case", ["alock", "mcs", "spinlock", "hlock",
+                                  "alock-rw", "open-loop"])
+def test_interpreter_runs_the_chip_kernel_program(case):
+    """Interpret mode traces the kernel program native lowering traces,
+    equation for equation: the CPU tests check the chip's own kernel.
+    Two replicas in one tile, a ragged last event chunk (300 % 128),
+    and for open loop the arrival rows and per-request outputs."""
+    alg = "alock" if case == "open-loop" else case
+    arr = (Arrivals(rate_per_us=2.0, max_requests=24, queue_cap=8,
+                    token_rate_per_us=1.0, token_burst=4.0)
+           if case == "open-loop" else None)
+    _, wl = _stack_operands([Workload(
+        alg, 2, 2, 4, locality=0.8, seed=41 + s, arrivals=arr,
+        read_frac=0.6 if alg == "alock-rw" else 0.0,
+        topology=(0, 0) if alg == "hlock" else None) for s in range(2)],
+        300)
+    assert (wl.arr_fix.shape[-1] > 0) == (case == "open-loop")
+    tn, ln, _ = topology(alg, 2, 2, 4)
+    args = (alg, 4, 2, 4, 300, wl, tn, ln)
+    assert (_kernel_jaxpr(*args, interpret=True)
+            == _kernel_jaxpr(*args, interpret=False))

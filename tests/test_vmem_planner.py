@@ -28,43 +28,35 @@ def test_buffer_table_matches_kernel_allocations():
     equals the block/scratch shape ``ops.run_events`` builds for the same
     parameters (the interpret-mode allocations the acceptance criterion
     points at)."""
-    for repr32 in (False, True):
-        t = vmem.buffer_table(repr32=repr32, **SHAPE)
-        tile, ev_chunk, T, N, K, P = (ARGS["tile"], ARGS["ev_chunk"],
-                                      ARGS["T"], ARGS["N"], ARGS["K"],
-                                      ARGS["P"])
-        # inputs: the in_specs block shapes
-        assert t["in.u1"][0] == (tile, ev_chunk)
-        assert t["in.locality"][0] == (tile, P * T)
-        assert t["in.cost_rows"][0] == (tile, P * N_COST_ROWS)
-        assert t["in.thread_node"][0] == (1, T)
-        assert t["in.lock_node"][0] == (1, K)
-        # outputs: the per-event latency stream, one i64 block vs an
-        # (hi, lo) i32 pair, same total bytes, double-buffered like the
-        # draws; no (tile, lat_samples) ring is priced
-        stream = tile * ev_chunk * 8 * vmem.PIPELINE_FACTOR
-        if repr32:
-            assert t["out.lat_stream.hi"][0] == (tile, ev_chunk)
-            assert (t["out.lat_stream.hi"][1] + t["out.lat_stream.lo"][1]
-                    == stream)
-            assert "out.lat_stream" not in t
-        else:
-            assert t["out.lat_stream"] == ((tile, ev_chunk), stream)
-        assert not any(LAT_SAMPLES in shape for shape, _ in t.values())
-        # scratch: semantic i32 + clock buffers
-        assert t["scr.tail0"] == ((tile, K), tile * K * 4)
-        assert t["scr.pc"] == ((tile, T), tile * T * 4)
-        ready = (t["scr.ready.hi"][1] + t["scr.ready.lo"][1] if repr32
-                 else t["scr.ready"][1])
-        assert ready == tile * T * 8
-        busy = (t["scr.busy.hi"][1] + t["scr.busy.lo"][1] if repr32
-                else t["scr.busy"][1])
-        assert busy == tile * N * 8
-        # the double-buffered event streams carry the pipeline factor
-        assert t["in.u1"][1] == tile * ev_chunk * 4 * vmem.PIPELINE_FACTOR
-        # and the plan total is exactly the sum of the table
-        plan = vmem.plan_vmem(repr32=repr32, **ARGS)
-        assert plan.total_bytes == sum(b for _, b in t.values())
+    t = vmem.buffer_table(**SHAPE)
+    tile, ev_chunk, T, N, K, P = (ARGS["tile"], ARGS["ev_chunk"],
+                                  ARGS["T"], ARGS["N"], ARGS["K"],
+                                  ARGS["P"])
+    # inputs: the in_specs block shapes
+    assert t["in.u1"][0] == (tile, ev_chunk)
+    assert t["in.locality"][0] == (tile, P * T)
+    assert t["in.cost_rows"][0] == (tile, P * N_COST_ROWS)
+    assert t["in.thread_node"][0] == (1, T)
+    assert t["in.lock_node"][0] == (1, K)
+    # outputs: the per-event latency stream, an (hi, lo) i32 pair,
+    # double-buffered like the draws; no (tile, lat_samples) ring is
+    # priced
+    stream = tile * ev_chunk * 8 * vmem.PIPELINE_FACTOR
+    assert t["out.lat_stream.hi"][0] == (tile, ev_chunk)
+    assert (t["out.lat_stream.hi"][1] + t["out.lat_stream.lo"][1]
+            == stream)
+    assert "out.lat_stream" not in t
+    assert not any(LAT_SAMPLES in shape for shape, _ in t.values())
+    # scratch: semantic i32 + clock buffers
+    assert t["scr.tail0"] == ((tile, K), tile * K * 4)
+    assert t["scr.pc"] == ((tile, T), tile * T * 4)
+    assert t["scr.ready.hi"][1] + t["scr.ready.lo"][1] == tile * T * 8
+    assert t["scr.busy.hi"][1] + t["scr.busy.lo"][1] == tile * N * 8
+    # the double-buffered event streams carry the pipeline factor
+    assert t["in.u1"][1] == tile * ev_chunk * 4 * vmem.PIPELINE_FACTOR
+    # and the plan total is exactly the sum of the table
+    plan = vmem.plan_vmem(**ARGS)
+    assert plan.total_bytes == sum(b for _, b in t.values())
 
 
 def test_plan_matches_measured_pallas_buffers(monkeypatch):
@@ -96,8 +88,8 @@ def test_plan_matches_measured_pallas_buffers(monkeypatch):
         [s.block_shape for s in captured["out_specs"]]
     assert [t[k][0] for k in names("scr.")] == \
         [tuple(s.shape) for s in captured["scratch_shapes"]]
-    # bytes = prod(shape) x 4 (all buffers are f32/i32 pairs under the
-    # native representation), x2 for the double-buffered event streams
+    # bytes = prod(shape) x 4 (every buffer is f32 or i32, clocks as hi/lo
+    # pairs), x2 for the double-buffered event streams
     for k, (shape, nbytes) in t.items():
         factor = (vmem.PIPELINE_FACTOR
                   if k in ("in.u1", "in.r2", "in.r3", "out.lat_stream.hi",
@@ -151,19 +143,10 @@ def test_open_loop_plan_matches_measured_pallas_buffers(monkeypatch):
         [tuple(s.shape) for s in captured["scratch_shapes"]]
 
 
-def test_plan_representations_cost_identical_bytes():
-    """hi/lo i32 pairs occupy exactly the bytes of the i64 buffers they
-    replace — switching representation must never change the footprint."""
-    a = vmem.plan_vmem(repr32=False, **ARGS)
-    b = vmem.plan_vmem(repr32=True, **ARGS)
-    assert a.total_bytes == b.total_bytes
-
-
 def test_plan_total_does_not_grow_with_lat_samples():
     """The ring is built outside the kernel: ``lat_samples`` changes no
     VMEM buffer, so the priced total is the same at any ring size."""
-    totals = {vmem.plan_vmem(repr32=repr32, **dict(ARGS, lat_samples=n))
-              .total_bytes for repr32 in (False, True)
+    totals = {vmem.plan_vmem(**dict(ARGS, lat_samples=n)).total_bytes
               for n in (64, 1024, LAT_SAMPLES, 4 * LAT_SAMPLES)}
     assert len(totals) == 1
 
@@ -173,13 +156,13 @@ def test_oversize_tile_auto_shrinks_deterministically():
     # ~11 KiB a tile row (the double-buffered draw and latency streams):
     # tile 64 needs ~700 KiB, so 256 KiB forces the 64 -> 32 -> 16 path
     budget = 256 * 2**10
-    p1 = vmem.plan_vmem(repr32=True, budget=budget, **kw)
-    p2 = vmem.plan_vmem(repr32=True, budget=budget, **kw)
+    p1 = vmem.plan_vmem(budget=budget, **kw)
+    p2 = vmem.plan_vmem(budget=budget, **kw)
     assert p1 == p2                       # deterministic
     assert p1.shrunk and p1.requested_tile == 64
     assert p1.tile < 64 and p1.total_bytes <= budget
     # halving: the next-larger tile would NOT have fit
-    over = vmem.plan_vmem(repr32=True, **dict(kw, tile=p1.tile * 2))
+    over = vmem.plan_vmem(**dict(kw, tile=p1.tile * 2))
     assert over.total_bytes > budget
     # the dict view benchmarks serialize
     d = p1.as_dict()
@@ -190,12 +173,12 @@ def test_impossible_budget_raises_actionable_error():
     # tile 1 needs 11,268 B at ev_chunk 256; the hint names ev_chunk, the
     # knob that sizes the streamed blocks
     with pytest.raises(ValueError, match="ev_chunk"):
-        vmem.plan_vmem(repr32=True, budget=10_000, **ARGS)
+        vmem.plan_vmem(budget=10_000, **ARGS)
     # bad arguments are real errors too
     with pytest.raises(ValueError, match="tile"):
-        vmem.plan_vmem(repr32=True, **dict(ARGS, tile=0))
+        vmem.plan_vmem(**dict(ARGS, tile=0))
     with pytest.raises(ValueError, match="budget"):
-        vmem.plan_vmem(repr32=True, budget=0, **ARGS)
+        vmem.plan_vmem(budget=0, **ARGS)
 
 
 def _replicas(alg="alock", ev=700, B=1):
@@ -224,8 +207,8 @@ def test_budgeted_run_shrinks_tile_and_stays_bitwise():
                run_events_ref("alock", 4, 2, 8, ev, wl, tn, ln,
                               lat_samples=lat_samples)]
         out = run_events("alock", 4, 2, 8, ev, wl, tn, ln, interpret=True,
-                         representation="i32pair", tile=8, ev_chunk=128,
-                         lat_samples=lat_samples, vmem_budget=budget)
+                         tile=8, ev_chunk=128, lat_samples=lat_samples,
+                         vmem_budget=budget)
     plan = vmem.last_plan()
     assert plan is not None and plan.shrunk
     assert plan.requested_tile == 6 and plan.tile == 1
@@ -274,10 +257,10 @@ def test_plan_for_run_minimizes_edge_padding():
                         **shape).tile == 6
     # budget pressure: 5 does not fit, one halving lands on 2 (which the
     # budget below is sized to fit exactly)
-    fit2 = vmem.plan_vmem(tile=2, ev_chunk=64, P=2, repr32=True,
+    fit2 = vmem.plan_vmem(tile=2, ev_chunk=64, P=2,
                           lat_samples=LAT_SAMPLES, **shape).total_bytes
-    p = plan_for_run(9, 2, 64, tile=8, interpret=True,
-                     representation="i32pair", vmem_budget=fit2, **shape)
+    p = plan_for_run(9, 2, 64, tile=8, interpret=True, vmem_budget=fit2,
+                     **shape)
     assert (p.requested_tile, p.tile, p.shrunk) == (5, 2, True)
 
 
@@ -300,7 +283,7 @@ def test_native_plan_respects_mosaic_block_rules():
     assert native(9, 300, ev_chunk=4096).ev_chunk == 300   # whole run
     assert native(9, 300, ev_chunk=100).ev_chunk == 128
     # budget pressure halves 64 -> 32 -> 16 -> 8 and stops at 8
-    at8 = vmem.plan_vmem(tile=8, ev_chunk=64, P=2, repr32=True,
+    at8 = vmem.plan_vmem(tile=8, ev_chunk=64, P=2,
                          lat_samples=LAT_SAMPLES, **shape).total_bytes
     p = native(64, 64, tile=64, vmem_budget=at8)
     assert (p.requested_tile, p.tile) == (64, 8)
