@@ -139,7 +139,8 @@ def main() -> None:
     ap.add_argument("--devices", type=int, default=None,
                     help="shard sweep buckets over this many JAX devices")
     ap.add_argument("--chunk", type=int, default=None,
-                    help="rows per device per dispatch (fixed-size chunks)")
+                    help="rows per device per dispatch unit (units coalesce "
+                         "into one kernel tile per device)")
     ap.add_argument("--zipf", type=float, default=0.0,
                     help="Zipf skew of within-node lock targets (fig5)")
     ap.add_argument("--scenario", action="append", default=[],

@@ -33,21 +33,22 @@ tiled across the Pallas grid). ``"auto"`` resolves per
 
 ``sweep(..., devices=, chunk=)`` turns on the sharded bucket layout: each
 bucket's flattened (workload x seed) axis is measured in dispatch *units*
-of ``chunk`` rows per device (``chunk * n_devices`` rows each), and the
-unit count is greedily decomposed into power-of-two **superchunks** —
-each superchunk is ONE dispatch of ``2**k * chunk * n_devices`` rows
-through a cached ``jax.shard_map`` runner (mesh axis ``"data"``). A
-bucket of ``u`` units therefore costs ``popcount(u)`` dispatches against
-an executable family of at most ``log2(u) + 1`` shapes, instead of ``u``
-serialized unit dispatches: on hosts where every dispatch pays a full
-serial event loop regardless of its replica-row count (vmap rows are
-nearly free), this is what keeps the sharded layout's events/sec at
-parity with the unsharded single-dispatch layout. The dispatch loop
-never blocks — every superchunk is issued before the first result is
+of ``chunk`` rows per device, and the units are coalesced into
+dispatches of one kernel tile per device (``dispatch_rows``): each
+dispatch is ONE call of a cached ``jax.shard_map`` runner (mesh axis
+``"data"``) carrying as many whole units as fit the tile that
+``ops.plan_for_run`` derives for the bucket's rows per device (``MAX_TILE``
+rows on the XLA backend), and never fewer than one. A kernel tile runs
+its rows as one serial event chain whatever their number, so a bucket
+whose rows per device fit one tile is one dispatch, one chain per chip;
+a larger one runs one dispatch per tile's worth of rows, as many chains
+as its tiles would in one call. A bucket's executable family is at most
+two shapes, the full tile and the trailing dispatch. The dispatch loop
+never blocks — every dispatch is issued before the first result is
 touched, chunk operand buffers are donated, and the host-side
-aggregation of finished superchunks overlaps the still-in-flight ones.
+conversion of finished dispatches overlaps the still-in-flight ones.
 Edge padding is bounded below the mesh width (rows are only rounded up
-to a device-count multiple); the final superchunk is trimmed to the true
+to a device-count multiple); the final dispatch is trimmed to the true
 remaining rows rather than padded to a full unit.
 ``exec_stats()`` exposes the dispatch and shape-key counters and the
 kernel's VMEM plans. ``repro.experiments.ExecOptions`` carries (backend,
@@ -70,10 +71,11 @@ call carries the same ``sweep`` ordinal in its metadata:
     ``rows``, ``phases``);
   * ``batch.dispatch`` — the device put of the operands, the VMEM plan
     and the jitted call up to its return: the enqueue, plus any compile
-    (per bucket, or per superchunk when sharded; ``bucket``, ``rows``,
+    (per bucket, or per dispatch when sharded; ``bucket``, ``rows``,
     ``bytes`` put, ``new_shape``, and the bucket's ``lock_lanes`` K,
-    ``draw_streams`` 3 or 4 and ``zipf_ranks`` K/N; on the Pallas
-    backend also each kernel call's ``tile``, ``tiles`` and
+    ``draw_streams`` 3 or 4 and ``zipf_ranks`` K/N; when sharded the
+    ``units`` of ``chunk`` rows coalesced into the dispatch; on the
+    Pallas backend also each kernel call's ``tile``, ``tiles`` and
     ``padded_rows``, per shard when sharded);
   * ``batch.fetch`` — the wait for the device and the copy of the outputs
     back to the host (``bucket``, ``bytes`` fetched);
@@ -191,11 +193,11 @@ def _bucket_runner(key, n_phases: int, backend: str, mesh: Mesh):
 
     The wrapped function maps the flattened replica axis onto the mesh's
     ``data`` axis; inside each shard the local block runs through the
-    selected backend. One runner serves every superchunk size — jit keys
-    executables by input shape, so the power-of-two superchunk family
-    upstream compiles at most O(log units) shapes per runner, reused
-    across superchunks and buckets (``_note_call`` mirrors this by
-    including the superchunk row count in the compile-counter key). The
+    selected backend. One runner serves every dispatch size — jit keys
+    executables by input shape, so a bucket's full-tile and trailing
+    dispatches compile at most two shapes per runner, reused across
+    buckets (``_note_call`` mirrors this by including the dispatch row
+    count in the compile-counter key). The
     workload-operand arguments are donated: each dispatch transfers fresh
     host slices, so their device buffers are dead on return and the
     runtime may reuse them for the outputs; the broadcast
@@ -360,6 +362,29 @@ def _draw_shape(alg: str, N: int, K: int) -> dict:
             "zipf_ranks": K // N}
 
 
+def dispatch_rows(B: int, D: int, chunk: int, ceiling: int) -> list[int]:
+    """The rows of each sharded dispatch of a bucket of ``B`` rows over
+    ``D`` devices, in units of ``chunk`` rows per device.
+
+    A dispatch carries as many whole units as fit ``ceiling`` rows per
+    device (one kernel tile), and never fewer than one unit; a bucket
+    whose rows per device fit the ceiling is one dispatch. Rows are
+    padded to a multiple of ``D`` only, and the last dispatch is trimmed
+    to the rows that remain.
+
+    >>> dispatch_rows(96, 4, 8, 24), dispatch_rows(640, 4, 8, 80)
+    ([96], [320, 320])
+    >>> dispatch_rows(10, 4, 1, 2)
+    [8, 4]
+    """
+    if chunk < 1:
+        raise ValueError(f"chunk must be >= 1, got {chunk}")
+    per = -(-B // D)                      # rows per device, padded
+    fit = per if per <= ceiling else max(1, ceiling // chunk) * chunk
+    step, Bp = fit * D, per * D
+    return [min(step, Bp - off) for off in range(0, Bp, step)]
+
+
 def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
                  backend: str, devices, chunk, sweep_id: int,
                  bucket: int):
@@ -369,12 +394,13 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
     per-phase cost rows and budgets included. Unsharded (devices/chunk
     both None): one dispatch for the whole bucket — the XLA leg is the
     original ``_run_events_batch`` oracle. Sharded: the row axis is
-    measured in units of ``chunk`` rows per device, the unit count is
-    decomposed into greedy power-of-two superchunks, and each superchunk
-    is one non-blocking dispatch over the device mesh (see the module
-    docstring); the fetch converts finished superchunks while later
-    ones are still in flight and only the final concatenate forces the
-    last dispatch. ``sweep_id`` and ``bucket`` label the profiler spans.
+    measured in units of ``chunk`` rows per device, the units are
+    coalesced into dispatches of one kernel tile per device
+    (``dispatch_rows``), and each is one non-blocking dispatch over the
+    device mesh (see the module docstring); the fetch converts finished
+    dispatches while later ones are still in flight and only the final
+    concatenate forces the last dispatch. ``sweep_id`` and ``bucket``
+    label the profiler spans.
     """
     alg, T, N, K, n_events, R = key
     B = wl.seed.shape[0]
@@ -386,6 +412,7 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
             with jax.enable_x64(True):
                 wj = WorkloadOperands(*(jnp.asarray(a) for a in wl))
                 if backend == "pallas":
+                    from repro.kernels.event_loop import vmem
                     from repro.kernels.event_loop.ops import (
                         plan_for_run, run_events_jit)
                     # re-record the VMEM plan per dispatch: planning inside
@@ -394,6 +421,7 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
                     plan = plan_for_run(B, n_phases, n_events, T, N, K, R=R,
                                         hl=alg == "hlock",
                                         rw=alg == "alock-rw")
+                    vmem.note_plan(plan)
                     span.set_metadata(tile=plan.tile, tiles=plan.tiles,
                                       padded_rows=plan.padded_rows)
                     out = run_events_jit(alg, T, N, K, n_events, wj,
@@ -407,28 +435,26 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
                              bytes=_nbytes(out)):
             return tuple(np.asarray(o) for o in out)
 
+    from repro.kernels.event_loop import ops, vmem
     devs = list(devices) if devices is not None else jax.devices()
     mesh = Mesh(np.asarray(devs), ("data",))
     D = len(devs)
-    rows = int(chunk) if chunk is not None else math.ceil(B / D)
-    if rows < 1:
-        raise ValueError(f"chunk must be >= 1, got {chunk}")
-    step = rows * D                       # rows per dispatch unit
+    unit = int(chunk) if chunk is not None else math.ceil(B / D)
+
+    def plan(rows):
+        # the kernel's plan for `rows` replicas on one shard
+        return ops.plan_for_run(rows, n_phases, n_events, T, N, K, R=R,
+                                hl=alg == "hlock", rw=alg == "alock-rw")
+
+    # one kernel tile per device per dispatch: the tile the kernel derives
+    # for the bucket's rows per device, VMEM shrink included
+    ceiling = (plan(math.ceil(B / D)).tile if backend == "pallas"
+               else ops.MAX_TILE)
+    sizes = dispatch_rows(B, D, unit, ceiling)
     # pad only to the mesh width: shard_map needs each dispatch's row
     # count divisible by D, nothing more — per-replica edge padding is
-    # dead serial kernel work on every device, so the final superchunk is
-    # trimmed to the true remaining rows instead of a full unit
-    Bp = math.ceil(B / D) * D
-    n_units = math.ceil(Bp / step)
-    # greedy power-of-two decomposition of the unit count: one dispatch
-    # per superchunk (popcount(n_units) total), executable family bounded
-    # by log2(n_units) + 1 full-unit shapes plus at most one trimmed
-    # trailing shape
-    sizes, rem = [], n_units
-    while rem:
-        p = 1 << (rem.bit_length() - 1)
-        sizes.append(p)
-        rem -= p
+    # dead serial kernel work on every device
+    Bp = sum(sizes)
     leaves = [_pad_rows(np.asarray(a), Bp - B) for a in wl]
     tn = np.asarray(thread_node)
     ln = np.asarray(lock_node)
@@ -442,31 +468,29 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
         off = 0
-        for sz in sizes:
-            nrows = min(sz * step, Bp - off)   # multiple of D by induction
+        for nrows in sizes:
             sl = slice(off, off + nrows)
             args = [a[sl] for a in leaves]
             with TraceAnnotation(SPAN_DISPATCH, sweep=sweep_id,
                                  bucket=bucket, rows=nrows,
+                                 units=math.ceil(nrows // D / unit),
                                  bytes=_nbytes(args),
                                  **_draw_shape(alg, N, K)) as span:
                 if backend == "pallas":
                     # each shard's kernel sees nrows/D replicas (same
                     # trace-time-only caveat as the unsharded branch above)
-                    from repro.kernels.event_loop.ops import plan_for_run
-                    plan = plan_for_run(nrows // D, n_phases, n_events, T,
-                                        N, K, R=R, hl=alg == "hlock",
-                                        rw=alg == "alock-rw")
-                    span.set_metadata(tile=plan.tile, tiles=plan.tiles,
-                                      padded_rows=plan.padded_rows)
+                    p = plan(nrows // D)
+                    vmem.note_plan(p)
+                    span.set_metadata(tile=p.tile, tiles=p.tiles,
+                                      padded_rows=p.padded_rows)
                 # async: the call returns device futures — every
-                # superchunk is issued before any result is forced below
+                # dispatch is issued before any result is forced below
                 outs.append(runner(*args, tn, ln))
                 span.set_metadata(new_shape=_note_call((ck, nrows)))
             off += nrows
     # the fetch is the only blocking point: np.asarray forces the
-    # superchunks in dispatch order, so converting an early (large) one
-    # overlaps the later in-flight dispatches
+    # dispatches in order, so converting an early one overlaps the later
+    # in-flight dispatches
     with TraceAnnotation(SPAN_FETCH, sweep=sweep_id, bucket=bucket,
                          bytes=sum(_nbytes(o) for o in outs)):
         return tuple(np.concatenate([np.asarray(o[j]) for o in outs])[:B]
@@ -488,11 +512,11 @@ def sweep(configs: Sequence[SimConfig | Workload], n_seeds: int = 1,
       (mesh axis "data"); None with chunk=None keeps the single-dispatch
       layout.
     chunk: rows per device per dispatch *unit*. Units are coalesced into
-      greedy power-of-two superchunks — one dispatch each — so an
-      oversized bucket costs popcount(units) dispatches against at most
-      log2(units)+1 executable shapes instead of one serialized dispatch
-      per unit; chunk=None with devices set derives one even chunk per
-      device (a single superchunk).
+      dispatches of one kernel tile per device (``dispatch_rows``), so a
+      bucket whose rows per device fit one tile is one dispatch, and a
+      bucket costs at most two executable shapes; chunk=None with
+      devices set derives one even chunk per device (a single
+      dispatch).
 
     Returns BatchResults parallel to ``configs`` (duplicates are simulated
     twice — dedupe upstream if the grid overlaps; ``experiments.Experiment``

@@ -22,8 +22,9 @@ class ExecOptions:
       (``sim.resolve_backend`` semantics).
     devices: shard sweep buckets over the first N JAX devices (mesh axis
       "data"); None keeps the single-dispatch layout.
-    chunk: rows per device per dispatch (fixed-size chunks pin the
-      executable shape; see ``core/batch.py``).
+    chunk: rows per device per dispatch *unit*; a dispatch coalesces as
+      many units as fit one kernel tile per device
+      (``core/batch.py``, ``dispatch_rows``).
     """
     backend: str = "auto"
     devices: int | None = None

@@ -176,7 +176,7 @@ def plan_for_run(B, P, n_events, T, N, K, *, R: int = 0,
                  device_kind: str | None = None,
                  hl: bool = False, rw: bool = False) -> vmem.VmemPlan:
     """Resolve the budget, derive and clamp (tile, ev_chunk)
-    exactly like ``run_events`` will, and record the resulting VMEM plan.
+    exactly like ``run_events`` will, and return the resulting VMEM plan.
 
     The tile is derived from B (module docstring, "Replica tile"). The
     ceiling is ``MAX_TILE`` rows, or ``tile`` where given, rounded up to
@@ -189,9 +189,12 @@ def plan_for_run(B, P, n_events, T, N, K, *, R: int = 0,
 
     This is the *single* clamping+planning code path — ``run_events`` goes
     through it at trace time, and ``batch._exec_bucket`` calls it per
-    dispatch so ``exec_stats()["vmem_plans"]`` stays populated even when
-    the jitted kernel call is a cache hit (planning is python-level and
-    does not re-run inside a cached executable).
+    dispatch, and to size its sharded dispatches. Planning records
+    nothing: ``run_events`` and each dispatch of ``batch._exec_bucket``
+    note the plan they run (``vmem.note_plan``), so
+    ``exec_stats()["vmem_plans"]`` stays populated even when the jitted
+    kernel call is a cache hit (planning is python-level and does not
+    re-run inside a cached executable).
 
     >>> [plan_for_run(b, 1, 512, 80, 10, 100, interpret=False,
     ...               device_kind="TPU v5 lite").tile
@@ -228,10 +231,8 @@ def plan_for_run(B, P, n_events, T, N, K, *, R: int = 0,
                  lat_samples=lat_samples, R=R, hl=hl, rw=rw,
                  budget=vmem_budget, align=align)
     fit = vmem.plan_vmem(tile=cap, **shape).tile
-    plan = dataclasses.replace(vmem.plan_vmem(tile=split(fit), **shape),
+    return dataclasses.replace(vmem.plan_vmem(tile=split(fit), **shape),
                                requested_tile=split(cap), rows=B)
-    vmem.note_plan(plan)
-    return plan
 
 
 def latency_ring(stream, lat_n, lat_samples: int):
@@ -313,6 +314,7 @@ def run_events_pairs(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
                         ev_chunk=ev_chunk, interpret=interpret,
                         lat_samples=lat_samples, vmem_budget=vmem_budget,
                         device_kind=device_kind, hl=is_hl, rw=is_rw)
+    vmem.note_plan(plan)
     tile, ev_chunk, pad_b = plan.tile, plan.ev_chunk, plan.padded_rows
     pad_e = -n_events % ev_chunk
 

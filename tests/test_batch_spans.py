@@ -4,8 +4,8 @@ A small sweep run under ``jax.profiler.trace`` and read back with
 ``ProfileData`` yields the six ``batch.*`` spans, nested as the module
 docstring says and carrying their metadata, on the unsharded path and on
 the sharded path over 4 virtual CPU devices (one ``batch.dispatch`` per
-superchunk). Tracing changes no result: a sweep under the profiler
-equals the same sweep without it, bit for bit.
+kernel tile's worth of chunk units). Tracing changes no result: a sweep
+under the profiler equals the same sweep without it, bit for bit.
 """
 from __future__ import annotations
 
@@ -73,7 +73,8 @@ def check_call(spans, n_configs, n_seeds, n_events, bucket_rows,
                devices=1):
     """One call's spans: their nesting, order and metadata. Returns the
     call's ordinal and its dispatch spans. ``devices``: the chips each
-    dispatch is sharded over, one kernel call each."""
+    dispatch is sharded over, one kernel call each (above 1, the sharded
+    path, whose dispatches also carry their ``units``)."""
     (sw,) = [s for s in spans if s[0] == batch.SPAN_SWEEP]
     sid = sw[3]["sweep"]
     assert sw[3] == {"sweep": sid, "configs": n_configs, "seeds": n_seeds,
@@ -102,7 +103,8 @@ def check_call(spans, n_configs, n_seeds, n_events, bucket_rows,
             assert set(d[3]) == {"sweep", "bucket", "rows", "bytes",
                                  "new_shape", "lock_lanes", "draw_streams",
                                  "zipf_ranks", "tile", "tiles",
-                                 "padded_rows"}
+                                 "padded_rows"} | (
+                                     {"units"} if devices > 1 else set())
             # every bucket here: 2 nodes, 4 locks, exclusive machines
             assert (d[3]["lock_lanes"], d[3]["draw_streams"],
                     d[3]["zipf_ranks"]) == (4, 3, 2)
@@ -162,13 +164,15 @@ plain = batch.sweep(ws, 8, 300, backend="pallas", devices=jax.devices(),
 sid, dispatches = t.check_call(spans, 3, 8, 300, [24], devices=4)
 print(json.dumps({{"devices": len(jax.devices()),
                   "same": t.same_results(plain, res),
-                  "dispatch_rows": [d[3]["rows"] for d in dispatches[0]]}}))
+                  "dispatch_rows": [d[3]["rows"] for d in dispatches[0]],
+                  "units": [d[3]["units"] for d in dispatches[0]]}}))
 """
 
 
-def test_sharded_sweep_has_one_dispatch_span_per_superchunk():
+def test_sharded_sweep_has_one_dispatch_span_per_tile():
     """24 rows over 4 devices at ``chunk`` 2: units of 8 rows, 3 units,
-    superchunks of 2 and 1 units, so two dispatch spans."""
+    6 rows a device, which fit one kernel tile, so one dispatch span
+    that carries all 3 units."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=str(ROOT / "src"))
@@ -177,4 +181,5 @@ def test_sharded_sweep_has_one_dispatch_span_per_superchunk():
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
     out = json.loads(p.stdout.strip().splitlines()[-1])
-    assert out == {"devices": 4, "same": True, "dispatch_rows": [16, 8]}
+    assert out == {"devices": 4, "same": True, "dispatch_rows": [24],
+                   "units": [3]}

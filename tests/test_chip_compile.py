@@ -112,12 +112,12 @@ def test_fig4_bucket_compiles(one_chip):
     assert not plan.shrunk
 
 
-def test_sharded_16_row_shard_compiles(one_chip):
-    """What each chip's kernel runs in the larger superchunk of the
-    4-chip fig5 sweep: 16 rows of one bucket, one tile of 16."""
-    ws = [w for w in fig5_workloads() if w.alg == "alock"][:2]
+def test_sharded_24_row_shard_compiles(one_chip):
+    """What each chip's kernel runs in the 4-chip fig5 sweep: 24 rows of
+    one bucket (its 3 chunk units in one dispatch), one tile of 24."""
+    ws = [w for w in fig5_workloads() if w.alg == "alock"]
     plan = _compile(one_chip, ws, n_seeds=8)
-    assert plan.tile == 16
+    assert plan.tile == 24 and plan.tiles == 1
     assert not plan.shrunk
 
 

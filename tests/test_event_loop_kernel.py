@@ -132,18 +132,18 @@ def test_sweep_pallas_backend_matches_xla():
 
 
 def test_sweep_chunked_matches_unsharded_and_counts_dispatches():
-    """A bucket larger than the chunk spills into power-of-two superchunk
-    dispatches of one shared runner; results stay bitwise-equal to the
-    one-dispatch layout."""
+    """A bucket of several chunk units whose rows fit one kernel tile is
+    coalesced into one dispatch of the shared runner; results stay
+    bitwise-equal to the unsharded layout."""
     cfgs = [SimConfig("alock", 2, 2, 8, l, (2, 3), seed=s, zipf_s=z)
             for l, s, z in ((0.9, 7, 0.0), (0.5, 1, 1.2), (0.95, 3, 0.0))]
     base = batch.sweep(cfgs, n_seeds=2, n_events=EV)      # bucket B = 6
     batch.reset_exec_stats()
     ch = batch.sweep(cfgs, n_seeds=2, n_events=EV, chunk=2)
     st = batch.exec_stats()
-    # 3 units of 2 rows coalesce into superchunks [2, 1]: one dispatch
-    # of 4 rows + one of 2 rows (popcount(3)), not 3 unit dispatches
-    assert st["dispatches"] == 2
+    # 3 units of 2 rows fit one tile: one dispatch of 6 rows, not one
+    # per unit
+    assert st["dispatches"] == 1
     for b, c in zip(base, ch):
         np.testing.assert_array_equal(b.ops, c.ops)
         np.testing.assert_array_equal(b.sim_ns, c.sim_ns)
@@ -153,13 +153,13 @@ def test_sweep_chunked_matches_unsharded_and_counts_dispatches():
     batch.reset_exec_stats()
     batch.sweep(cfgs, n_seeds=2, n_events=EV, chunk=2)
     st2 = batch.exec_stats()
-    assert st2["dispatches"] == 2 and st2["compiles"] == 0
+    assert st2["dispatches"] == 1 and st2["compiles"] == 0
 
 
 def test_sweep_three_bucket_ragged_counts_and_bitwise():
     """Dispatch/compile accounting across a 3-bucket ragged sweep under the
-    pipelined path: per bucket (6, 4, 2 rows at chunk=2) the superchunk
-    decomposition is [4, 2] / [4] / [2] rows — 4 dispatches, one compile
+    pipelined path: per bucket (6, 4, 2 rows at chunk=1) every bucket's
+    units fit one tile, so one dispatch each — 3 dispatches, one compile
     per distinct (runner, rows) shape, zero compiles on the rerun — and
     results stay bitwise-equal to the unsharded layout."""
     ev = EV - 100     # own shape keys: no executable reuse across tests
@@ -173,12 +173,12 @@ def test_sweep_three_bucket_ragged_counts_and_bitwise():
     batch.reset_exec_stats()
     ch = batch.sweep(cfgs, n_seeds=2, n_events=ev, chunk=1)
     st = batch.exec_stats()
-    # alock bucket: 6 rows -> units [4, 2] -> 2 dispatches; mcs: 4 rows
+    # alock bucket: 6 units of 1 row -> [6] -> 1 dispatch; mcs: 4 rows
     # -> [4] -> 1; spinlock: 2 rows -> [2] -> 1
-    assert st["dispatches"] == 4
+    assert st["dispatches"] == 3
     # each bucket runner compiles once per distinct row count it saw:
-    # alock {4, 2}, mcs {4}, spinlock {2} -> 4 executables
-    assert st["compiles"] == 4
+    # alock {6}, mcs {4}, spinlock {2} -> 3 executables
+    assert st["compiles"] == 3
     for b, c in zip(base, ch):
         np.testing.assert_array_equal(b.lat_ns, c.lat_ns)
         np.testing.assert_array_equal(b.ops, c.ops)
@@ -186,7 +186,65 @@ def test_sweep_three_bucket_ragged_counts_and_bitwise():
     batch.reset_exec_stats()
     batch.sweep(cfgs, n_seeds=2, n_events=ev, chunk=1)
     st2 = batch.exec_stats()
-    assert st2["dispatches"] == 4 and st2["compiles"] == 0
+    assert st2["dispatches"] == 3 and st2["compiles"] == 0
+
+
+@pytest.mark.parametrize("B, D, chunk, ceiling, rows", [
+    (96, 4, 8, 24, [96]),            # fig5-grid-x4: 3 units, one tile a chip
+    (640, 4, 8, 80, [320, 320]),     # over one tile a device: full tiles
+    (256, 4, 32, 24, [128, 128]),    # chunk above the ceiling: one unit each
+    (97, 4, 8, 24, [96, 4]),         # B padded to D, the trailing trimmed
+    (10, 4, 1, 2, [8, 4]),
+    (640, 4, 16, 75, [256, 256, 128]),   # ceiling rounded down to a unit
+    (8, 4, 8, 24, [8]),              # less than one unit
+])
+def test_dispatch_rows(B, D, chunk, ceiling, rows):
+    got = batch.dispatch_rows(B, D, chunk, ceiling)
+    assert got == rows
+    assert all(r % D == 0 for r in got) and sum(got) == -(-B // D) * D
+
+
+def test_dispatch_rows_under_a_vmem_shrunk_tile():
+    """A VMEM budget that halves the x4 cell's 24-row tile to 8 lowers the
+    ceiling with it: one dispatch per unit of 8 rows a device."""
+    from repro.kernels.event_loop.ops import plan_for_run
+    plan = plan_for_run(24, 1, 150_000, 80, 10, 100, interpret=False,
+                        device_kind="TPU v5 lite", vmem_budget=3 << 20)
+    assert plan.shrunk and plan.tile == 8
+    assert batch.dispatch_rows(96, 4, 8, plan.tile) == [32, 32, 32]
+    with pytest.raises(ValueError, match="chunk must be >= 1"):
+        batch.dispatch_rows(96, 4, 0, 24)
+
+
+@pytest.mark.parametrize("backend, rows", [("xla", [4, 2]),
+                                           ("pallas", [3, 3])])
+def test_sweep_dispatches_one_tile_at_a_time(monkeypatch, backend, rows):
+    """Units that overflow the tile ceiling spill into one dispatch per
+    tile's worth of rows: at a ceiling of 4 rows, the XLA backend takes
+    4 + 2 of 6 rows, and the kernel's tile derived for 6 rows (two even
+    tiles of 3) takes 3 + 3. Results stay bitwise-equal to the unsharded
+    layout."""
+    from repro.kernels.event_loop import ops
+    ev = 300
+    cfgs = [SimConfig("alock", 2, 2, 8, l, (2, 3), seed=s)
+            for l, s in ((0.9, 11), (0.6, 12), (1.0, 13))]
+    base = batch.sweep(cfgs, n_seeds=2, n_events=ev, backend=backend)
+    monkeypatch.setattr(ops, "MAX_TILE", 4)
+    batch.reset_exec_stats()
+    ch = batch.sweep(cfgs, n_seeds=2, n_events=ev, backend=backend,
+                     chunk=1)
+    st = batch.exec_stats()
+    # one executable per distinct dispatch row count
+    assert st["dispatches"] == len(rows)
+    assert st["compiles"] == len(set(rows))
+    # the kernel records the plans it runs, not the 6-row ceiling plan
+    assert [p["rows"] for p in st["vmem_plans"]] == (
+        [3] if backend == "pallas" else [])
+    for b, c in zip(base, ch):
+        np.testing.assert_array_equal(b.ops, c.ops)
+        np.testing.assert_array_equal(b.sim_ns, c.sim_ns)
+        np.testing.assert_array_equal(b.lat_ns, c.lat_ns)
+        np.testing.assert_array_equal(b.per_thread_ops, c.per_thread_ops)
 
 
 def test_sweep_devices_path_matches_unsharded():
