@@ -423,7 +423,9 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
                                         rw=alg == "alock-rw")
                     vmem.note_plan(plan)
                     span.set_metadata(tile=plan.tile, tiles=plan.tiles,
-                                      padded_rows=plan.padded_rows)
+                                      padded_rows=plan.padded_rows,
+                                      lock_layout=plan.lock_layout,
+                                      lock_slots=plan.lock_slots)
                     out = run_events_jit(alg, T, N, K, n_events, wj,
                                          thread_node, lock_node)
                 else:
@@ -482,7 +484,9 @@ def _exec_bucket(key, thread_node, lock_node, wl: WorkloadOperands,
                     p = plan(nrows // D)
                     vmem.note_plan(p)
                     span.set_metadata(tile=p.tile, tiles=p.tiles,
-                                      padded_rows=p.padded_rows)
+                                      padded_rows=p.padded_rows,
+                                      lock_layout=p.lock_layout,
+                                      lock_slots=p.lock_slots)
                 # async: the call returns device futures — every
                 # dispatch is issued before any result is forced below
                 outs.append(runner(*args, tn, ln))

@@ -744,8 +744,8 @@ def topology(alg: str, n_nodes: int, threads_per_node: int, n_locks: int,
         raise ValueError(
             f"locks must partition evenly across nodes: n_locks={K} is not "
             f"a multiple of n_nodes={N} (got (n_locks, n_nodes)=({K}, {N}))")
-    thread_node = jnp.asarray([t // threads_per_node for t in range(T)], I32)
-    lock_node = jnp.asarray([k // (K // N) for k in range(K)], I32)
+    thread_node = jnp.arange(T, dtype=I32) // threads_per_node
+    lock_node = jnp.arange(K, dtype=I32) // (K // N)
     return thread_node, lock_node, cm.cost_rows(alg, N, threads_per_node)
 
 

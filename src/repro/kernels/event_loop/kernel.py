@@ -42,6 +42,38 @@ Clock representation
   stream outside the kernel. The ring-overflow tests hold it identical
   to the XLA engine.
 
+Lock layout
+  The lock words (tails, victim, alock-rw reader count) sit on L lanes,
+  the width of the ``s_t0`` scratch, and a thread reaches its lock's
+  lane through its ``s_tgt`` descriptor. ``vmem.lock_layout`` derives
+  the layout from (K, T); no caller sets it.
+
+  ``direct`` (K fits S = T rounded up to 128 lanes): L = K, the lane is
+  the lock id, and ``s_tgt`` holds the target lock. This is the dense
+  layout of ``sim.Sem``.
+
+  ``table`` (K above S): L = S slots, each holding a lock id
+  (``s_sid``, -1 when free), a reference count (``s_cnt``) and that
+  lock's words. ``s_tgt`` holds the thread's slot and ``s_lnd`` its
+  lock's node (the node the draw chose). A thread claims a slot as it
+  leaves NCS with its new target: it joins the live slot holding that
+  id, else takes the lowest free slot (one always exists: at most T - 1
+  other threads hold a slot). It releases the slot on the step that
+  returns it to NCS; the slot whose count reaches 0 resets to the idle
+  words and is freed. The table is bit-identical to the dense layout:
+  - an idle lock's words are the initial ones: a cohort tail or the
+    spinlock/MCS word names a thread that still holds the lock's slot
+    (it is cleared by that thread's solo release), and the reader count
+    is the readers between RD_TRY and RD_REL, each holding the slot; so
+    a lock's tails and count are 0 whenever its count of holders is 0,
+    as a fresh slot's are;
+  - the victim word is the one word an idle lock may hold non-zero, but
+    it is read only in PET_WAIT(_R), after the same thread wrote it in
+    SET_VICTIM(_R) within the same holding of the slot, so a reset
+    victim cannot be told from a stale one;
+  - every read and write of a lock word is gated by a PC that holds the
+    slot, so which slot a lock occupies is never observed.
+
 Randomness + workload operands
   The XLA loop draws from ``jax.random.fold_in(key, i)`` per event. The
   raw draws depend only on (seed, i) — never on simulation state — so
@@ -133,7 +165,8 @@ def _is_never(v):
 
 
 def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
-                      n_events: int, ev_chunk: int, R: int = 0):
+                      n_events: int, ev_chunk: int, R: int = 0,
+                      table: bool = False):
     """One (replica_tile, event_chunk) grid step.
 
     ``refs`` arrive flat from ``pl.pallas_call`` — 12 inputs (plus the
@@ -149,7 +182,9 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
     s_t0/s_t1 are the two cohort tails for alock (and its hlock/alock-rw
     variants); for mcs/spinlock s_t0 is the lock word and s_t1/s_vic stay
     zero (those PCs are unreachable). alock-rw adds an s_word scratch
-    holding per-lock reader counts.
+    holding per-lock reader counts. ``table`` selects the active-lock
+    table (module docstring, "Lock layout"): no lock_node input, and the
+    slot ids, counts and each thread's lock node as the last scratch.
     """
     is_hl = alg == "hlock"
     is_rw = alg == "alock-rw"
@@ -167,9 +202,11 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
     if is_rw:
         readf_ref = refs[pos]               # per-phase read probabilities
         pos += 1
-    (actp_ref, binit_ref, costs_ref, nmult_ref, tn_ref,
-     ln_ref) = refs[pos:pos + 6]
-    pos += 6
+    (actp_ref, binit_ref, costs_ref, nmult_ref, tn_ref) = refs[pos:pos + 5]
+    pos += 5
+    if not table:
+        ln_ref = refs[pos]                  # per-lock node ids
+        pos += 1
     if is_hl:
         rack_ref = refs[pos]                # per-node rack ids
         pos += 1
@@ -194,6 +231,8 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
     opst_refs = scr[pos + 4:pos + 6]
     if R > 0:
         s_curreq, s_arrptr, s_qlen = scr[pos + 6:pos + 9]
+    if table:
+        s_sid, s_cnt, s_lnd = scr[-3:]      # slot ids/counts, lock nodes
 
     is_alock = alg in ("alock", "hlock", "alock-rw")
     is_spin = alg == "spinlock"
@@ -210,6 +249,9 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             zrefs = zrefs + (s_word,)
         if R > 0:
             zrefs = zrefs + (rstat_ref, s_arrptr, s_qlen)
+        if table:
+            zrefs = zrefs + (s_cnt, s_lnd)
+            s_sid[...] = jnp.full(s_sid.shape, -1, I32)
         for ref in zrefs:
             ref[...] = jnp.zeros(ref.shape, ref.dtype)
         s_pc[...] = jnp.full((tile, T), mc.NCS, I32)
@@ -237,7 +279,9 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
     cstp = costs_ref[...].astype(I32)               # (tile, P*8)
     nmp = nmult_ref[...]                            # (tile, P*N) f32
     tn = jnp.broadcast_to(tn_ref[...].astype(I32), (tile, T))
-    ln = jnp.broadcast_to(ln_ref[...].astype(I32), (tile, K))
+    L = s_t0.shape[1]                               # lock lanes
+    if not table:
+        ln = jnp.broadcast_to(ln_ref[...].astype(I32), (tile, K))
     if is_rw:
         readfp = readf_ref[...]                     # (tile, P*T) f32
     if is_hl:
@@ -252,7 +296,7 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         rio = _iota((tile, R), 1)
 
     tids = _iota((tile, T), 1)
-    kio = _iota((tile, K), 1)
+    kio = _iota((tile, L), 1)
     nio = _iota((tile, N), 1)
     pio = _iota((tile, P), 1)
     # event e's draw is read from an aligned `win`-lane window of the
@@ -323,8 +367,14 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         # reader counts ride at the tail of the carry so every existing
         # unpack position stays fixed for the other algorithms
         state = state + (s_word[...],)
+    if table:
+        state = state + (s_sid[...], s_cnt[...], s_lnd[...])
 
     def step(e, st):
+        if table:
+            tab_old = st[-3:]
+            sid, cnt, lnd = tab_old
+            st = st[:-3]
         if is_rw:
             st_wrd = st[-1]
             wrd = st_wrd
@@ -401,6 +451,9 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         pv = gat_t(prv, tid)
         ohK = kio == tg[:, None]
         mynode = gat_t(tn, tid)
+        if table:
+            # tg is the thread's slot; its lock's node rides beside it
+            lnode = gat_t(lnd, tid)
 
         # -- workload draw (precomputed stream; NCS branch consumes it) ----
         u1e = draw(u1_ref, e)
@@ -431,6 +484,13 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
             rf_t = jnp.sum(jnp.where(ohT, rf_row, np.float32(0)), axis=1,
                            dtype=jnp.float32)
             new_r = u4e < rf_t
+        if table:
+            # claim (NCS): the live slot holding new_t, else the lowest
+            # free one, as one min over the lanes (free slots hold -1)
+            pick = jnp.min(jnp.where(sid == new_t[:, None], kio,
+                                     jnp.where(sid < _I(0), kio + _I(L),
+                                               _I(2 * L))), axis=1)
+            new_s = jnp.where(pick >= _I(L), pick - _I(L), pick)
 
         if R > 0:
             live = jnp.logical_not(_is_never(now))
@@ -547,7 +607,14 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         bud_tid_m = is_ncs | swap_bud | (is_pwr & can)
         bud = jnp.where(bud_tid_m[:, None] & ohT, bud_tid_val[:, None], bud)
         bud = jnp.where(is_ps[:, None] & oh_succ, newb[:, None], bud)
-        tgt = jnp.where(is_ncs[:, None] & ohT, new_t[:, None], tgt)
+        if table:
+            claim = is_ncs[:, None] & (kio == new_s[:, None])
+            cnt = cnt + jnp.where(claim, _I(1), _I(0))
+            sid = jnp.where(claim, new_t[:, None], sid)
+            lnd = jnp.where(is_ncs[:, None] & ohT, node_w[:, None], lnd)
+            tgt = jnp.where(is_ncs[:, None] & ohT, new_s[:, None], tgt)
+        else:
+            tgt = jnp.where(is_ncs[:, None] & ohT, new_t[:, None], tgt)
         coh = jnp.where(is_ncs[:, None] & ohT, new_c[:, None], coh)
 
         # -- next PC (the lax.switch, as one select over PC classes) -------
@@ -587,6 +654,22 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
                         jnp.where(wdv == 0, _I(mc.CS), _I(mc.WR_DRAIN))]
         new_pc = _select(pc_conds, pc_vals, p).astype(I32)
         pc = jnp.where(ohT, new_pc[:, None], pc)
+        if table:
+            # release: the step that returns the thread to NCS drops its
+            # slot; the last holder resets it to the idle words and frees
+            # it (module docstring, "Lock layout")
+            rel_m = is_rc | is_ps | is_slr
+            if is_rw:
+                rel_m = rel_m | is_rdr
+            rel = rel_m & (new_pc == mc.NCS)
+            last = (rel & (gat_k(cnt, tg) == _I(1)))[:, None] & ohK
+            cnt = cnt - jnp.where(rel[:, None] & ohK, _I(1), _I(0))
+            t0 = jnp.where(last, _I(0), t0)
+            t1 = jnp.where(last, _I(0), t1)
+            vic = jnp.where(last, _I(0), vic)
+            sid = jnp.where(last, _I(-1), sid)
+            if is_rw:
+                wrd = jnp.where(last, _I(0), wrd)
         if R > 0:
             # no-op events (drained stream / idle thread with an empty
             # queue) keep the semantic machine frozen — the exact analogue
@@ -597,9 +680,14 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
                 zip((t0, t1, vic, pc, bud, nxt, prv, tgt, coh), sem_old))
             if is_rw:
                 wrd = jnp.where(sm, wrd, st_wrd)
+            if table:
+                (sid, cnt, lnd) = tuple(
+                    jnp.where(sm, n, o) for n, o in
+                    zip((sid, cnt, lnd), tab_old))
 
         # -- cost opcode + RNIC node (sim._step_fns' cost functions) -------
-        lnode = gat_k(ln, tg)
+        if not table:
+            lnode = gat_k(ln, tg)
         pred_node = gat_t(tn, pred)
         succ_node = gat_t(tn, succ)
         if is_hl:
@@ -724,6 +812,8 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
                       busy, opst, done, latn, reacq, npass)
         if is_rw:
             new_st = new_st + (wrd,)
+        if table:
+            new_st = new_st + (sid, cnt, lnd)
         return new_st
 
     # ragged final chunk: bound the loop at the true remaining event count
@@ -738,6 +828,9 @@ def event_loop_kernel(*refs, alg: str, T: int, N: int, K: int, P: int,
         lambda c: (c[0] + _I(1), step(c[0], c[1])),
         (jnp.zeros((), I32), state))
     state = carry[1]
+    if table:
+        s_sid[...], s_cnt[...], s_lnd[...] = state[-3:]
+        state = state[:-3]
     if is_rw:
         s_word[...] = state[-1]
         state = state[:-1]

@@ -64,7 +64,7 @@ one vmapped pass fused into the surrounding jit, not a per-event dispatch.
 >>> u1, r2, r3 = precompute_draws(jnp.asarray(o.seed)[None],
 ...                               jnp.asarray(o.edges)[None],
 ...                               jnp.asarray(o.zcdf)[None],
-...                               n_events=64, N=2, kpn=4)
+...                               n_events=64, N=2)
 >>> u1.shape, str(r2.dtype), r3.shape
 ((1, 64), 'int32', (1, 64))
 
@@ -86,6 +86,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -115,6 +116,14 @@ LANES = 128
 #: index-map constant: a python 0 would trace as int64 under x64, which
 #: Mosaic refuses in the grid's index functions
 _ZERO = np.int32(0)
+#: the most ranks a node's Zipf draw compares with every event
+#: (``zipf_compare``); above it the draw merges (``zipf_merge``). One
+#: pass of 72 x 150,000 uniforms on a v5e took (ms, compare / merge) 0.71
+#: / 42.95 at 5 ranks, 1.72 / 42.97 at 100, 30.34 / 42.82 at 1,000,
+#: 77.52 / 43.71 at 2,000 and 384.15 / 45.64 at 10,000: the merge costs
+#: its two sorts whatever the ranks, the compare grows with them
+#: (``scripts/lock_layout_curve.py``)
+ZIPF_COMPARE_RANKS = 1024
 #: the named scope of the draw precompute and its stream padding: their
 #: HLO ops carry it in their ``op_name`` metadata
 DRAWS_SCOPE = "event_loop.draws"
@@ -130,7 +139,69 @@ def _device_kind() -> str:
     return jax.devices()[0].device_kind
 
 
-def precompute_draws(seed, edges, zcdf, n_events: int, N: int, kpn: int,
+def zipf_offset(cdf, ph, u):
+    """Each event's within-node Zipf offset: the count of its phase's
+    CDF entries at or below its uniform, clamped to ``kpn - 1``.
+
+    ``cdf`` is one replica's (P, kpn) per-phase CDF, non-decreasing
+    along each row; ``ph`` and ``u`` are (n,) phase indices and float32
+    uniforms. Equal, bit for bit, to the XLA engine's per-event
+    ``min(sum(u >= cdf[ph]), kpn - 1)``, in one of two forms derived from
+    ``kpn`` (``ZIPF_COMPARE_RANKS``), neither of which gathers a CDF row
+    per event: up to that many ranks, each phase's row is compared with
+    every event in one fused reduction; above it, a merge of the events
+    into the sorted CDF entries.
+
+    >>> import numpy as np
+    >>> cdf = np.float32([[0.25, 0.5, 0.75, 1.0]])
+    >>> u = np.float32([0.0, 0.25, 0.3, 0.75, 0.99])
+    >>> zipf_offset(cdf, np.zeros(5, np.int32), u).tolist()
+    [0, 1, 1, 3, 3]
+    >>> zipf_merge(cdf, np.zeros(5, np.int32), u).tolist()
+    [0, 1, 1, 3, 3]
+    """
+    form = zipf_compare if cdf.shape[1] <= ZIPF_COMPARE_RANKS else zipf_merge
+    return form(cdf, ph, u)
+
+
+def zipf_compare(cdf, ph, u):
+    """``zipf_offset`` by comparing every event with each phase's whole
+    row: P * kpn compares an event, which XLA fuses into the count."""
+    P, kpn = cdf.shape
+    n = None
+    for q in range(P):
+        c = jnp.sum(u[:, None] >= cdf[q], axis=1, dtype=I32)
+        n = c if n is None else jnp.where(ph == q, c, n)
+    return jnp.minimum(n, kpn - 1)
+
+
+def zipf_merge(cdf, ph, u):
+    """``zipf_offset`` by merging the events into the CDF entries: one
+    sort of both by (phase, value, tag), where a CDF entry's tag -1 puts
+    it before an event of equal value (so ``u >= cdf`` counts it) and an
+    event's tag is its index; a running count of the CDF entries gives
+    each event its rank, and a second sort by tag puts the ranks back in
+    event order. The values are float32 uniforms in [0, 1) and CDF
+    entries above 0, so the sort's total order is the compare's."""
+    P, kpn = cdf.shape
+    n = u.shape[0]
+    tag = jnp.concatenate([jnp.full(P * kpn, -1, I32),
+                           jnp.arange(n, dtype=I32)])
+    keys = (jnp.concatenate([jnp.reshape(cdf, (P * kpn,)), u]), tag)
+    if P > 1:
+        phase = jnp.concatenate([jnp.repeat(jnp.arange(P, dtype=I32), kpn),
+                                 ph])
+        keys = (phase,) + keys
+    tag = lax.sort(keys, num_keys=len(keys))[-1]
+    # the CDF entries at or before each position, earlier phases' too
+    below = jnp.cumsum((tag < 0).astype(I32))
+    rank = lax.sort((tag, below), num_keys=1)[1][P * kpn:]
+    if P > 1:
+        rank = rank - ph * kpn
+    return jnp.minimum(rank, kpn - 1)
+
+
+def precompute_draws(seed, edges, zcdf, n_events: int, N: int,
                      rw: bool = False):
     """The state-independent per-event draw stream, replica-batched.
 
@@ -138,10 +209,11 @@ def precompute_draws(seed, edges, zcdf, n_events: int, N: int, kpn: int,
     zipf_offset i32) — exactly the values ``sim._run_events`` draws at
     event i from ``split(fold_in(key, i), 3)``. The Zipf inverse-CDF is
     resolved against the phase active at event i (phases are a pure
-    function of the event index), so consuming the stream in-kernel
-    reproduces the XLA path bit for bit. ``rw=True`` mirrors the
-    alock-rw engine's 4-way split and appends the reader/writer coin
-    uniform (f32) as a fourth stream. Traced under ``DRAWS_SCOPE``.
+    function of the event index) by ``zipf_offset``, so consuming the
+    stream in-kernel reproduces the XLA path bit for bit.
+    ``rw=True`` mirrors the alock-rw engine's 4-way split and appends the
+    reader/writer coin uniform (f32) as a fourth stream. Traced under
+    ``DRAWS_SCOPE``.
     """
     def one(sd, ed, cdf):
         key = jax.random.key(sd)
@@ -155,14 +227,14 @@ def precompute_draws(seed, edges, zcdf, n_events: int, N: int, kpn: int,
             u1 = jax.random.uniform(k1, dtype=jnp.float32)
             r2 = jax.random.randint(k2, (), 0, max(N - 1, 1), dtype=I32)
             u3 = jax.random.uniform(k3, dtype=jnp.float32)
-            ph = jnp.sum(i >= ed) - 1
-            r3 = jnp.minimum(jnp.sum(u3 >= cdf[ph]).astype(I32), kpn - 1)
+            ph = jnp.sum(i >= ed, dtype=I32) - 1
             if rw:
                 u4 = jax.random.uniform(k4, dtype=jnp.float32)
-                return u1, r2, r3, u4
-            return u1, r2, r3
+                return u1, r2, u3, ph, u4
+            return u1, r2, u3, ph
 
-        return jax.vmap(ev)(jnp.arange(n_events))
+        u1, r2, u3, ph, *u4 = jax.vmap(ev)(jnp.arange(n_events))
+        return (u1, r2, zipf_offset(cdf, ph, u3), *u4)
 
     with jax.named_scope(DRAWS_SCOPE):
         return jax.vmap(one)(seed, edges, zcdf)
@@ -304,11 +376,10 @@ def run_events_pairs(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
             m1 = p32.pfull((B, R), -1)
             return base + (arr, m1, m1, jnp.zeros((B, R), I32))
         return base
-    kpn = K // N
     is_hl = alg == "hlock"
     is_rw = alg == "alock-rw"
     streams = list(precompute_draws(wl.seed, wl.edges, wl.zcdf, n_events,
-                                    N, kpn, rw=is_rw))
+                                    N, rw=is_rw))
 
     plan = plan_for_run(B, P, n_events, T, N, K, R=R, tile=tile,
                         ev_chunk=ev_chunk, interpret=interpret,
@@ -350,6 +421,8 @@ def run_events_pairs(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
         tokp = prep(jnp.asarray(aplan.tok, I32))
         tokcp = prep(jnp.asarray(aplan.tokcum, I32))
         qcapp = prep(jnp.asarray(aplan.qcap, I32))
+    layout, L = vmem.lock_layout(K, T)
+    table = layout == "table"
     Bp = B + pad_b
     n_chunks = (n_events + pad_e) // ev_chunk
     grid = (Bp // tile, n_chunks)
@@ -384,18 +457,18 @@ def run_events_pairs(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
         out_shape += (wq_shapes + soj_shapes
                       + [jax.ShapeDtypeStruct((Bp, R), I32)])
     scratch_shapes = [
-        pltpu.VMEM((tile, K), I32),   # tail0 / lock word
-        pltpu.VMEM((tile, K), I32),   # tail1
-        pltpu.VMEM((tile, K), I32),   # victim
+        pltpu.VMEM((tile, L), I32),   # tail0 / lock word
+        pltpu.VMEM((tile, L), I32),   # tail1
+        pltpu.VMEM((tile, L), I32),   # victim
         pltpu.VMEM((tile, T), I32),   # pc
         pltpu.VMEM((tile, T), I32),   # budget
         pltpu.VMEM((tile, T), I32),   # nxt
         pltpu.VMEM((tile, T), I32),   # prev
-        pltpu.VMEM((tile, T), I32),   # target
+        pltpu.VMEM((tile, T), I32),   # target lock (direct) / slot (table)
         pltpu.VMEM((tile, T), I32),   # cohort
         # alock-rw reader counts ride between the semantic and clock
         # scratch (matching the kernel's unpack and vmem.buffer_table)
-        *([pltpu.VMEM((tile, K), I32)] if is_rw else []),
+        *([pltpu.VMEM((tile, L), I32)] if is_rw else []),
         *clock_scratch(T),            # ready
         *clock_scratch(N),            # busy
         *clock_scratch(T),            # op_start
@@ -405,8 +478,9 @@ def run_events_pairs(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
         + [row(P), row(P), row(P * T)]
         + ([row(P * T)] if is_rw else [])          # read_frac rows
         + [row(P * T), row(P * 2), row(P * N_COST_ROWS), row(P * N),
-           pl.BlockSpec((1, T), lambda i, j: (_ZERO, _ZERO)),
-           pl.BlockSpec((1, K), lambda i, j: (_ZERO, _ZERO))]
+           pl.BlockSpec((1, T), lambda i, j: (_ZERO, _ZERO))]
+        + ([] if table else                        # lock_node row
+           [pl.BlockSpec((1, K), lambda i, j: (_ZERO, _ZERO))])
         + ([row(N)] if is_hl else []))             # rack row
     operands = [*streams,
                 jnp.asarray(edges, I32), jnp.asarray(think, I32),
@@ -416,8 +490,10 @@ def run_events_pairs(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     operands += [jnp.asarray(actp, I32),
                  jnp.asarray(binit, I32), jnp.asarray(costp, I32),
                  jnp.asarray(nmult, jnp.float32),
-                 jnp.asarray(thread_node, I32)[None, :],
-                 jnp.asarray(lock_node, I32)[None, :]]
+                 jnp.asarray(thread_node, I32)[None, :]]
+    if not table:
+        # the table layout takes a lock's node from the draw that chose it
+        operands += [jnp.asarray(lock_node, I32)[None, :]]
     if is_hl:
         operands += [jnp.asarray(rackp, I32)]
     if R:
@@ -426,10 +502,15 @@ def run_events_pairs(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
         scratch_shapes += [pltpu.VMEM((tile, T), I32),   # curreq
                            pltpu.VMEM((tile, 1), I32),   # arrptr
                            pltpu.VMEM((tile, 1), I32)]   # qlen
+    if table:
+        scratch_shapes += [pltpu.VMEM((tile, L), I32),   # slot lock ids
+                           pltpu.VMEM((tile, L), I32),   # slot ref counts
+                           pltpu.VMEM((tile, T), I32)]   # thread lock node
 
     out = pl.pallas_call(
         functools.partial(event_loop_kernel, alg=alg, T=T, N=N, K=K, P=P,
-                          n_events=n_events, ev_chunk=ev_chunk, R=R),
+                          n_events=n_events, ev_chunk=ev_chunk, R=R,
+                          table=table),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -464,8 +545,11 @@ def run_events(alg, T, N, K, n_events, wl, thread_node, lock_node, *,
     ``wl`` is a ``WorkloadOperands`` with a leading replica axis B on
     every leaf: locality (B,P,T) f32, zcdf (B,P,K//N) f32, edges (B,P)
     i32, think_ns (B,P) i32, active (B,P,T) i32, b_init (B,P,2) i32,
-    cost_rows (B,P,8) i32, node_mult (B,P,N) f32, seed (B,) i32;
-    thread_node (T,)/lock_node (K,) broadcast. Returns (done (B,T) i32,
+    cost_rows (B,P,8) i32, node_mult (B,P,N) f32, seed (B,) i32,
+    rack (B,N) i32 (hlock), read_frac (B,P,T) f32 (alock-rw);
+    thread_node (T,)/lock_node (K,) broadcast, lock_node read only in the
+    direct lock layout (``vmem.lock_layout``: the active-lock table takes
+    a lock's node from its draw). Returns (done (B,T) i32,
     lat (B,lat_samples) i64, lat_n (B,) i32, t_end (B,) i64,
     nreacq (B,) i32, npass (B,) i32).
 

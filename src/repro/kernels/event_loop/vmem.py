@@ -18,14 +18,23 @@ clocks = 8 bytes, as an (hi, lo) pair of i32 buffers):
   workload rows     edges/think ``tile*P*4`` each; locality/active
                     ``tile*P*T*4`` each; b_init ``tile*P*2*4``; cost_rows
                     ``tile*P*8*4``; node_mult ``tile*P*N*4``; thread_node
-                    ``T*4``; lock_node ``K*4``
+                    ``T*4``; lock_node ``K*4`` (direct layout only)
   outputs           done ``tile*T*4``; per-event latency stream
                     ``tile*ev_chunk*8``, **x2** double-buffered like the
                     draws; lat_n/reacq/npass ``tile*4`` each; t_end
                     ``tile*8``
-  scratch           tails/victim ``3 * tile*K*4``; six per-thread i32
-                    descriptors ``tile*T*4``; ready/op_start ``tile*T*8``;
-                    busy ``tile*N*8``
+  scratch           tails/victim ``3 * tile*L*4`` (L lock lanes, below);
+                    six per-thread i32 descriptors ``tile*T*4``;
+                    ready/op_start ``tile*T*8``; busy ``tile*N*8``; the
+                    table layout adds the slots' lock ids and reference
+                    counts ``2 * tile*S*4`` and each thread's lock node
+                    ``tile*T*4``
+
+Lock layout (``lock_layout``): the lock words sit on L lanes. While K
+fits S = T rounded up to 128 lanes, L = K and a lock's lane is its id
+(``"direct"``). Above that, L = S: at most T locks are non-idle at once,
+so the kernel keeps an active-lock table of S slots (``"table"``) and
+its VMEM no longer grows with K.
 
 ``ops.plan_for_run`` derives the tile from the bucket's rows B: it
 first lets this planner halve the ceiling (``ops.MAX_TILE`` rows, or
@@ -95,6 +104,9 @@ def budget_for(device_kind: str) -> int:
 
 _I32 = 4
 _F32 = 4
+#: the lanes of one vreg row: the active-lock table rounds T up to these
+_LANES = 128
+
 #: the sequential event axis streams u1/r2/r3 in and the per-event
 #: latencies out chunk by chunk; Pallas double-buffers streamed blocks so
 #: the next chunk moves during compute
@@ -106,6 +118,22 @@ PIPELINE_FACTOR = 2
 STREAMED_INPUTS = ("in.u1", "in.r2", "in.r3", "in.u4")
 #: the streamed outputs: the per-event latency's hi and lo blocks
 STREAMED_OUTPUTS = ("out.lat_stream.hi", "out.lat_stream.lo")
+
+
+def lock_layout(K: int, T: int) -> tuple[str, int]:
+    """The kernel's lock-state layout for K locks and T threads: its name
+    and its lanes L.
+
+    ``"direct"`` (L = K, a lock's lane is its id) while K fits S, the
+    T threads rounded up to whole 128-lane rows; ``"table"`` (L = S
+    slots, claimed and released by the threads) above. The table never
+    needs more than T slots: a thread targets one lock at a time.
+
+    >>> lock_layout(100, 80), lock_layout(1000, 80), lock_layout(100, 240)
+    (('direct', 100), ('table', 128), ('direct', 100))
+    """
+    S = -(-T // _LANES) * _LANES
+    return ("direct", K) if K <= S else ("table", S)
 
 
 def _entries(name, shape, itemsize, factor=1):
@@ -135,8 +163,12 @@ def buffer_table(tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
     (alock-rw) adds the streamed reader/writer coin, the per-phase read
     probabilities and the reader-count scratch; ``hl`` (hlock) adds the
     per-node rack row — both in their exact binding positions, and both
-    inert for every other algorithm.
+    inert for every other algorithm. The lock layout (``lock_layout``)
+    sets the lock words' lanes, drops ``in.lock_node`` under the table and
+    appends the table's scratch last.
     """
+    layout, L = lock_layout(K, T)
+    direct = layout == "direct"
     rows: list[tuple] = [
         # streamed draw inputs (STREAMED_INPUTS — double-buffered along
         # the event axis)
@@ -155,7 +187,7 @@ def buffer_table(tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
         _entries("in.cost_rows", (tile, P * N_COST_ROWS), _I32),
         _entries("in.node_mult", (tile, P * N), _F32),
         _entries("in.thread_node", (1, T), _I32),
-        _entries("in.lock_node", (1, K), _I32),
+        *([_entries("in.lock_node", (1, K), _I32)] if direct else []),
         *([_entries("in.rack", (tile, N), _I32)] if hl else []),
         # open-loop arrival rows (same block every chunk)
         *([*_clock_entries("in.arr", (tile, R)),
@@ -176,17 +208,18 @@ def buffer_table(tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
            *_clock_entries("out.soj", (tile, R)),
            _entries("out.rstat", (tile, R), _I32)] if R else []),
         # semantic scratch (int32)
-        _entries("scr.tail0", (tile, K), _I32),
-        _entries("scr.tail1", (tile, K), _I32),
-        _entries("scr.victim", (tile, K), _I32),
+        _entries("scr.tail0", (tile, L), _I32),
+        _entries("scr.tail1", (tile, L), _I32),
+        _entries("scr.victim", (tile, L), _I32),
         _entries("scr.pc", (tile, T), _I32),
         _entries("scr.budget", (tile, T), _I32),
         _entries("scr.nxt", (tile, T), _I32),
         _entries("scr.prev", (tile, T), _I32),
+        # the target lock (direct) or the thread's slot (table)
         _entries("scr.target", (tile, T), _I32),
         _entries("scr.cohort", (tile, T), _I32),
         # alock-rw reader counts (between semantic and clock scratch)
-        *([_entries("scr.word", (tile, K), _I32)] if rw else []),
+        *([_entries("scr.word", (tile, L), _I32)] if rw else []),
         # clock scratch
         *_clock_entries("scr.ready", (tile, T)),
         *_clock_entries("scr.busy", (tile, N)),
@@ -195,6 +228,12 @@ def buffer_table(tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
         *([_entries("scr.curreq", (tile, T), _I32),
            _entries("scr.arrptr", (tile, 1), _I32),
            _entries("scr.qlen", (tile, 1), _I32)] if R else []),
+        # the active-lock table: each slot's lock id and reference count,
+        # each thread's lock node
+        *([_entries("scr.slot_lock", (tile, L), _I32),
+           _entries("scr.slot_count", (tile, L), _I32),
+           _entries("scr.lock_node", (tile, T), _I32)] if not direct
+          else []),
     ]
     return dict(rows)
 
@@ -210,6 +249,8 @@ class VmemPlan:
     total_bytes: int
     breakdown: Mapping[str, tuple]           # name -> (shape, bytes)
     rows: int | None = None                  # the call's replicas B
+    lock_layout: str = "direct"              # ``lock_layout``'s name
+    lock_slots: int | None = None            # its lock lanes L
 
     @property
     def shrunk(self) -> bool:
@@ -239,6 +280,7 @@ class VmemPlan:
             "total_bytes": self.total_bytes, "shrunk": self.shrunk,
             "rows": self.rows, "tiles": self.tiles,
             "padded_rows": self.padded_rows,
+            "lock_layout": self.lock_layout, "lock_slots": self.lock_slots,
         }
 
 
@@ -278,8 +320,11 @@ def plan_vmem(*, tile: int, ev_chunk: int, T: int, N: int, K: int, P: int,
             f"tile={t} (needs {total:,}B; largest buffers: {detail}). Lower "
             f"ev_chunk ({ev_chunk}), or raise the budget "
             f"(run_events(vmem_budget=...)).")
+    layout, lanes = lock_layout(K, T)
     return VmemPlan(requested_tile=requested, tile=t, ev_chunk=ev_chunk,
-                    lat_samples=lat_samples, budget=budget, total_bytes=total, breakdown=table)
+                    lat_samples=lat_samples, budget=budget,
+                    total_bytes=total, breakdown=table, lock_layout=layout,
+                    lock_slots=lanes)
 
 
 # -- plan registry (read by batch.exec_stats / benchmarks) ------------------

@@ -103,11 +103,14 @@ def check_call(spans, n_configs, n_seeds, n_events, bucket_rows,
             assert set(d[3]) == {"sweep", "bucket", "rows", "bytes",
                                  "new_shape", "lock_lanes", "draw_streams",
                                  "zipf_ranks", "tile", "tiles",
-                                 "padded_rows"} | (
+                                 "padded_rows", "lock_layout",
+                                 "lock_slots"} | (
                                      {"units"} if devices > 1 else set())
-            # every bucket here: 2 nodes, 4 locks, exclusive machines
+            # every bucket here: 2 nodes, 4 locks, exclusive machines,
+            # each lock on a lane of its own
             assert (d[3]["lock_lanes"], d[3]["draw_streams"],
-                    d[3]["zipf_ranks"]) == (4, 3, 2)
+                    d[3]["zipf_ranks"], d[3]["lock_layout"],
+                    d[3]["lock_slots"]) == (4, 3, 2, "direct", 4)
             # a kernel call's rows fit one derived tile: one chain, no
             # padding
             assert (d[3]["tile"], d[3]["tiles"], d[3]["padded_rows"]) == (
@@ -138,6 +141,22 @@ def test_unsharded_sweep_spans_and_metadata():
         news.append([d[0][3]["new_shape"] for d in dispatches])
     assert sids[1] == sids[0] + 1
     assert news == [[1, 1], [0, 0]]
+
+
+def test_table_bucket_dispatch_span_names_its_layout():
+    """256 locks on 4 x 2 threads (S = 128 lanes): the bucket runs the
+    active-lock table, and its dispatch span and VMEM plan say so, with
+    the 128 slots beside the 256 lock lanes."""
+    ws = [Workload("alock-rw", 4, 2, 256, locality=0.8, zipf_s=0.99,
+                   read_frac=0.5, seed=3)]
+    batch.reset_exec_stats()
+    _, spans = traced_sweeps([(ws, 2, 300)], backend="pallas")
+    (d,) = [s for s in spans if s[0] == batch.SPAN_DISPATCH]
+    assert (d[3]["lock_lanes"], d[3]["lock_layout"], d[3]["lock_slots"],
+            d[3]["zipf_ranks"], d[3]["draw_streams"]) == (
+                256, "table", 128, 64, 4)
+    (plan,) = batch.exec_stats()["vmem_plans"]
+    assert (plan["lock_layout"], plan["lock_slots"]) == ("table", 128)
 
 
 def test_tracing_changes_no_result():

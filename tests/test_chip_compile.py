@@ -146,14 +146,28 @@ def test_alock_rw_compiles(one_chip):
 
 def test_ycsb_b_bucket_compiles(one_chip):
     """The YCSB-B lock table (``bench/configs/ycsb-b-n10-k1000.json``):
-    alock-rw on the fig5 cluster with 1,000 locks (a K axis of 8 lane
-    vregs, not a multiple of 128), Zipf 0.99 draws and the reader coin,
-    3 localities x 24 seeds = 72 replicas in one bucket."""
+    alock-rw on the fig5 cluster with 1,000 locks (past the 128 lanes of
+    T = 80, so the active-lock table), Zipf 0.99 draws and the reader
+    coin, 3 localities x 24 seeds = 72 replicas in one bucket."""
     ws = [Workload("alock-rw", 10, 8, 1000, locality=loc, zipf_s=0.99,
                    read_frac=0.95, b_init=(5, 20), seed=1 + 100 * i)
           for i, loc in enumerate((0.85, 0.95, 1.0))]
     plan = _compile(one_chip, ws, n_seeds=24)
-    assert plan.tile == 72
+    assert plan.tile == 72 and plan.lock_layout == "table"
+    assert not plan.shrunk and plan.total_bytes <= plan.budget
+
+
+def test_ycsb_a_bucket_compiles(one_chip):
+    """The YCSB-A lock table (``bench/configs/ycsb-a-n10-k100k.json``):
+    100,000 locks in the table's 128 slots, the Zipf draw by merge at
+    10,000 ranks a node, read 0.5: one unshrunk tile of 72 rows, where
+    the lanes of every lock shrank it to 16 and overflowed VMEM."""
+    ws = [Workload("alock-rw", 10, 8, 100_000, locality=loc, zipf_s=0.99,
+                   read_frac=0.5, b_init=(5, 20), seed=1 + 100 * i)
+          for i, loc in enumerate((0.85, 0.95, 1.0))]
+    plan = _compile(one_chip, ws, n_seeds=24)
+    assert (plan.tile, plan.tiles, plan.lock_layout,
+            plan.lock_slots) == (72, 1, "table", 128)
     assert not plan.shrunk and plan.total_bytes <= plan.budget
 
 

@@ -13,7 +13,8 @@ from repro.core import batch
 from repro.core.cost_model import N_COST_ROWS
 from repro.core.sim import LAT_SAMPLES, topology
 from repro.kernels.event_loop import vmem
-from repro.kernels.event_loop.ops import run_events, run_events_pairs
+from repro.kernels.event_loop.ops import (plan_for_run, run_events,
+                                          run_events_pairs)
 from repro.kernels.event_loop.ref import run_events_ref
 from repro.workloads import Arrivals, Workload, WorkloadOperands, lower
 
@@ -95,6 +96,67 @@ def test_plan_matches_measured_pallas_buffers(monkeypatch):
                   if k in ("in.u1", "in.r2", "in.r3", "out.lat_stream.hi",
                            "out.lat_stream.lo") else 1)
         assert nbytes == int(np.prod(shape)) * 4 * factor, k
+
+
+def test_table_layout_prices_slot_lanes():
+    """Above S = T rounded up to 128 lanes the planner prices the lock
+    words at S lanes, not K: the YCSB-A bucket (K = 100,000, T = 80,
+    alock-rw) is one unshrunk tile of 72 rows on the v5e budget, where K
+    lanes shrank it to 16 rows in 5 tiles. No lock_node row is priced;
+    the slots' ids and counts and each thread's lock node are."""
+    t = vmem.buffer_table(72, 4096, 80, 10, 100_000, 1, rw=True)
+    for name in ("scr.tail0", "scr.tail1", "scr.victim", "scr.word",
+                 "scr.slot_lock", "scr.slot_count"):
+        assert t[name] == ((72, 128), 72 * 128 * 4), name
+    assert t["scr.lock_node"] == ((72, 80), 72 * 80 * 4)
+    assert "in.lock_node" not in t
+    assert not any(100_000 in shape for shape, _ in t.values())
+    plan = plan_for_run(72, 1, 150_000, 80, 10, 100_000, rw=True,
+                        interpret=False, device_kind="TPU v5 lite")
+    assert (plan.tile, plan.tiles, plan.shrunk, plan.lock_layout,
+            plan.lock_slots) == (72, 1, False, "table", 128)
+    assert plan.as_dict()["lock_layout"] == "table"
+    # at or below S a lock keeps a lane of its own
+    d = vmem.buffer_table(72, 4096, 80, 10, 100, 1)
+    assert d["scr.tail0"][0] == (72, 100)
+    assert d["in.lock_node"][0] == (1, 100)
+    assert "scr.slot_lock" not in d
+    assert vmem.lock_layout(128, 80) == ("direct", 128)
+    assert vmem.lock_layout(129, 80) == ("table", 128)
+    assert vmem.lock_layout(1000, 240) == ("table", 256)
+
+
+def test_table_plan_matches_measured_pallas_buffers(monkeypatch):
+    """The table layout's buffers, measured as above: 256 locks on 2 x 2
+    threads (S = 128), alock-rw."""
+    from repro.kernels.event_loop import ops as el_ops
+    captured = {}
+    real = el_ops.pl.pallas_call
+
+    def spy(kernel, **kw):
+        captured.update(kw)
+        return real(kernel, **kw)
+
+    monkeypatch.setattr(el_ops.pl, "pallas_call", spy)
+    ev = 300
+    ws = [lower(Workload("alock-rw", 2, 2, 256, locality=0.9,
+                         read_frac=0.5, seed=4 + s), ev) for s in range(3)]
+    wl = WorkloadOperands(
+        *(jnp.asarray(np.stack([np.asarray(getattr(w.operands, f))
+                                for w in ws]))
+          for f in WorkloadOperands._fields))
+    tn, ln, _ = topology("alock-rw", 2, 2, 256)
+    run_events_pairs("alock-rw", 4, 2, 256, ev, wl, tn, ln, interpret=True,
+                     tile=2, ev_chunk=128, lat_samples=512)
+    t = vmem.last_plan().breakdown
+    assert vmem.last_plan().lock_layout == "table"
+    for prefix, got in (("in.", [s.block_shape
+                                 for s in captured["in_specs"]]),
+                        ("out.", [s.block_shape
+                                  for s in captured["out_specs"]]),
+                        ("scr.", [tuple(s.shape)
+                                  for s in captured["scratch_shapes"]])):
+        assert [t[k][0] for k in t if k.startswith(prefix)] == got, prefix
 
 
 def test_open_loop_plan_matches_measured_pallas_buffers(monkeypatch):
